@@ -42,7 +42,7 @@ SEQ_LEN = 128
 # --at-scale: the REAL bench model (1.47B wide-MLP Llama, bf16 params,
 # factored-rms state — bench.py's headline config) so the clocked restore
 # moves a multi-GB checkpoint through Orbax + device_put + re-jit, the
-# actual cost the <30 s north star is about (VERDICT r3 item 1).
+# actual cost the <30 s north star is about.
 SCALE_GLOBAL_BATCH = 2
 SCALE_SEQ_LEN = 2048
 
@@ -114,13 +114,9 @@ def worker_main(ckpt_dir: str, events_file: str, total_steps: int,
         client = None
 
     if at_scale:
-        on_tpu = jax.default_backend() == "tpu"
         cfg = LlamaConfig.llama_wide_1b(
-            max_seq_len=SCALE_SEQ_LEN,
-            attn_impl="flash" if on_tpu else "reference",
-            embed_impl="gather",
-            norm_impl="fused" if on_tpu else "reference",
-            dtype=jnp.bfloat16,
+            max_seq_len=SCALE_SEQ_LEN, attn_impl="flash",
+            embed_impl="gather", norm_impl="fused", dtype=jnp.bfloat16,
         )
         tx = optax.chain(optax.scale_by_factored_rms(),
                          optax.scale(-3e-4))
@@ -265,20 +261,13 @@ def run_bench(timeout_s: float = 480.0, at_scale: bool = False,
         # 8) would multiply into a dp size the toy batch cannot divide
         worker_env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=1"
     if at_scale:
-        # Both incarnations share an on-disk compile cache: a restarted
-        # process on the same host legitimately reuses it, and without
-        # it the clocked restore is mostly XLA re-compile, not restore.
-        worker_env["JAX_COMPILATION_CACHE_DIR"] = os.path.join(
-            workdir, "compile_cache")
         # int8 params-only checkpoints (checkpoint/quantized.py): the
-        # 1.47B state is 5.5 GB of fp32 masters, and at-scale restore
-        # time is dominated by moving those bytes (measured 262 s raw);
-        # the codec cuts them ~3.9x with no measurable resume-loss
-        # impact, validated on the real chip round 5 (per-leaf encode,
-        # 1.34 GB vs 5.08 GB, Orbax read 21.7 s vs ~95 s — see
-        # docs/benchmarks.md "Round-5 on-chip evidence"), so int8 is
-        # now the default; BENCH_RESTORE_QUANT_BITS=0 reverts to the
-        # exact-dtype baseline.
+        # 1.47B state is 5.5 GB of fp32 masters and at-scale restore
+        # time is dominated by moving those bytes; the codec cuts them
+        # ~3.9x (1.34 GB vs 5.08 GB on disk), so int8 is the default
+        # here; BENCH_RESTORE_QUANT_BITS=0 reverts to the exact-dtype
+        # baseline. Both incarnations share the compile cache the agent
+        # hands every worker (common/compile_cache.py).
         # pinned unconditionally: the worker env overlays the ambient
         # environment, so the codec choice is governed ONLY by
         # BENCH_RESTORE_QUANT_BITS — an exported
@@ -465,7 +454,7 @@ def main() -> int:
     parser.add_argument("--timeout", type=float, default=480.0)
     parser.add_argument("--at-scale", action="store_true",
                         help="bench-headline 1.47B model: clock a "
-                             "multi-GB restore (VERDICT r3 item 1)")
+                             "multi-GB restore")
     parser.add_argument("--nodes", type=int, default=1,
                         help="agents in the world; > 1 wipes the "
                              "victim's host cache so its shards arrive "

@@ -36,6 +36,7 @@ from dlrover_tpu.agent.preemption import (
     default_sources,
     write_drain_request,
 )
+from dlrover_tpu.common import compile_cache
 from dlrover_tpu.common.bootstrap import publish_or_wait_coordinator
 from dlrover_tpu.common.constants import (
     DefaultValues,
@@ -270,10 +271,6 @@ class ElasticAgent:
         # export): an incarnation that pushes past it made FORWARD
         # progress — re-treading checkpointed steps does not count
         self._spawn_step = -1
-        # Persistent XLA compile cache shared across worker restarts: an
-        # elastic restart re-lowers the same programs, so the respawned
-        # worker skips compilation — the dominant cost of a fast restore.
-        self.compile_cache_dir = os.path.join(self._workdir, "xla-cache")
         # batches the agent's finished spans (rendezvous etc.) for the
         # master's job-wide timeline; flushed from the monitor loop
         self._span_exporter = obs.SpanExporter()
@@ -374,7 +371,11 @@ class ElasticAgent:
             # gradient sync and slice-targeted chaos faults
             NodeEnv.SLICE_ID: str(slice_id),
         })
-        env.setdefault("JAX_COMPILATION_CACHE_DIR", self.compile_cache_dir)
+        # Persistent XLA compile cache shared across worker restarts AND
+        # across launches: a respawned worker re-lowers the same programs
+        # and loads them instead of compiling — the dominant cost of a
+        # fast restore.
+        env.setdefault(compile_cache.ENV, compile_cache.compile_cache_dir())
         return env
 
     # -- worker lifecycle --------------------------------------------------

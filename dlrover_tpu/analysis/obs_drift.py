@@ -1,7 +1,7 @@
 """Obs-catalog drift checker (GL6xx): docs ↔ code, both directions.
 
 ``docs/observability.md`` is the operator contract: its metric catalog,
-span taxonomy and flight-event catalog tables claim what the fleet
+span catalog and flight-event catalog tables claim what the fleet
 emits, and ``obs/tsdb.DASHBOARD_SERIES`` claims what ``tools/top.py``
 can render. PR 11's sixth review pass caught a ``DASHBOARD_SERIES``
 entry that nothing fed; this checker makes that a lint failure instead:
@@ -41,7 +41,7 @@ _EVENT_METHODS = {"record_event"}
 # markdown section headings → catalog kinds (case-insensitive substring)
 _SECTIONS = (
     ("metric catalog", "metric"),
-    ("span taxonomy", "span"),
+    ("span catalog", "span"),
     ("flight-event catalog", "event"),
 )
 _ROW_RE = re.compile(r"^\|\s*`([A-Za-z0-9_.:*-]+)`")

@@ -28,6 +28,7 @@ from dlrover_tpu.auto.strategy import (
 )
 from dlrover_tpu.common.constants import MeshAxis
 from dlrover_tpu.common.log import default_logger as logger
+from dlrover_tpu.ops.backend import on_tpu
 from dlrover_tpu.parallel.mesh import MeshSpec, create_mesh
 from dlrover_tpu.parallel.sharding import make_sharding_rules
 from dlrover_tpu.trainer.train_step import (
@@ -108,8 +109,7 @@ def lower(context: ModelContext) -> AccelerateResult:
     if plan.params_dtype is not None:
         updates["param_dtype"] = plan.params_dtype
     if plan.flash_attention:
-        updates["attn_impl"] = (
-            "flash" if jax.default_backend() == "tpu" else "reference")
+        updates["attn_impl"] = "flash" if on_tpu() else "reference"
     if plan.sequence_parallel and mesh.shape[MeshAxis.SEQUENCE] > 1:
         # SP replaces the attention kernel: the sequence dim is sharded, so
         # attention must be the ring/all-to-all implementation (wins over a

@@ -3,8 +3,8 @@ surviving hosts' memory, not from Orbax storage.
 
 Why: after a single-host failure the survivors still hold every replicated
 shard of the model/optimizer state — restoring the replacement from storage
-is why ``elastic_restore_seconds_at_scale`` was 105.5 s (BENCH_r05) while
-the single-host path was 8 s. ElasWave's in-memory state redistribution and
+moves the whole multi-GB state through disk, which is most of an at-scale
+restore, while a toy restore takes seconds. ElasWave's in-memory state redistribution and
 the Orbax distributed-checkpointing paper (PAPERS.md) are the blueprints.
 
 The pieces, in data-flow order:

@@ -27,6 +27,7 @@ import time
 from typing import Tuple
 
 from dlrover_tpu.agent.master_client import MasterClient
+from dlrover_tpu.common import compile_cache
 from dlrover_tpu.common.bootstrap import publish_or_wait_coordinator
 from dlrover_tpu.common.constants import NodeEnv, RendezvousName
 from dlrover_tpu.common.log import default_logger as logger
@@ -75,8 +76,6 @@ def probe_main() -> int:
     if n > 1:
         from jax.sharding import Mesh, PartitionSpec as P
 
-        from dlrover_tpu.common.jax_compat import shard_map
-
         mesh = Mesh(jax.devices(), ("probe",))
         data = jnp.ones((n, _ALLGATHER_FLOATS), jnp.float32)
 
@@ -86,7 +85,7 @@ def probe_main() -> int:
                 gathered = jax.lax.all_gather(block, "probe")
                 return jnp.sum(gathered, dtype=jnp.float32)[None]
 
-            return shard_map(
+            return jax.shard_map(
                 inner, mesh=mesh, in_specs=P("probe"), out_specs=P("probe")
             )(arr)
 
@@ -176,11 +175,7 @@ def _probe_round(client: MasterClient, devices_per_node: int,
     # Round 1 re-runs the same probe program in a fresh process; a shared
     # persistent compile cache lets it skip the cold compile that makes a
     # loaded 1-core host starve the coordination-service deadline.
-    # per-user cache dir (uid, not getpass: containers with no passwd
-    # entry for an arbitrary uid raise KeyError from getpass.getuser())
-    env.setdefault("JAX_COMPILATION_CACHE_DIR",
-                   os.path.join(tempfile.gettempdir(),
-                                f"dlrover_tpu_nc_cache_{os.getuid()}"))
+    env.setdefault(compile_cache.ENV, compile_cache.compile_cache_dir())
     env.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
     t0 = time.perf_counter()
     try:

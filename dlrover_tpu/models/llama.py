@@ -22,11 +22,12 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from dlrover_tpu.ops.backend import on_tpu
 from dlrover_tpu.ops.flash_attention import (
     mesh_flash_attention,
     reference_attention,
 )
-from dlrover_tpu.ops.norms import fused_rms_norm, reference_rms_norm
+from dlrover_tpu.ops.norms import mesh_rms_norm, reference_rms_norm
 from dlrover_tpu.ops.remat import resolve_remat_policy
 
 
@@ -142,9 +143,9 @@ class RMSNorm(nn.Module):
         # The fused kernel only on real TPU: off-TPU it would run in
         # Pallas interpret mode — slow, and its interpreter loop breaks
         # the vma typing inside partial-auto shard_map (pipeline stages)
-        if self.impl == "fused" and jax.default_backend() == "tpu":
-            return fused_rms_norm(x, weight.astype(jnp.float32),
-                                  self.eps).astype(self.dtype)
+        if self.impl == "fused" and on_tpu():
+            return mesh_rms_norm(x, weight.astype(jnp.float32),
+                                 self.eps).astype(self.dtype)
         return reference_rms_norm(x, weight.astype(jnp.float32),
                                   self.eps).astype(self.dtype)
 

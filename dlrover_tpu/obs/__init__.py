@@ -13,7 +13,7 @@ Components then only need::
     obs.get_registry().counter("dlrover_tpu_rendezvous_rounds_total").inc()
     obs.get_flight_recorder().record_event("worker_spawn", rank=0)
 
-See docs/observability.md for the metric catalog, span taxonomy and the
+See docs/observability.md for the metric catalog, span catalog and the
 flight-recorder dump format.
 """
 

@@ -1,8 +1,8 @@
 """Goodput ledger: classify the job's wall-clock, per rank and job-wide.
 
-BENCH_r05 says restore-at-scale is 105.5 s and 7B MFU is 0.59 — but
-nothing rolls the span stream up into "of the last hour, X% was
-productive steps, Y% recompile, Z% restore". The ledger is that
+A bench says how long one restore takes and what MFU one step reaches —
+but nothing there rolls the span stream up into "of the last hour, X%
+was productive steps, Y% recompile, Z% restore". The ledger is that
 accounting layer: every rank-second of the job lands in exactly one
 bucket —
 

@@ -27,25 +27,26 @@ PEAK_FLOPS_BY_KIND = {
     "TPU v6 lite": 918e12,
 }
 
-# fallbacks when the device kind is unknown: a TPU backend defaults to
-# the v5p figure; anything else (CPU dev boxes) to a nominal 1 TFLOP/s
-# so MFU stays a finite, obviously-synthetic number instead of inf/0
-_DEFAULT_TPU_PEAK = 459e12
-_DEFAULT_OTHER_PEAK = 1e12
 
 
 def peak_flops_per_chip(device_kind: str = "",
                         backend: str = "") -> float:
     """Peak bf16 FLOP/s for one chip of ``device_kind`` (longest-prefix
-    table match), falling back by ``backend`` name."""
+    table match). There is no default peak: a TPU whose kind is not in
+    the table is an error (add the kind with its public spec), and any
+    other backend has no peak — 0.0, which :func:`achieved_mfu` reports
+    as -1, "no evidence", rather than a utilization of an invented
+    chip."""
     best = 0.0
     best_len = -1
     for name, flops in PEAK_FLOPS_BY_KIND.items():
         if device_kind.startswith(name) and len(name) > best_len:
             best, best_len = flops, len(name)
-    if best:
-        return best
-    return _DEFAULT_TPU_PEAK if backend == "tpu" else _DEFAULT_OTHER_PEAK
+    if not best and backend == "tpu":
+        raise ValueError(
+            f"no peak FLOP/s on record for TPU device kind "
+            f"{device_kind!r}; add it to PEAK_FLOPS_BY_KIND")
+    return best
 
 
 def flops_per_token(param_count: float, num_layers: int = 0,

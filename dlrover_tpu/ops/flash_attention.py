@@ -33,12 +33,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from dlrover_tpu.common.jax_compat import (
-    get_vma,
-    shape_dtype_struct,
-    shard_map,
-    tpu_compiler_params,
-)
+from dlrover_tpu.ops.backend import on_tpu
 
 NEG_INF = -1e30
 
@@ -59,12 +54,8 @@ DEFAULT_BLOCK_K = 1024
 
 # Grid axes (batch, heads, outer-block) are independent; the innermost
 # axis carries the VMEM accumulators and must stay sequential.
-_DIM_SEMANTICS = tpu_compiler_params(
+_DIM_SEMANTICS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
-
-
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _vma(*arrays) -> frozenset:
@@ -73,12 +64,23 @@ def _vma(*arrays) -> frozenset:
     outputs must declare how they vary."""
     u: frozenset = frozenset()
     for a in arrays:
-        u = u | get_vma(a)
+        u = u | jax.typeof(a).vma
     return u
 
 
+def in_manual_region(*arrays) -> bool:
+    """True when tracing inside a shard_map already (the pipeline's
+    pipe-manual stages, the train step's manual grad-reduce axis): the
+    operands are the caller's per-shard blocks, and a nested full-mesh
+    shard_map cannot be traced there. The abstract mesh's manual axes
+    cover regions traced with check_vma=False, whose avals carry no
+    vma."""
+    return bool(_vma(*arrays)
+                or jax.sharding.get_abstract_mesh().manual_axes)
+
+
 def _sds(shape, dtype, vma):
-    return shape_dtype_struct(shape, dtype, vma=vma)
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
 def _cdiv(a: int, b: int) -> int:
@@ -236,7 +238,7 @@ def _flash_fwd(q, k, v, sm_scale: float, causal: bool,
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
         compiler_params=_DIM_SEMANTICS,
-        interpret=_use_interpret(),
+        interpret=not on_tpu(),
     )(q, k, v)
     return out, lse
 
@@ -392,7 +394,7 @@ def _flash_bwd(res, g, *, sm_scale: float, causal: bool,
         out_shape=_sds(q.shape, q.dtype, _vma(q, k, v, do)),
         scratch_shapes=[pltpu.VMEM((block_q, head_dim), jnp.float32)],
         compiler_params=_DIM_SEMANTICS,
-        interpret=_use_interpret(),
+        interpret=not on_tpu(),
     )(q, k, v, do, lse, delta)
 
     # ---- dk/dv: per q-head contributions, iterate q blocks innermost --
@@ -456,7 +458,7 @@ def _flash_bwd(res, g, *, sm_scale: float, causal: bool,
             pltpu.VMEM((block_k, head_dim), jnp.float32),
         ],
         compiler_params=_DIM_SEMANTICS,
-        interpret=_use_interpret(),
+        interpret=not on_tpu(),
     )(q, k, v, do, lse, delta)
 
     if group > 1:
@@ -559,11 +561,7 @@ def mesh_flash_attention(q, k, v, causal: bool = True,
     mesh = current_mesh()
     if mesh is None:
         return flash_attention(q, k, v, causal, sm_scale)
-    # Inside an already-manual region (e.g. the pipeline's pipe-manual
-    # shard_map) a nested full-mesh shard_map cannot be traced (mesh
-    # mismatch / interpret-mode carry typing) — call the kernel directly;
-    # its operands there are the caller's per-shard blocks.
-    if _vma(q, k, v):
+    if in_manual_region(q, k, v):
         return flash_attention(q, k, v, causal, sm_scale)
     # foreign ambient meshes (no data/fsdp/tensor axes) fall through to
     # the plain call via the dp == tp == 1 check
@@ -575,7 +573,7 @@ def mesh_flash_attention(q, k, v, causal: bool = True,
     if (q.shape[0] % dp or q.shape[1] % tp or k.shape[1] % tp):
         return flash_attention(q, k, v, causal, sm_scale)
     spec = P((MeshAxis.DATA, MeshAxis.FSDP), MeshAxis.TENSOR, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda a, b, c: flash_attention(a, b, c, causal, sm_scale),
         mesh=mesh,
         in_specs=(spec, spec, spec),

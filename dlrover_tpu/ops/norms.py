@@ -9,17 +9,14 @@ custom VJP with a fused backward.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from dlrover_tpu.common.jax_compat import shape_dtype_struct
-from dlrover_tpu.ops.flash_attention import _vma
-
-
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+from dlrover_tpu.ops.backend import on_tpu
+from dlrover_tpu.ops.flash_attention import _sds, _vma, in_manual_region
 
 
 def _rms_fwd_kernel(x_ref, w_ref, o_ref, rstd_ref, *, eps: float):
@@ -100,11 +97,10 @@ def _rms_fwd(x, weight, eps):
             pl.BlockSpec((block, 1), lambda i: (i, 0)),
         ],
         out_shape=[
-            shape_dtype_struct(x2.shape, x.dtype, vma=_vma(x2, weight)),
-            shape_dtype_struct((rows, 1), jnp.float32,
-                               vma=_vma(x2, weight)),
+            _sds(x2.shape, x.dtype, _vma(x2, weight)),
+            _sds((rows, 1), jnp.float32, _vma(x2, weight)),
         ],
-        interpret=_use_interpret(),
+        interpret=not on_tpu(),
     )(x2, weight)
     return out.reshape(orig_shape), (x2, weight, rstd, orig_shape)
 
@@ -134,18 +130,59 @@ def _rms_bwd_vjp(eps, res, g):
             pl.BlockSpec((8, dim), lambda i: (0, 0)),
         ],
         out_shape=[
-            shape_dtype_struct(x2.shape, x2.dtype,
-                               vma=_vma(x2, weight, g2)),
-            shape_dtype_struct((8, dim), jnp.float32,
-                               vma=_vma(x2, weight, g2)),
+            _sds(x2.shape, x2.dtype, _vma(x2, weight, g2)),
+            _sds((8, dim), jnp.float32, _vma(x2, weight, g2)),
         ],
-        interpret=_use_interpret(),
+        interpret=not on_tpu(),
     )(x2, weight, rstd, g2)
     dw = dw_partial.sum(axis=0).astype(weight.dtype)
     return dx.reshape(orig_shape), dw
 
 
 fused_rms_norm.defvjp(_rms_fwd_vjp, _rms_bwd_vjp)
+
+
+def mesh_rms_norm(x: jax.Array, weight: jax.Array,
+                  eps: float = 1e-6) -> jax.Array:
+    """fused_rms_norm partitioned over the ambient mesh.
+
+    A Pallas kernel is a custom call the SPMD partitioner cannot split
+    on real TPU ("Mosaic kernels cannot be automatically partitioned"),
+    so under a multi-device mesh it runs inside a full-mesh shard_map:
+    rows follow the activation layout (dim 0 over the joint dp axes,
+    dim 1 over `sequence`; a dim the axes do not divide stays whole and
+    is computed redundantly), the hidden dim and the weight are whole on
+    every device. The custom VJP is INSIDE the shard_map, so its
+    transpose psums each shard's partial weight gradient over the whole
+    mesh — `_rms_bwd_kernel` only ever sums the rows of its own call.
+    Plain call when there is no ambient mesh, on one device, or inside
+    an already-manual region (see `in_manual_region`)."""
+    from jax.sharding import PartitionSpec as P
+
+    from dlrover_tpu.common.constants import MeshAxis
+    from dlrover_tpu.parallel.mesh import current_mesh, data_axes
+
+    mesh = current_mesh()
+    if mesh is None or mesh.size == 1 or in_manual_region(x, weight):
+        return fused_rms_norm(x, weight, eps)
+
+    def fit(dim: int, axes):
+        axes = tuple(a for a in axes if mesh.shape.get(a, 1) > 1)
+        ways = math.prod(mesh.shape[a] for a in axes)
+        return axes if axes and x.shape[dim] % ways == 0 else None
+
+    rows = [fit(0, data_axes(mesh))] if x.ndim >= 2 else []
+    if x.ndim >= 3:
+        rows.append(fit(1, (MeshAxis.SEQUENCE,)))
+    spec = P(*rows)
+    fn = jax.shard_map(
+        lambda a, w: fused_rms_norm(a, w, eps),
+        mesh=mesh,
+        in_specs=(spec, P()),
+        out_specs=spec,
+        check_vma=False,
+    )
+    return fn(x, weight)
 
 
 def reference_rms_norm(x, weight, eps: float = 1e-6):

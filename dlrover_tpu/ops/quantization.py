@@ -27,9 +27,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+from dlrover_tpu.ops.backend import on_tpu
 
 
 def _qmax(bits: int) -> int:
@@ -95,7 +93,7 @@ def quantize(x: jax.Array, bits: int = 8, group_size: int = 128
             jax.ShapeDtypeStruct((rows, group_size), jnp.int8),
             jax.ShapeDtypeStruct((rows, 1), jnp.float32),
         ],
-        interpret=_use_interpret(),
+        interpret=not on_tpu(),
     )(x2)
     scales = scales.reshape(orig_shape[:-1] + (groups,))
     q = q.reshape(orig_shape)
@@ -126,7 +124,7 @@ def dequantize(q: jax.Array, scales: jax.Array, bits: int = 8,
         ],
         out_specs=pl.BlockSpec((block, group_size), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, group_size), dtype),
-        interpret=_use_interpret(),
+        interpret=not on_tpu(),
     )(q2, s2)
     return out.reshape(orig_shape)
 

@@ -37,7 +37,6 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from dlrover_tpu.common.constants import MeshAxis
-from dlrover_tpu.common.jax_compat import get_vma, shard_map
 
 
 def _pipeline_local(stage_params, in_store, *, stage_fn, axis_name: str,
@@ -156,7 +155,7 @@ def pipeline_apply(
             num_stages=num_stages, stored_micro=stored)
 
     params_spec = jax.tree.map(lambda _: P(axis), stacked_params)
-    piped = shard_map(
+    piped = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(params_spec, P(axis)),
@@ -170,14 +169,10 @@ def pipeline_apply(
 
 
 def _varying(x, axis_name):
-    """Mark x as varying over the pipe axis (idempotent). On runtimes
-    without vma tracking (no lax.pcast) there is nothing to mark."""
-    if axis_name in get_vma(x):
+    """Mark x as varying over the pipe axis (idempotent)."""
+    if axis_name in jax.typeof(x).vma:
         return x
-    pcast = getattr(lax, "pcast", None)
-    if pcast is None:
-        return x
-    return pcast(x, (axis_name,), to="varying")
+    return lax.pcast(x, (axis_name,), to="varying")
 
 
 def pipeline_train(
@@ -415,7 +410,7 @@ def pipeline_train(
 
     params_spec = jax.tree.map(lambda _: P(None, axis), chunk_params)
     rep = jax.tree.map(lambda _: P(), shared_params)
-    piped = shard_map(
+    piped = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(params_spec, rep, P(), P()),

@@ -40,8 +40,7 @@ from typing import Any, Dict, Iterable, List, Optional, Tuple
 
 # -- scoring model coefficients (documented, deterministic) -----------------
 # Baseline fraction of peak a well-shaped single-axis data-parallel run
-# achieves (BENCH_r05: 0.59-0.70 measured); the per-axis penalties below
-# discount it. These are a coarse analytic prior, not a measurement —
+# achieves; the per-axis penalties below discount it. These are a coarse analytic prior, not a measurement —
 # their job is to RANK candidates consistently, and the ranking is what
 # determinism and the tests pin down.
 _BASE_EFFICIENCY = 0.6

@@ -35,7 +35,7 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from dlrover_tpu.common.constants import MeshAxis
-from dlrover_tpu.common.jax_compat import shard_map
+from dlrover_tpu.ops.backend import on_tpu
 
 _NEG_INF = -1e30
 
@@ -58,7 +58,7 @@ def _use_flash_blocks(block_impl: str) -> bool:
             f"unknown SP block impl {block_impl!r}: "
             "expected auto | flash | einsum")
     return block_impl == "flash" or (
-        block_impl == "auto" and jax.default_backend() == "tpu")
+        block_impl == "auto" and on_tpu())
 
 
 def _block_attn(q, k, v, scale, mask):
@@ -332,7 +332,7 @@ def ring_attention(
     flash on TPU, einsum elsewhere)."""
     scale = sm_scale if sm_scale is not None else q.shape[-1] ** -0.5
     spec = P(batch_axes, axis, head_axis, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(_ring_attn_local, axis_name=axis, causal=causal,
                           scale=scale, block_impl=block_impl),
         mesh=mesh,
@@ -441,7 +441,7 @@ def ulysses_attention(
             f"{tensor_size}")
     scale = sm_scale if sm_scale is not None else q.shape[-1] ** -0.5
     spec = P(batch_axes, axis, head_axis, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(_ulysses_local, axis_name=axis, causal=causal,
                           scale=scale, block_impl=block_impl),
         mesh=mesh,

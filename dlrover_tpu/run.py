@@ -86,9 +86,17 @@ def _detect_devices() -> int:
              "import jax; print(jax.local_device_count())"],
             capture_output=True, text=True, timeout=120,
         )
-        return int(out.stdout.strip().splitlines()[-1])
-    except Exception:
+        if out.returncode == 0:
+            return int(out.stdout.strip().splitlines()[-1])
+        reason = out.stderr.strip()[-400:]
+    except (subprocess.TimeoutExpired, OSError, ValueError,
+            IndexError) as e:
+        reason = repr(e)
+    if os.getenv("JAX_PLATFORMS", "") == "cpu":
         return 1
+    # an accelerator that cannot be probed must not be read as "one
+    # device": the worker would then train on whatever it finds
+    raise RuntimeError(f"device probe failed: {reason}")
 
 
 def run(args: argparse.Namespace) -> int:

@@ -668,11 +668,13 @@ class ElasticTrainLoop:
                 seq_len=self.config.seq_len,
                 uncounted_embed_params=uncounted,
             )
-            device = jax.devices()[0]
+            # the chips THIS loop trains on: the mesh, which is every
+            # device unless the caller handed the loop a subset
+            device = self.mesh.devices.flat[0]
             peak_chip = obs.mfu.peak_flops_per_chip(
                 getattr(device, "device_kind", ""),
                 backend=jax.default_backend())
-            chips = jax.device_count()
+            chips = self.mesh.size
             self._peak_flops_total = peak_chip * max(1, chips)
             if self.client is None:
                 return
@@ -751,7 +753,7 @@ class ElasticTrainLoop:
         self._flops_per_token = adopted
         if self.client is not None:
             try:
-                device = jax.devices()[0]
+                device = self.mesh.devices.flat[0]
                 self.client.report_model_info(
                     param_count=getattr(self, "_param_count", 0),
                     param_bytes=getattr(self, "_param_bytes", 0),
@@ -763,7 +765,7 @@ class ElasticTrainLoop:
                     peak_flops_per_chip=obs.mfu.peak_flops_per_chip(
                         getattr(device, "device_kind", ""),
                         backend=jax.default_backend()),
-                    chips=jax.device_count(),
+                    chips=self.mesh.size,
                     flops_source="cost_analysis",
                 )
             except Exception:  # noqa: BLE001 — stats are advisory

@@ -147,8 +147,17 @@ def build_streaming_trainer(
     devices: Any = None,
 ) -> StreamingTrainer:
     """Lower a scan-shaped Llama + per-leaf optimizer into a streaming
-    step. Single-device oriented (the >HBM single-chip escape hatch);
-    multi-chip scale-out composes the ordinary trainers with FSDP/PP."""
+    step. Single-device by definition (the >HBM single-chip escape
+    hatch); multi-chip scale-out composes the ordinary trainers with
+    FSDP/PP. ``devices``: the ONE device to run on (default: the first
+    local device); the state is committed there, so the step follows."""
+    devices = (list(devices) if devices is not None
+               else jax.devices()[:1])
+    if len(devices) != 1:
+        raise ValueError(
+            f"the streaming trainer runs on exactly one device, got "
+            f"{len(devices)}: shard across chips with fsdp / "
+            f"pipeline_parallel instead")
     L = cfg.num_layers
     hidden = cfg.hidden_size
     block = DecoderBlock(cfg)
@@ -324,15 +333,16 @@ def build_streaming_trainer(
         )
         return new_state, {"loss": loss}
 
+    from jax.sharding import SingleDeviceSharding
+
     from dlrover_tpu.parallel.mesh import MeshSpec, create_mesh
 
     return StreamingTrainer(
         config=cfg,
-        init_fn=jax.jit(_init),
+        init_fn=jax.jit(
+            _init, out_shardings=SingleDeviceSharding(devices[0])),
         step_fn=jax.jit(_step, donate_argnums=(0,)),
         micro_batch=micro_batch,
         seq_len=seq_len,
-        mesh=create_mesh(
-            MeshSpec(),
-            (devices if devices is not None else jax.devices())[:1]),
+        mesh=create_mesh(MeshSpec(), devices),
     )

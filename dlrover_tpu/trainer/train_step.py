@@ -91,8 +91,8 @@ class ShardedTrainer:
         """AOT-compile the train step from abstract inputs (trace +
         lower + XLA compile or persistent-cache load), so a respawned
         worker can overlap compilation with its checkpoint read instead
-        of serializing re-jit after it (the measured ~155 s tail of the
-        262 s at-scale restore, docs/benchmarks.md). Safe to call from a
+        of serializing re-jit after it (at scale the compile is the
+        longer of the two). Safe to call from a
         background thread; `step` uses the compiled executable when
         present and falls back to the jitted path on any mismatch."""
         if self._compiled_step is not None or self.batch_abstract is None:
@@ -263,16 +263,13 @@ def build_trainer(
     state_shardings = sanitize_shardings(
         state_shardings, nn.unbox(abstract_boxed), mesh)
     if offload_opt_state:
-        from dlrover_tpu.common.jax_compat import host_memory_kind
-
-        host_kind = host_memory_kind(mesh.devices.flat[0])
         abstract_opt = nn.unbox(abstract_boxed).opt_state
         state_shardings = state_shardings.replace(
             opt_state=jax.tree.map(
                 # scalars (step counters) stay on device: XLA's SPMD
                 # partitioner rejects memory-kind annotations on them
                 lambda s, a: s if a.ndim == 0 else NamedSharding(
-                    mesh, s.spec, memory_kind=host_kind),
+                    mesh, s.spec, memory_kind="pinned_host"),
                 state_shardings.opt_state, abstract_opt,
             ))
     # Batch (accum, micro, seq): micro over the joint dp axes (dcn +
@@ -379,27 +376,10 @@ def build_trainer(
                             if mesh.shape.get(MeshAxis.DCN, 1) > 1
                             else MeshAxis.DATA)
     n_reduce = mesh.shape.get(grad_reduce_axis, 1)
-    from dlrover_tpu.common.jax_compat import HAS_PARTIAL_AUTO, shard_map
-
     # the dcn axis always reduces explicitly (the hierarchical
     # contract), quantized or not; other axes only when quantized
     wrap_reduce = n_reduce > 1 and (
         bool(grad_reduce_bits) or grad_reduce_axis == MeshAxis.DCN)
-    if (wrap_reduce and not HAS_PARTIAL_AUTO
-            and len([a for a, n in mesh.shape.items() if n > 1]) > 1):
-        # the explicit reduce needs a shard_map manual over ONE axis of
-        # a multi-axis mesh; without partial-auto support that program
-        # cannot be built — train exactly instead of not at all (the
-        # flat implicit mean over (dcn, data, fsdp) is numerically the
-        # hierarchical mean of equal-size slice means)
-        from dlrover_tpu.common.log import default_logger
-
-        default_logger.warning(
-            "grad reduce over %r (bits=%d) needs a partial-auto "
-            "shard_map this jax lacks; falling back to the exact flat "
-            "reduce", grad_reduce_axis, grad_reduce_bits)
-        grad_reduce_bits = 0
-        wrap_reduce = False
     if wrap_reduce:
         from jax.sharding import PartitionSpec
 
@@ -430,7 +410,7 @@ def build_trainer(
         state_manual_spec = jax.tree.map(lambda _: PartitionSpec(),
                                          state_shardings)
         batch_manual_spec = PartitionSpec(None, grad_reduce_axis)
-        wrapped = shard_map(
+        wrapped = jax.shard_map(
             _body_local,
             mesh=mesh,
             in_specs=(state_manual_spec, batch_manual_spec,

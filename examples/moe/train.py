@@ -79,6 +79,7 @@ def main(argv=None) -> int:
 
     from dlrover_tpu.models.llama import cross_entropy_loss
     from dlrover_tpu.models.llama_moe import LlamaMoE, LlamaMoEConfig
+    from dlrover_tpu.ops.backend import on_tpu
     from dlrover_tpu.parallel.mesh import MeshSpec
     from dlrover_tpu.trainer.elastic_loop import (
         ElasticTrainLoop,
@@ -109,8 +110,7 @@ def main(argv=None) -> int:
         intermediate_size=args.hidden * 2,
         max_seq_len=args.seq,
         num_experts=args.experts, top_k=args.top_k,
-        attn_impl="flash" if jax.default_backend() == "tpu"
-        else "reference",
+        attn_impl="flash" if on_tpu() else "reference",
     )
 
     client = None

@@ -76,15 +76,14 @@ def main(argv=None) -> int:
 
     from dlrover_tpu.models.gpt import GPT, GPTConfig
     from dlrover_tpu.models.llama import cross_entropy_loss
+    from dlrover_tpu.ops.backend import on_tpu
     from dlrover_tpu.trainer.elastic_loop import (
         ElasticTrainLoop,
         TrainLoopConfig,
     )
     from dlrover_tpu.trainer.sampler import ElasticDistributedSampler
 
-    cfg = GPTConfig.nano(
-        attn_impl="flash" if jax.default_backend() == "tpu"
-        else "reference")
+    cfg = GPTConfig.nano(attn_impl="flash" if on_tpu() else "reference")
     model = GPT(cfg)
 
     client = None

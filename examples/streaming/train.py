@@ -79,6 +79,7 @@ def main(argv=None) -> int:
         LlamaConfig,
         cross_entropy_loss,
     )
+    from dlrover_tpu.ops.backend import on_tpu
     from dlrover_tpu.trainer.elastic_loop import (
         ElasticTrainLoop,
         TrainLoopConfig,
@@ -96,8 +97,7 @@ def main(argv=None) -> int:
         intermediate_size=args.hidden * 2,
         max_seq_len=args.seq,
         tie_embeddings=False,
-        attn_impl="flash" if jax.default_backend() == "tpu"
-        else "reference",
+        attn_impl="flash" if on_tpu() else "reference",
     )
 
     result = auto_accelerate(
@@ -108,6 +108,8 @@ def main(argv=None) -> int:
         sample_batch=np.zeros((args.batch, args.seq), np.int32),
         strategy=["half", ("streaming", {})],
         micro_batch=args.batch,
+        # the streaming trainer is the ONE-chip >HBM path by definition
+        # (auto_accelerate rejects more); across chips use fsdp/pipeline
         devices=jax.devices()[:1],
     )
 

@@ -28,6 +28,22 @@ assert len(jax.devices("cpu")) >= 8, (
 
 import pytest  # noqa: E402
 
+# Every agent, worker and probe a test spawns is handed a persistent
+# compile cache (common/compile_cache.py), by default a fixed directory
+# inside the checkout. Six xdist workers and their children must not fill
+# that with CPU programs: unless the caller named a cache, give each test
+# session a temporary one. The environment variable wins by the helper's
+# own rule. Set AFTER jax's import, which is when jax reads it: this
+# process compiles uncached, as it always has.
+if "JAX_COMPILATION_CACHE_DIR" not in os.environ:
+    import atexit
+    import shutil
+    import tempfile
+
+    _cache = tempfile.mkdtemp(prefix="dlrover-tpu-test-jax-cache-")
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = _cache
+    atexit.register(shutil.rmtree, _cache, ignore_errors=True)
+
 
 @pytest.fixture(scope="session", autouse=True)
 def _graftrace_lockcheck():

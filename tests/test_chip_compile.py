@@ -1,0 +1,163 @@
+"""The main path's kernels and step program, compiled for the chip.
+
+No chip is attached here. The TPU's compiler is installed, and it compiles
+for a DESCRIBED v5e (`on-chip-measurement` guide §2.3): what it refuses —
+a misaligned slice, too much VMEM, a program that does not fit HBM, a
+Mosaic kernel the SPMD partitioner is asked to split — it refuses here, at
+no chip time. A compile that passes is not a chip run; these tests only
+assert that the program lowers and that the kernels are IN it
+(`tpu_custom_call` in the compiled text), not interpreted or swapped for
+the reference.
+
+This is the ONLY test file that describes a topology, and it does so
+inside a module-scoped fixture: one process may load the TPU library, and
+under xdist every worker imports every test file. Nothing here touches
+the topology at import, in a `skipif`, in `parametrize` or in a child
+process. The backend selections (`ops/backend.py`) are steered with
+monkeypatch, never through an option of the program.
+"""
+
+import dataclasses
+import os
+
+import jax
+import jax.numpy as jnp
+import optax
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from dlrover_tpu.models.llama import Llama, LlamaConfig, cross_entropy_loss
+from dlrover_tpu.ops.flash_attention import flash_attention
+from dlrover_tpu.ops.norms import fused_rms_norm, mesh_rms_norm
+from dlrover_tpu.ops.quantization import dequantize, quantize
+from dlrover_tpu.parallel.mesh import MeshSpec, create_mesh, use_mesh
+from dlrover_tpu.trainer.train_step import build_trainer
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        described = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler installed
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a program compiled for a described chip is written to the
+    # persistent cache but cannot be read back without the chip: keep the
+    # cache out of these compiles so later tests stay silent
+    was_enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield described
+    jax.config.update("jax_enable_compilation_cache", was_enabled)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def chip_path(monkeypatch):
+    """Every selection by backend takes its TPU branch: kernels compile
+    (no interpret mode), the model picks the fused norm."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _compiled_text(fn, *args) -> str:
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("kv_heads", [16, 4])
+def test_flash_attention_fwd_bwd(topo, one_chip, chip_path, kv_heads):
+    """Forward + both backward kernels at the 1.47B model's attention
+    shape (micro 4, 16 heads, seq 2048, head 128, bf16); 16/4 is GQA."""
+    q = jax.ShapeDtypeStruct((4, 16, 2048, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((4, kv_heads, 2048, 128), jnp.bfloat16,
+                              sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, True).astype(jnp.float32))
+
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    # forward, dq, dk/dv
+    assert text.count("tpu_custom_call") >= 3
+
+
+@pytest.mark.parametrize("hidden", [2048, 4096])
+def test_fused_rms_norm_fwd_bwd(topo, one_chip, chip_path, hidden):
+    x = jax.ShapeDtypeStruct((2, 2048, hidden), jnp.bfloat16,
+                             sharding=one_chip)
+    w = jax.ShapeDtypeStruct((hidden,), jnp.float32, sharding=one_chip)
+
+    def loss(x, w):
+        return jnp.sum(fused_rms_norm(x, w).astype(jnp.float32))
+
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1)), x, w)
+    assert text.count("tpu_custom_call") >= 2
+
+
+@pytest.mark.parametrize("bits", [8, 4])
+def test_group_quantize_dequantize(topo, one_chip, chip_path, bits):
+    x = jax.ShapeDtypeStruct((2048, 8192), jnp.float32, sharding=one_chip)
+
+    def roundtrip(x):
+        q, scales = quantize(x, bits=bits)
+        return dequantize(q, scales, bits=bits)
+
+    assert _compiled_text(roundtrip, x).count("tpu_custom_call") >= 2
+
+
+def test_shard_mapped_norm_on_four_devices(topo, chip_path):
+    """Called bare under a four-device mesh the fused norm does not lower
+    at all ("Mosaic kernels cannot be automatically partitioned");
+    `mesh_rms_norm` must, with the weight gradient's psum in the
+    program."""
+    mesh = create_mesh(MeshSpec(fsdp=4), topo.devices)
+    x = jax.ShapeDtypeStruct(
+        (8, 2048, 2048), jnp.bfloat16,
+        sharding=NamedSharding(mesh, P(("data", "fsdp"), None, None)))
+    w = jax.ShapeDtypeStruct((2048,), jnp.float32,
+                             sharding=NamedSharding(mesh, P()))
+
+    def loss(x, w):
+        with use_mesh(mesh):
+            return jnp.sum(mesh_rms_norm(x, w).astype(jnp.float32))
+
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1)), x, w)
+    assert text.count("tpu_custom_call") >= 2
+    # dw: one f32[2048] all-reduce over all four devices
+    psums = [line for line in text.splitlines()
+             if "all-reduce(" in line and "f32[2048]" in line]
+    assert psums and all("{{0,1,2,3}}" in line for line in psums), psums
+
+
+@pytest.mark.parametrize("n_devices", [1, 4])
+def test_full_width_train_step(topo, chip_path, n_devices):
+    """One whole step program of the 1.47B Llama at full width (hidden
+    2048, MLP 8192, 16 heads, seq 2048, bf16, flash + fused norm,
+    factored-RMS), depth cut to 2 layers, for one described chip and for
+    a four-chip mesh with the state sharded fsdp=4."""
+    cfg = dataclasses.replace(
+        LlamaConfig.llama_wide_1b(
+            max_seq_len=2048, attn_impl="flash", norm_impl="fused",
+            embed_impl="gather", dtype=jnp.bfloat16),
+        num_layers=2)
+    tx = optax.chain(optax.scale_by_factored_rms(), optax.scale(-3e-4))
+    spec = MeshSpec(fsdp=4) if n_devices == 4 else MeshSpec()
+    mesh = create_mesh(spec, topo.devices[:n_devices])
+    micro = 2 * n_devices
+    trainer = build_trainer(
+        Llama(cfg), tx, mesh, jnp.zeros((micro, 2048), jnp.int32),
+        cross_entropy_loss, accum_steps=1, micro_batch=micro)
+    trainer.precompile()
+    text = trainer._compiled_step.as_text()
+    # per layer: 2 norms + attention, forward and backward; final norm
+    assert text.count("tpu_custom_call") >= 2 * (2 * 2 + 1)
+    if n_devices == 4:
+        assert "all-gather" in text

@@ -162,3 +162,104 @@ class TestMessageSecurity:
         )
         with pytest.raises(Exception):
             deserialize_message(payload)
+
+
+class TestCompileCacheDir:
+    """One rule for where the persistent compile cache lives
+    (common/compile_cache.py): the path is part of every entry's key, so
+    it must not move between launches."""
+
+    def test_env_var_wins(self, monkeypatch, tmp_path):
+        from dlrover_tpu.common import compile_cache
+
+        monkeypatch.setenv(compile_cache.ENV, str(tmp_path / "given"))
+        assert compile_cache.compile_cache_dir() == str(tmp_path / "given")
+
+    def test_fixed_path_inside_checkout(self, monkeypatch):
+        import tempfile
+
+        from dlrover_tpu.common import compile_cache
+
+        monkeypatch.delenv(compile_cache.ENV, raising=False)
+        first = compile_cache.compile_cache_dir()
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert first == os.path.join(repo, ".jax_cache")
+        assert first == compile_cache.compile_cache_dir()
+        # never a temp name, a pid or a time
+        assert not first.startswith(tempfile.gettempdir() + os.sep)
+        assert str(os.getpid()) not in first
+        # ... and git ignores it
+        with open(os.path.join(repo, ".gitignore")) as f:
+            assert ".jax_cache/" in f.read().split()
+
+    @pytest.mark.parametrize("preset", [False, True])
+    def test_agent_hands_it_to_the_worker(self, monkeypatch, tmp_path,
+                                          preset):
+        """The worker's environment carries the helper's path — the same
+        one on every launch — unless the launcher's environment already
+        names a cache, which then wins untouched."""
+        import sys
+
+        from dlrover_tpu.agent.elastic_agent import ElasticAgent, WorkerSpec
+        from dlrover_tpu.agent.master_client import MasterClient
+        from dlrover_tpu.common import compile_cache
+        from dlrover_tpu.master.job_master import JobMaster
+
+        given = str(tmp_path / "given")
+        if preset:
+            monkeypatch.setenv(compile_cache.ENV, given)
+        else:
+            monkeypatch.delenv(compile_cache.ENV, raising=False)
+        expected = given if preset else compile_cache.compile_cache_dir()
+        master = JobMaster(min_nodes=1, max_nodes=1, host="127.0.0.1")
+        master.prepare()
+        seen = []
+        try:
+            for launch in range(2):
+                out = tmp_path / f"seen-{launch}"
+                client = MasterClient(master.addr, node_id=0, node_rank=0)
+                agent = ElasticAgent(client, WorkerSpec(
+                    entrypoint=[
+                        sys.executable, "-c",
+                        "import os; open(%r, 'w').write(os.environ[%r])"
+                        % (str(out), compile_cache.ENV)],
+                    monitor_interval_s=0.1, enable_monitors=False))
+                try:
+                    assert agent.run() == 0
+                finally:
+                    agent.shutdown()
+                    client.close()
+                seen.append(out.read_text())
+        finally:
+            master.stop()
+        assert seen == [expected, expected]
+
+    def test_network_check_probe_uses_it(self, monkeypatch):
+        from dlrover_tpu.common import compile_cache
+        from dlrover_tpu.diagnostics import network_check
+
+        monkeypatch.delenv(compile_cache.ENV, raising=False)
+
+        class _Client:
+            node_rank = 0
+
+            def join_rendezvous(self, *a):
+                return 0
+
+            def get_comm_world(self, name):
+                return 0, 0, {0: 1}
+
+            def kv_set(self, key, value):
+                pass
+
+        captured = {}
+
+        def _run(cmd, env=None, timeout=None):
+            captured.update(env)
+            raise network_check.subprocess.TimeoutExpired(cmd, timeout)
+
+        monkeypatch.setattr(network_check.subprocess, "run", _run)
+        normal, _ = network_check._probe_round(_Client(), 1, timeout_s=5.0)
+        assert not normal
+        assert captured[compile_cache.ENV] == \
+            compile_cache.compile_cache_dir()

@@ -11,7 +11,6 @@ import numpy as np
 import optax
 import pytest
 
-from dlrover_tpu.common.jax_compat import HAS_PARTIAL_AUTO
 from dlrover_tpu.models.llama import Llama, LlamaConfig, cross_entropy_loss
 from dlrover_tpu.parallel.mesh import MeshSpec
 from dlrover_tpu.trainer.elastic_loop import ElasticTrainLoop, TrainLoopConfig
@@ -102,9 +101,6 @@ def test_global_batch_held_fixed():
         assert micro // dp <= 4
 
 
-@pytest.mark.skipif(
-    not HAS_PARTIAL_AUTO,
-    reason="pipeline needs partial-auto shard_map (jax.shard_map)")
 def test_pipeline_trainer_through_elastic_loop(cpu_devices, tmp_path):
     """PP is elastic too: the loop drives a PipelinedTrainer (external
     trainer surface) with flash checkpointing, and a fresh loop resumes

@@ -288,8 +288,11 @@ class TestMfuMath:
         assert obs.mfu.peak_flops_per_chip("TPU v5 lite") == 197e12
         assert obs.mfu.peak_flops_per_chip("TPU v5p") == 459e12
         assert obs.mfu.peak_flops_per_chip("TPU v4i") == 275e12
-        assert obs.mfu.peak_flops_per_chip("", backend="tpu") == 459e12
-        assert obs.mfu.peak_flops_per_chip("", backend="cpu") == 1e12
+        # no default peak: an unknown TPU is an error, a non-TPU backend
+        # has no peak at all (achieved_mfu reads 0.0 as "no evidence")
+        with pytest.raises(ValueError, match="TPU v9"):
+            obs.mfu.peak_flops_per_chip("TPU v9", backend="tpu")
+        assert obs.mfu.peak_flops_per_chip("cpu", backend="cpu") == 0.0
 
     def test_achieved_mfu_golden_and_sentinels(self):
         # 1000 tok/s × 2e9 FLOPs/tok over a 4e12 peak = 0.5 MFU

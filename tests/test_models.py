@@ -7,7 +7,6 @@ import numpy as np
 import optax
 import pytest
 
-from dlrover_tpu.common.jax_compat import LEGACY_JAX
 from dlrover_tpu.models.gpt import GPT, GPTConfig
 from dlrover_tpu.models.llama import (
     Llama,
@@ -216,9 +215,6 @@ class TestBert:
         assert np.isfinite(float(full)) and np.isfinite(float(masked))
         assert float(mlm_loss(logits, tokens, jnp.zeros((2, 16)))) == 0.0
 
-    @pytest.mark.skipif(
-        LEGACY_JAX,
-        reason="multi-axis collective reduction order on the legacy XLA SPMD partitioner drifts beyond the tuned tolerance")
     def test_sharded_training_on_mesh(self, cpu_devices):
         """The same strategy table applies to encoders: fsdp x tensor
         mesh losses match the single-device oracle."""

@@ -139,7 +139,7 @@ loop.close()
 
 
 def test_scale_down_mid_run_through_cli(tmp_path):
-    """Elastic scale-DOWN e2e (VERDICT r3 item 6, the reference's core
+    """Elastic scale-DOWN e2e (the reference's core
     recovery claim, README.md:55-61): two agents train at world=2 (min
     1); one AGENT process group is SIGKILLed (agent + its worker — no
     failure RPC ever reaches the master). The master's liveness reaper

@@ -12,7 +12,11 @@ from dlrover_tpu.ops.flash_attention import (
     flash_attention,
     reference_attention,
 )
-from dlrover_tpu.ops.norms import fused_rms_norm, reference_rms_norm
+from dlrover_tpu.ops.norms import (
+    fused_rms_norm,
+    mesh_rms_norm,
+    reference_rms_norm,
+)
 
 
 def _qkv(batch=1, heads=2, kv_heads=None, seq=128, dim=64, dtype=jnp.float32,
@@ -160,6 +164,80 @@ class TestFusedRmsNorm:
         f = jax.jit(lambda x: fused_rms_norm(x, w).sum())
         assert np.isfinite(float(f(x)))
         assert np.isfinite(float(jax.jit(jax.grad(f))(x).sum()))
+
+
+class TestMeshRmsNorm:
+    """The fused norm inside a full-mesh shard_map (the only way a Mosaic
+    kernel lowers under a multi-chip mesh): the kernel's backward sums dw
+    over the rows of ITS call, so each shard holds a partial weight
+    gradient and the shard_map transpose must psum it over the mesh. A
+    missing psum crashes nothing — only these gradients show it."""
+
+    @pytest.mark.parametrize("spec,shape", [
+        # rows split four ways, nothing replicated
+        (dict(data=2, fsdp=2), (8, 16, 128)),
+        # rows split over fsdp AND sequence
+        (dict(fsdp=2, sequence=2), (4, 16, 128)),
+        # tensor replicates the rows: its shards compute the same block
+        # twice, and dw must still come out once, not doubled
+        (dict(fsdp=2, tensor=2), (4, 16, 128)),
+        # a batch the dp axes do not divide stays whole on every device
+        (dict(data=4), (3, 16, 128)),
+        # 2-D input: rows only
+        (dict(fsdp=4), (32, 128)),
+    ])
+    def test_grads_match_reference(self, cpu_devices, spec, shape):
+        from dlrover_tpu.parallel.mesh import MeshSpec, create_mesh, use_mesh
+
+        mesh = create_mesh(MeshSpec(**spec), cpu_devices[:4])
+        x = jax.random.normal(jax.random.PRNGKey(0), shape)
+        w = jax.random.normal(jax.random.PRNGKey(1), shape[-1:]) + 1.0
+        # a cotangent that differs per row, so a shard's partial dw is
+        # visibly not the total
+        c = jax.random.normal(jax.random.PRNGKey(2), shape)
+
+        def loss_mesh(x, w):
+            with use_mesh(mesh):
+                return jnp.sum(mesh_rms_norm(x, w) * c)
+
+        def loss_ref(x, w):
+            return jnp.sum(reference_rms_norm(x, w) * c)
+
+        with use_mesh(mesh):
+            out = jax.jit(mesh_rms_norm)(x, w)
+        np.testing.assert_allclose(out, reference_rms_norm(x, w),
+                                   atol=1e-5, rtol=1e-5)
+        gx, gw = jax.jit(jax.grad(loss_mesh, argnums=(0, 1)))(x, w)
+        gx_r, gw_r = jax.grad(loss_ref, argnums=(0, 1))(x, w)
+        np.testing.assert_allclose(gx, gx_r, atol=1e-4, rtol=1e-4)
+        np.testing.assert_allclose(gw, gw_r, atol=1e-4, rtol=1e-4)
+
+    def test_plain_kernel_off_mesh_and_in_manual_region(self, cpu_devices):
+        """No ambient mesh → the plain kernel; inside an already-manual
+        region (pipeline stages, the manual grad-reduce axis) a nested
+        full-mesh shard_map cannot be traced → the plain kernel on the
+        caller's per-shard block."""
+        from jax.sharding import PartitionSpec as P
+
+        from dlrover_tpu.parallel.mesh import MeshSpec, create_mesh, use_mesh
+
+        x = jax.random.normal(jax.random.PRNGKey(0), (8, 128))
+        w = jnp.ones((128,)) * 1.5
+        np.testing.assert_allclose(mesh_rms_norm(x, w),
+                                   reference_rms_norm(x, w),
+                                   atol=1e-5, rtol=1e-5)
+        mesh = create_mesh(MeshSpec(data=2, fsdp=2), cpu_devices[:4])
+
+        def body(xs, ws):
+            return mesh_rms_norm(xs, ws)
+
+        with use_mesh(mesh):
+            out = jax.jit(jax.shard_map(
+                body, mesh=mesh, in_specs=(P("data"), P()),
+                out_specs=P("data"), axis_names=frozenset({"data"}),
+                check_vma=False))(x, w)
+        np.testing.assert_allclose(out, reference_rms_norm(x, w),
+                                   atol=1e-5, rtol=1e-5)
 
 
 class TestMeshFlashAttention:

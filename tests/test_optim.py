@@ -203,16 +203,10 @@ class TestOffloadOptimizer:
                                 .opt_state))
             if leaf.ndim > 0
         }
-        from dlrover_tpu.common.jax_compat import host_memory_kind
-
-        assert moment_kinds == {host_memory_kind(cpu_devices[0])}
+        assert moment_kinds == {"pinned_host"}
         # scalars (step counters) and params stay in the device's default
-        # memory ("device" on modern backends; legacy CPU backends call
-        # their only memory space "unpinned_host")
-        default_kind = (cpu_devices[0].default_memory().kind
-                        if hasattr(cpu_devices[0], "default_memory")
-                        else "device")
-        assert all(s.memory_kind == default_kind
+        # memory
+        assert all(s.memory_kind == "device"
                    for s in jax.tree.leaves(shardings.params))
 
         if jax.default_backend() != "tpu":

@@ -9,7 +9,6 @@ import optax
 import pytest
 
 from dlrover_tpu.common.constants import MeshAxis
-from dlrover_tpu.common.jax_compat import LEGACY_JAX
 from dlrover_tpu.models.llama import Llama, LlamaConfig, cross_entropy_loss
 from dlrover_tpu.parallel.mesh import MeshSpec, create_mesh, dp_size
 from dlrover_tpu.parallel.sharding import make_sharding_rules
@@ -17,11 +16,6 @@ from dlrover_tpu.trainer.train_step import (
     build_trainer,
     choose_accumulation,
 )
-
-_LEGACY_MESH_SKIP = pytest.mark.skipif(
-    LEGACY_JAX,
-    reason="multi-axis collective reduction order on the legacy XLA "
-           "SPMD partitioner drifts beyond the tuned tolerance")
 
 
 class TestMeshSpec:
@@ -134,12 +128,9 @@ class TestShardedTraining:
 
     @pytest.mark.parametrize("spec", [
         MeshSpec(data=8),                       # pure DP
-        # multi-axis meshes: the legacy partitioner's collective
-        # reduction order drifts beyond the tuned tolerance
-        pytest.param(MeshSpec(data=2, fsdp=4), marks=_LEGACY_MESH_SKIP),
-        pytest.param(MeshSpec(fsdp=2, tensor=4), marks=_LEGACY_MESH_SKIP),
-        pytest.param(MeshSpec(data=2, fsdp=2, tensor=2),
-                     marks=_LEGACY_MESH_SKIP),
+        MeshSpec(data=2, fsdp=4),
+        MeshSpec(fsdp=2, tensor=4),
+        MeshSpec(data=2, fsdp=2, tensor=2),
     ])
     def test_sharded_matches_single_device(self, cpu_devices, spec):
         mesh1 = create_mesh(MeshSpec(data=1), cpu_devices[:1])
@@ -205,9 +196,6 @@ class TestShardedTraining:
         np.testing.assert_allclose(losses_big, losses_acc, atol=1e-4,
                                    rtol=1e-4)
 
-    @pytest.mark.skipif(
-        LEGACY_JAX,
-        reason="the legacy SPMD partitioner hits involuntary remat on this lowering")
     def test_clean_spmd_lowering_on_3d_mesh(self, cpu_devices, capfd):
         """The (data, fsdp, tensor) lowering must not hit XLA's
         'Involuntary full rematerialization' fallback — that warning means

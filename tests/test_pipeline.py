@@ -6,19 +6,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from dlrover_tpu.common.jax_compat import HAS_PARTIAL_AUTO
 from dlrover_tpu.parallel.mesh import MeshSpec, create_mesh
 from dlrover_tpu.parallel.pipeline import (
     pipeline_apply,
     sequential_oracle,
     stack_stage_params,
 )
-
-# the pipeline is shard_map-manual over ONE axis of a multi-axis mesh;
-# old jax (no jax.shard_map) cannot build that program
-pytestmark = pytest.mark.skipif(
-    not HAS_PARTIAL_AUTO,
-    reason="pipeline needs partial-auto shard_map (jax.shard_map)")
 
 
 def mlp_stage(params, x):

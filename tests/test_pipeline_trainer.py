@@ -9,17 +9,10 @@ import optax
 import pytest
 
 from dlrover_tpu.common.constants import MeshAxis
-from dlrover_tpu.common.jax_compat import HAS_PARTIAL_AUTO
 from dlrover_tpu.models.gpt import GPTConfig
 from dlrover_tpu.models.llama import LlamaConfig, cross_entropy_loss
 from dlrover_tpu.parallel.mesh import MeshSpec, create_mesh
 from dlrover_tpu.trainer.pipeline_trainer import build_pipeline_trainer
-
-# the pipeline is shard_map-manual over ONE axis of a multi-axis mesh;
-# old jax (no jax.shard_map) cannot build that program
-pytestmark = pytest.mark.skipif(
-    not HAS_PARTIAL_AUTO,
-    reason="pipeline needs partial-auto shard_map (jax.shard_map)")
 
 
 def flat_loss(logits, targets):
@@ -97,7 +90,7 @@ class TestPipelinedTrainer:
 
     def test_pp_tensor_parallel_matches_oracle(self, cpu_devices,
                                                llama_cfg, llama_oracle):
-        """PP × TP (VERDICT round-2 weakness 3): tensor=2 under the pipe
+        """PP × TP: tensor=2 under the pipe
         shard_map — column/row-parallel chunk weights compose with the
         pipeline and the losses stay exact."""
         mesh = create_mesh(MeshSpec(tensor=2, pipe=2), cpu_devices[:4])
@@ -125,8 +118,7 @@ class TestPipelinedTrainer:
         np.testing.assert_allclose(losses, base, atol=1e-4, rtol=1e-4)
 
     def test_gpt_pipeline_matches_oracle(self, cpu_devices):
-        """Pipeline lowering is no longer Llama-only (VERDICT round-2
-        weakness 4): the GPT family pipelines via its own spec."""
+        """Pipeline lowering is not Llama-only: the GPT family pipelines via its own spec."""
         cfg = GPTConfig.nano(attn_impl="reference", dtype=jnp.float32)
         mesh1 = create_mesh(MeshSpec(data=1), cpu_devices[:1])
         _, _, base = _run(cfg, mesh1)
@@ -237,7 +229,7 @@ class TestPipelinedTrainer:
         assert "Involuntary full rematerialization" not in captured.err
 
     def test_bert_pipeline_matches_dense(self, cpu_devices):
-        """Encoder (BERT) pipeline spec (VERDICT r3 item 8): the MLM
+        """Encoder (BERT) pipeline spec: the MLM
         objective through the pipeline equals the dense Bert forward on
         identical params."""
         from dlrover_tpu.models.bert import Bert, BertConfig, mlm_loss
@@ -276,7 +268,7 @@ class TestPipelinedTrainer:
         np.testing.assert_allclose(piped, oracle, rtol=2e-4)
 
     def test_offload_opt_state_shardings(self, cpu_devices):
-        """offload_optimizer × pipeline (VERDICT r3 item 8): optimizer
+        """offload_optimizer × pipeline: optimizer
         moments carry pinned_host shardings; scalars and params stay in
         device memory. (Mixed-memory-kind EXECUTION is TPU-only, same
         contract as the dense trainer's offload test.)"""
@@ -312,7 +304,7 @@ class TestPipelinedTrainer:
 
 class TestBf16Pipeline:
     """The bf16 pipeline program must compile and train on the CPU
-    backend (VERDICT r4 weak 4): the blanket fp32 forcing is gone;
+    backend: the blanket fp32 forcing is gone;
     shared params cross the pipe shard_map in fp32 (pvary'd before the
     compute-dtype cast) so their grad psum dodges the XLA-CPU
     half-precision promotion bug while compute stays bf16."""
@@ -357,7 +349,7 @@ class TestBf16Pipeline:
 
 
 class TestBoundedActivations:
-    """1F1B-style memory profile (VERDICT r4 missing 3): with
+    """1F1B-style memory profile: with
     bound_activations the step scan is checkpointed in windows of
     num_stages steps, so live linearization residuals are bound to ~one
     window (~num_stages microbatches) instead of O(num_microbatches) —

@@ -10,7 +10,6 @@ import optax
 import pytest
 from jax import lax
 
-from dlrover_tpu.common.jax_compat import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from dlrover_tpu.models.llama import (
@@ -40,7 +39,7 @@ def test_quantized_pmean_matches_exact(mode, bits):
     # trailing shape to exercise the pad path
     x = rng.normal(size=(n, 63, 65)).astype(np.float32)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(quantized_pmean_leaf, axis_name="data", n=n,
                           bits=bits, mode=mode),
         mesh=mesh, in_specs=P("data"), out_specs=P("data"),
@@ -68,7 +67,7 @@ def test_small_and_int_leaves_reduce_exactly():
     mesh = _data_mesh(n)
     x = jnp.arange(n * 8, dtype=jnp.float32).reshape(n * 8 // 8, 8)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(quantized_pmean_leaf, axis_name="data", n=n,
                           bits=8),
         mesh=mesh, in_specs=P("data"), out_specs=P("data"),

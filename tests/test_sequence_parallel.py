@@ -10,7 +10,6 @@ import pytest
 
 from dlrover_tpu.auto.accelerate import auto_accelerate
 from dlrover_tpu.common.constants import MeshAxis
-from dlrover_tpu.common.jax_compat import LEGACY_JAX
 from dlrover_tpu.models.llama import Llama, LlamaConfig, cross_entropy_loss
 
 BATCH, SEQ, STEPS = 4, 32, 2
@@ -80,9 +79,6 @@ def test_sp_through_auto_accelerate_matches_oracle(
     np.testing.assert_allclose(losses, oracle_losses, rtol=2e-3)
 
 
-@pytest.mark.skipif(
-    LEGACY_JAX,
-    reason="multi-axis collective reduction order on the legacy XLA SPMD partitioner drifts beyond the tuned tolerance")
 def test_sp_composes_with_fsdp(cpu_devices_module, oracle_losses):
     """sequence=2 under fsdp=2: rules + ring shard_map compose."""
     result = _accelerate(

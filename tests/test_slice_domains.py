@@ -1068,7 +1068,7 @@ def test_slice_loss_acceptance_in_process(tmp_path):
 
 # ---------------------------------------------------------------------------
 # slow 2-slice e2e: chaos kills a slice mid-training (satellite:
-# multi-process DCN acceptance, VERDICT item 6)
+# multi-process DCN acceptance)
 # ---------------------------------------------------------------------------
 
 
