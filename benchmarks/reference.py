@@ -1,0 +1,413 @@
+"""The plain reference: a Llama-shaped decoder, its loss, its gradients and
+the factored-RMS update, in straightforward ``jax.numpy`` and float32.
+
+It imports nothing of ``dlrover_tpu`` and takes nothing the program has made:
+weights come from ``--seed`` by the same rule flax uses (a key folded from the
+parameter's path), the rows from the seed and the sampler's documented order.
+No kernels, no cache, no batching tricks. A step runs layer by layer: the
+forward keeps each block's input, the backward recomputes one block at a time
+and applies that block's update at once, so beside the float32 parameters only
+one block's gradients are ever alive and a 2B model fits a 16 GB chip once the
+program's state is freed.
+
+``mode`` is the precision of every matrix product's operands:
+``f32`` (the reference: float32 at ``highest``), ``bf16`` (what the
+configurations state), ``int8`` (the control: the nearest step below bf16,
+operands rounded to 8 bits per row with a straight-through gradient).
+Departures from the published models are listed in each configuration's
+``assumed``.
+"""
+
+from __future__ import annotations
+
+import functools
+import hashlib
+import random
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+HIGHEST = jax.lax.Precision.HIGHEST
+EPSILON = 1e-30          # optax.scale_by_factored_rms defaults
+DECAY_EXPONENT = 0.8
+MIN_DIM_TO_FACTOR = 128
+INIT_STDDEV = 0.02
+
+
+# ---------------------------------------------------------------------------
+# Inputs and weights from the seed
+# ---------------------------------------------------------------------------
+
+
+def token_rows(seed: int, vocab_size: int, rows: int, seq_len: int
+               ) -> np.ndarray:
+    """The token stream the traffic file describes: uniform over the
+    vocabulary, cut into ``rows`` windows of ``seq_len + 1``."""
+    stream = np.random.default_rng(seed).integers(
+        0, vocab_size, rows * (seq_len + 1), dtype=np.int32)
+    return stream.reshape(rows, seq_len + 1)
+
+
+def sampler_order(seed: int, rows: int, shuffle: bool = True) -> list:
+    """Row indices in the order an ``ElasticDistributedSampler(shuffle,
+    seed)`` of one replica deals them in epoch 0 (its documented rule:
+    ``random.Random(seed + epoch).shuffle``)."""
+    order = list(range(rows))
+    if shuffle:
+        random.Random(seed).shuffle(order)
+    return order
+
+
+class Rows:
+    """The rows of one seed and the batches the sampler deals from them."""
+
+    def __init__(self, seed: int, vocab_size: int, rows: int, seq_len: int,
+                 shuffle: bool = True):
+        self.data = token_rows(seed, vocab_size, rows, seq_len)
+        self.order = sampler_order(seed, rows, shuffle)
+
+    def batch(self, index: int, global_batch: int):
+        """(tokens, targets) of the ``index``-th global batch."""
+        picked = self.data[self.order[index * global_batch:
+                                      (index + 1) * global_batch]]
+        return picked[:, :-1], picked[:, 1:]
+
+
+def _flax_fold(path: tuple, count: int) -> int:
+    """What flax's ``Module.param`` folds into the root key for the
+    ``count``-th parameter made in the scope at ``path``: 32 bits of the
+    SHA-1 of the path and the count
+    (``flax.core.scope._fold_in_static``)."""
+    try:
+        import flax
+        separator = bool(flax.config.flax_fix_rng_separator)
+    except Exception:  # noqa: BLE001 - flax absent or renamed: old rule
+        separator = False
+    m = hashlib.sha1()
+    for part in path + (count,):
+        if separator:
+            m.update(b"\00")
+        if isinstance(part, str):
+            m.update(part.encode("utf-8"))
+        else:
+            m.update(part.to_bytes((part.bit_length() + 7) // 8, "big"))
+    return int.from_bytes(m.digest()[:4], "big")
+
+
+def leaf_shapes(cfg: dict) -> dict:
+    """name -> (shape, scope path, count in scope) of every parameter."""
+    h, i, v = cfg["hidden_size"], cfg["intermediate_size"], cfg["vocab_size"]
+    d = cfg.get("head_dim") or h // cfg["num_attention_heads"]
+    q, kv = cfg["num_attention_heads"] * d, cfg["num_key_value_heads"] * d
+    leaves = {"embed": ((v, h), (), 1)}
+    for layer in range(cfg["num_hidden_layers"]):
+        name = f"layer_{layer}"
+        for norm in ("attn_norm", "mlp_norm"):
+            leaves[f"{name}/{norm}/weight"] = ((h,), (name, norm), 1)
+        for proj, shape in (("q_proj", (h, q)), ("k_proj", (h, kv)),
+                            ("v_proj", (h, kv)), ("o_proj", (q, h))):
+            leaves[f"{name}/attn/{proj}/kernel"] = (
+                shape, (name, "attn", proj), 1)
+        for proj, shape in (("gate_proj", (h, i)), ("up_proj", (h, i)),
+                            ("down_proj", (i, h))):
+            leaves[f"{name}/mlp/{proj}/kernel"] = (
+                shape, (name, "mlp", proj), 1)
+    leaves["final_norm/weight"] = ((h,), ("final_norm",), 1)
+    if not cfg.get("tie_word_embeddings"):
+        leaves["lm_head"] = ((h, v), (), 2)
+    return leaves
+
+
+@functools.partial(jax.jit, static_argnums=(2,))
+def _init_leaf(root, fold, shape):
+    # one program per shape: the path's hash is an argument
+    return jax.random.normal(jax.random.fold_in(root, fold), shape,
+                             jnp.float32) * INIT_STDDEV
+
+
+def init_leaf(seed: int, cfg: dict, name: str):
+    shape, path, count = leaf_shapes(cfg)[name]
+    if len(shape) == 1:
+        return jnp.ones(shape, jnp.float32)
+    return _init_leaf(jax.random.PRNGKey(seed),
+                      np.uint32(_flax_fold(path, count)), shape)
+
+
+def init_params(seed: int, cfg: dict) -> dict:
+    return {name: init_leaf(seed, cfg, name) for name in leaf_shapes(cfg)}
+
+
+# ---------------------------------------------------------------------------
+# The model
+# ---------------------------------------------------------------------------
+
+
+def _int8(x, axis: int):
+    """Round to 8 bits along ``axis`` (absmax scale per row); the gradient
+    passes straight through."""
+    scale = jnp.max(jnp.abs(x), axis=axis, keepdims=True) / 127.0
+    scale = jnp.where(scale == 0.0, 1.0, scale)
+    rounded = jnp.clip(jnp.round(x / scale), -127.0, 127.0) * scale
+    return x + jax.lax.stop_gradient(rounded - x)
+
+
+def _product(spec: str, a, b, mode: str, a_axis: int, b_axis: int):
+    """einsum in the given operand precision; ``*_axis`` is each operand's
+    contracted axis (the one an int8 scale runs along)."""
+    if mode == "f32":
+        return jnp.einsum(spec, a, b, precision=HIGHEST)
+    if mode == "bf16":
+        return jnp.einsum(spec, a.astype(jnp.bfloat16),
+                          b.astype(jnp.bfloat16),
+                          preferred_element_type=jnp.float32)
+    if mode == "int8":
+        return jnp.einsum(spec, _int8(a, a_axis), _int8(b, b_axis),
+                          precision=HIGHEST)
+    raise ValueError(f"unknown precision mode {mode!r}")
+
+
+def _linear(x, w, mode):
+    return _product("...k,kn->...n", x, w, mode, -1, 0)
+
+
+def rms_norm(x, weight, eps):
+    return x * jax.lax.rsqrt(
+        jnp.mean(x * x, axis=-1, keepdims=True) + eps) * weight
+
+
+def rope(x, theta: float):
+    """Rotary embedding, halves rotated against each other (the
+    published models' layout), on (batch, seq, heads, head_dim)."""
+    d = x.shape[-1]
+    freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    angles = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * freq
+    cos, sin = jnp.cos(angles)[None, :, None, :], jnp.sin(angles)[None, :, None, :]
+    x1, x2 = x[..., :d // 2], x[..., d // 2:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+
+
+def attention(q, k, v, mode):
+    """Causal softmax attention, grouped-query: (b, s, heads, d) with
+    k and v on fewer heads, each shared by heads/kv_heads queries."""
+    b, s, heads, d = q.shape
+    group = heads // k.shape[2]
+    k = jnp.repeat(k, group, axis=2)
+    v = jnp.repeat(v, group, axis=2)
+    scores = _product("bqhd,bkhd->bhqk", q, k, mode, -1, -1) / np.sqrt(d)
+    mask = jnp.tril(jnp.ones((s, s), bool))
+    scores = jnp.where(mask[None, None], scores, -jnp.inf)
+    probs = jax.nn.softmax(scores, axis=-1)
+    return _product("bhqk,bkhd->bqhd", probs, v, mode, -1, 1)
+
+
+def block(x, p: dict, cfg: dict, mode: str):
+    """One decoder block on (batch, seq, hidden); ``p`` holds the block's
+    nine leaves by their short names."""
+    b, s, _ = x.shape
+    d = cfg.get("head_dim") or cfg["hidden_size"] // cfg["num_attention_heads"]
+    eps, theta = cfg["rms_norm_eps"], cfg["rope_theta"]
+    y = rms_norm(x, p["attn_norm/weight"], eps)
+    q = _linear(y, p["attn/q_proj/kernel"], mode).reshape(b, s, -1, d)
+    k = _linear(y, p["attn/k_proj/kernel"], mode).reshape(b, s, -1, d)
+    v = _linear(y, p["attn/v_proj/kernel"], mode).reshape(b, s, -1, d)
+    out = attention(rope(q, theta), rope(k, theta), v, mode)
+    x = x + _linear(out.reshape(b, s, -1), p["attn/o_proj/kernel"], mode)
+    y = rms_norm(x, p["mlp_norm/weight"], eps)
+    gate = _linear(y, p["mlp/gate_proj/kernel"], mode)
+    up = _linear(y, p["mlp/up_proj/kernel"], mode)
+    return x + _linear(jax.nn.silu(gate) * up, p["mlp/down_proj/kernel"], mode)
+
+
+def head_loss(x, final_norm, head, targets, cfg: dict, mode: str):
+    """Final norm, output head and the mean next-token cross entropy."""
+    logits = _linear(rms_norm(x, final_norm, cfg["rms_norm_eps"]), head, mode)
+    logp = jax.nn.log_softmax(logits, axis=-1)
+    return -jnp.mean(jnp.take_along_axis(logp, targets[..., None], -1))
+
+
+def layer_params(params: dict, layer: int) -> dict:
+    prefix = f"layer_{layer}/"
+    return {k[len(prefix):]: v for k, v in params.items()
+            if k.startswith(prefix)}
+
+
+# ---------------------------------------------------------------------------
+# The optimizer: optax.scale_by_factored_rms then scale(-lr), written out
+# ---------------------------------------------------------------------------
+
+
+def _factored_dims(shape):
+    if len(shape) < 2:
+        return None
+    order = np.argsort(shape)
+    if shape[order[-2]] < MIN_DIM_TO_FACTOR:
+        return None
+    return int(order[-2]), int(order[-1])
+
+
+def init_moment(shape) -> dict:
+    dims = _factored_dims(shape)
+    if dims is None:
+        return {"v": jnp.zeros(shape, jnp.float32)}
+    d1, d0 = dims
+    return {"v_row": jnp.zeros(np.delete(shape, d0), jnp.float32),
+            "v_col": jnp.zeros(np.delete(shape, d1), jnp.float32)}
+
+
+def factored_rms_update(p, g, moment: dict, count, lr: float):
+    """(new parameter, new moment) after one update; ``count`` is the number
+    of updates already made."""
+    decay = 1.0 - (jnp.asarray(count, jnp.float32) + 1.0) ** (-DECAY_EXPONENT)
+    g2 = g * g + EPSILON
+    dims = _factored_dims(p.shape)
+    if dims is None:
+        v = decay * moment["v"] + (1.0 - decay) * g2
+        return p - lr * g * v ** -0.5, {"v": v}
+    d1, d0 = dims
+    v_row = decay * moment["v_row"] + (1.0 - decay) * jnp.mean(g2, axis=d0)
+    v_col = decay * moment["v_col"] + (1.0 - decay) * jnp.mean(g2, axis=d1)
+    reduced_d1 = d1 - 1 if d1 > d0 else d1
+    row_mean = jnp.mean(v_row, axis=reduced_d1, keepdims=True)
+    update = (g * jnp.expand_dims((v_row / row_mean) ** -0.5, d0)
+              * jnp.expand_dims(v_col ** -0.5, d1))
+    return p - lr * update, {"v_row": v_row, "v_col": v_col}
+
+
+def _apply(ps: dict, gs: dict, moments: dict, count, lr):
+    """Update every leaf of one group; also each gradient's norm."""
+    new_p, new_m, norms = {}, {}, {}
+    for name, g in gs.items():
+        new_p[name], new_m[name] = factored_rms_update(
+            ps[name], g, moments[name], count, lr)
+        norms[name] = jnp.sqrt(jnp.sum(g * g))
+    return new_p, new_m, norms
+
+
+# ---------------------------------------------------------------------------
+# One training step, layer by layer
+# ---------------------------------------------------------------------------
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3))
+def _block_forward(x, p, cfg_items, mode):
+    return block(x, p, dict(cfg_items), mode)
+
+
+@functools.partial(jax.jit, static_argnums=(6, 7), donate_argnums=(1, 2))
+def _block_backward(x, p, moments, dy, count, lr, cfg_items, mode):
+    _, vjp = jax.vjp(lambda x_, p_: block(x_, p_, dict(cfg_items), mode), x, p)
+    dx, gp = vjp(dy)
+    new_p, new_m, norms = _apply(p, gp, moments, count, lr)
+    return dx, new_p, new_m, norms
+
+
+@functools.partial(jax.jit, static_argnums=(6, 7), donate_argnums=(1, 2))
+def _head_backward(x, p, moments, targets, count, lr, cfg_items, mode):
+    cfg = dict(cfg_items)
+
+    def f(x_, p_):
+        return head_loss(x_, p_["final_norm/weight"], p_["lm_head"],
+                         targets, cfg, mode)
+
+    loss, (dx, gp) = jax.value_and_grad(f, argnums=(0, 1))(x, p)
+    new_p, new_m, norms = _apply(p, gp, moments, count, lr)
+    return loss, dx, new_p, new_m, norms
+
+
+@functools.partial(jax.jit, donate_argnums=(0, 1))
+def _embed_backward(embed, moment, tokens, dx, count, lr):
+    g = jnp.zeros_like(embed).at[tokens].add(dx)
+    new_p, new_m = factored_rms_update(embed, g, moment, count, lr)
+    return new_p, new_m, jnp.sqrt(jnp.sum(g * g))
+
+
+class Trainer:
+    """The reference's training state and its step. ``cfg`` is a
+    configuration file's dict; untied embeddings only (both configurations'
+    case)."""
+
+    def __init__(self, seed: int, cfg: dict, mode: str = "f32"):
+        if cfg.get("tie_word_embeddings"):
+            raise NotImplementedError("tied embeddings: no cell has them")
+        self.cfg, self.mode, self.seed = cfg, mode, seed
+        self.lr = float(cfg["optimizer"]["learning_rate"])
+        self._items = tuple(sorted(
+            (k, v) for k, v in cfg.items()
+            if isinstance(v, (int, float, bool)) and not isinstance(v, str)))
+        self.params = init_params(seed, cfg)
+        self.moments = {name: init_moment(p.shape)
+                        for name, p in self.params.items()}
+        self.count = 0
+
+    def _store(self, tree: dict, layer: int, group: dict) -> None:
+        for name, value in group.items():
+            tree[f"layer_{layer}/{name}"] = value
+
+    def step(self, tokens, targets) -> dict:
+        """One update on a global batch; returns the loss and every leaf's
+        gradient norm (floats)."""
+        tokens, targets = jnp.asarray(tokens), jnp.asarray(targets)
+        layers = self.cfg["num_hidden_layers"]
+        count = jnp.asarray(self.count, jnp.int32)
+        x = self.params["embed"][tokens]
+        inputs = []
+        for layer in range(layers):
+            inputs.append(x)
+            x = _block_forward(x, layer_params(self.params, layer),
+                               self._items, self.mode)
+        top = ("final_norm/weight", "lm_head")
+        loss, dx, new_p, new_m, norms = _head_backward(
+            x, {k: self.params.pop(k) for k in top},
+            {k: self.moments.pop(k) for k in top},
+            targets, count, self.lr, self._items, self.mode)
+        self.params.update(new_p)
+        self.moments.update(new_m)
+        grad_norms = dict(norms)
+        for layer in reversed(range(layers)):
+            names = list(layer_params(self.params, layer))
+            p = {n: self.params.pop(f"layer_{layer}/{n}") for n in names}
+            m = {n: self.moments.pop(f"layer_{layer}/{n}") for n in names}
+            dx, new_p, new_m, norms = _block_backward(
+                inputs.pop(), p, m, dx, count, self.lr, self._items,
+                self.mode)
+            self._store(self.params, layer, new_p)
+            self._store(self.moments, layer, new_m)
+            for name, value in norms.items():
+                grad_norms[f"layer_{layer}/{name}"] = value
+        embed, moment, norm = _embed_backward(
+            self.params.pop("embed"), self.moments.pop("embed"), tokens, dx,
+            count, self.lr)
+        self.params["embed"], self.moments["embed"] = embed, moment
+        grad_norms["embed"] = norm
+        self.count += 1
+        return {"loss": float(loss),
+                "grad_norms": {k: float(v) for k, v in grad_norms.items()}}
+
+    def change_norms(self) -> dict:
+        """Norm of each leaf's change since the seed's initial value, the
+        initial leaf made again one at a time."""
+        return {name: float(_change_norm(p, init_leaf(self.seed, self.cfg,
+                                                      name)))
+                for name, p in self.params.items()}
+
+
+@jax.jit
+def _change_norm(now, initial):
+    delta = now - initial
+    return jnp.sqrt(jnp.sum(delta * delta))
+
+
+def follow(seed: int, cfg: dict, batches: list, mode: str = "f32",
+           keep_rows=None) -> dict:
+    """Drive a fresh reference through ``batches`` ((tokens, targets) each)
+    and return what the comparison reads: each step's loss, the first
+    gradient's norm by leaf, each leaf's change after the last step.
+    ``keep_rows`` plants a fault: only that many rows of each batch are
+    trained on, the mean taken over them."""
+    trainer = Trainer(seed, cfg, mode)
+    steps = [trainer.step(tokens[:keep_rows], targets[:keep_rows])
+             for tokens, targets in batches]
+    return {"losses": [s["loss"] for s in steps],
+            "grad_norms": steps[0]["grad_norms"],
+            "change_norms": trainer.change_norms()}
