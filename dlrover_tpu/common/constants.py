@@ -245,6 +245,21 @@ class MeshAxis:
     ALL = ("dcn", "data", "fsdp", "tensor", "sequence", "expert", "pipe")
 
 
+class TraceScope:
+    """`jax.named_scope` names of the step program where no Flax module
+    gives one (a module scopes its own call; forward and backward are
+    told apart by JAX's own `jvp(` / `transpose(jvp(` in an op's
+    `op_name`). They reach the compiled program's metadata and, through
+    it, a profiler trace. The trace readers match these names
+    (benchmarks/metrics/step.*_share.py): a contract
+    (docs/observability.md), not labels to reword."""
+
+    EMBED = "embed"              # the token-embedding lookup
+    HEAD_LOSS = "head_loss"      # final norm + head matmul + loss
+    OPTIMIZER = "optimizer"      # tx.update + apply_updates
+    GRAD_ACCUM = "grad_accum"    # the scan over micro-batches
+
+
 class DefaultValues:
     MASTER_PORT = 0                 # 0 → pick a free port
     METRICS_PORT = 0                # /metrics exposition; 0 → free port,

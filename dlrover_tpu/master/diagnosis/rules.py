@@ -506,8 +506,9 @@ class CriticalPathRule(Rule):
     it is looking for. Hysteresis mirrors StragglerRule
     (``straggler_trigger_windows`` to flag,
     ``straggler_clear_windows`` to clear); disabled when the fraction
-    knob is <= 0 or the window has fewer than
-    ``diagnosis_min_worker_samples`` traced steps."""
+    knob is <= 0, the window has fewer than
+    ``diagnosis_min_worker_samples`` traced steps, or no step of the
+    window joined more than one rank."""
 
     name = "critical_path"
 
@@ -524,6 +525,11 @@ class CriticalPathRule(Rule):
             return []
         steps = int(evidence.get("steps", 0))
         if steps < ctx.diagnosis_min_worker_samples:
+            return []
+        if int(evidence.get("ranks", 2)) < 2:
+            # a fleet of one: its only rank "gates" every step with
+            # nobody waiting on it. Flagging it sent a profiler request
+            # into every single-worker run half a minute in.
             return []
         by_rank = evidence.get("by_rank", {}) or {}
         reports: List[DiagnosisReport] = []

@@ -148,9 +148,11 @@ def summarize_solved(solved: List[Dict[str, Any]]) -> Dict[str, Any]:
     this shape into their JSON)."""
     by_rank: Dict[str, Dict[str, Any]] = {}
     frac_sum = 0.0
+    ranks = 0
     for group in solved:
         if not group:
             continue
+        ranks = max(ranks, len(group.get("lanes") or ()))
         rank = str(group.get("gating_rank", -1))
         entry = by_rank.setdefault(
             rank, {"gating_steps": 0, "gating_s": 0.0, "phases": {}})
@@ -176,6 +178,9 @@ def summarize_solved(solved: List[Dict[str, Any]]) -> Dict[str, Any]:
             dominant_phase, _ = _sorted_argmax(phases)
     return {
         "steps": steps,
+        # the most ranks any step of the window joined: a window of one
+        # rank has no critical path to attribute (CriticalPathRule)
+        "ranks": ranks,
         "by_rank": by_rank,
         "dominant_gating_rank": dominant_rank,
         "dominant_gating_phase": dominant_phase,

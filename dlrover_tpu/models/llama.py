@@ -22,6 +22,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from dlrover_tpu.common.constants import TraceScope
 from dlrover_tpu.ops.backend import on_tpu
 from dlrover_tpu.ops.flash_attention import (
     mesh_flash_attention,
@@ -338,7 +339,8 @@ class Llama(nn.Module):
             _logical(nn.initializers.normal(0.02), "vocab", "embed"),
             (cfg.vocab_size, cfg.hidden_size), cfg.param_dtype,
         )
-        x = embed_lookup(embed, tokens, cfg)
+        with jax.named_scope(TraceScope.EMBED):
+            x = embed_lookup(embed, tokens, cfg)
         positions = jnp.broadcast_to(
             jnp.arange(tokens.shape[-1]), tokens.shape)
         block_cls = DecoderBlock
@@ -349,17 +351,23 @@ class Llama(nn.Module):
             )
         for layer in range(cfg.num_layers):
             x = block_cls(cfg, name=f"layer_{layer}")(x, positions)
-        x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.norm_impl, name="final_norm")(x)
-        if cfg.tie_embeddings:
-            logits = jnp.dot(x, embed.astype(cfg.dtype).T)
-        else:
-            head = self.param(
-                "lm_head",
-                _logical(nn.initializers.normal(0.02), "embed", "vocab"),
-                (cfg.hidden_size, cfg.vocab_size), cfg.param_dtype,
-            )
-            logits = jnp.dot(x, head.astype(cfg.dtype))
-        return logits.astype(jnp.float32)
+        # one scope with the loss (trainer/train_step.py opens it again
+        # around `loss_fn`): final norm + head matmul + loss are one
+        # item in a trace's account of the step
+        with jax.named_scope(TraceScope.HEAD_LOSS):
+            x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.norm_impl,
+                        name="final_norm")(x)
+            if cfg.tie_embeddings:
+                logits = jnp.dot(x, embed.astype(cfg.dtype).T)
+            else:
+                head = self.param(
+                    "lm_head",
+                    _logical(nn.initializers.normal(0.02), "embed",
+                             "vocab"),
+                    (cfg.hidden_size, cfg.vocab_size), cfg.param_dtype,
+                )
+                logits = jnp.dot(x, head.astype(cfg.dtype))
+            return logits.astype(jnp.float32)
 
 
 def cross_entropy_loss(logits: jax.Array, targets: jax.Array) -> jax.Array:

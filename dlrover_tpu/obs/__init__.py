@@ -57,6 +57,7 @@ from dlrover_tpu.obs.spans import (
     remove_span_sink,
     span,
 )
+from dlrover_tpu.obs.stepmarks import LoopWindow, StepMarks, StepsInFlight
 from dlrover_tpu.obs.steptrace import (
     TRACE_PHASES,
     ClockSync,
@@ -80,13 +81,16 @@ __all__ = [
     "DeviceTelemetry",
     "FlightRecorder",
     "GoodputLedger",
+    "LoopWindow",
     "MetricsRegistry",
     "ProfilerCapture",
     "ProfilerSession",
     "Span",
     "SpanExporter",
+    "StepMarks",
     "StepTimeline",
     "StepTraceRecorder",
+    "StepsInFlight",
     "TimeSeriesSidecar",
     "TimeSeriesStore",
     "TsdbCollector",

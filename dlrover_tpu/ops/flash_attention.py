@@ -57,6 +57,15 @@ DEFAULT_BLOCK_K = 1024
 _DIM_SEMANTICS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
 
+# Kernel names: each becomes its custom-call's HLO instruction name
+# (`%flash_attn_fwd.3 = ... custom-call(...)`) and so the start of the
+# profiler's event name. The trace readers (benchmarks/metrics/
+# kernels.flash_attn_*_roofline.py) find the kernels by these prefixes:
+# a contract (docs/observability.md), not a label to reword.
+KERNEL_FWD = "flash_attn_fwd"
+KERNEL_DQ = "flash_attn_dq"
+KERNEL_DKV = "flash_attn_dkv"
+
 
 def _vma(*arrays) -> frozenset:
     """Union of the inputs' varying-manual-axes: under a check_vma
@@ -239,6 +248,7 @@ def _flash_fwd(q, k, v, sm_scale: float, causal: bool,
         ],
         compiler_params=_DIM_SEMANTICS,
         interpret=not on_tpu(),
+        name=KERNEL_FWD,
     )(q, k, v)
     return out, lse
 
@@ -395,6 +405,7 @@ def _flash_bwd(res, g, *, sm_scale: float, causal: bool,
         scratch_shapes=[pltpu.VMEM((block_q, head_dim), jnp.float32)],
         compiler_params=_DIM_SEMANTICS,
         interpret=not on_tpu(),
+        name=KERNEL_DQ,
     )(q, k, v, do, lse, delta)
 
     # ---- dk/dv: per q-head contributions, iterate q blocks innermost --
@@ -459,6 +470,7 @@ def _flash_bwd(res, g, *, sm_scale: float, causal: bool,
         ],
         compiler_params=_DIM_SEMANTICS,
         interpret=not on_tpu(),
+        name=KERNEL_DKV,
     )(q, k, v, do, lse, delta)
 
     if group > 1:

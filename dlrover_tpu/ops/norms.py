@@ -18,6 +18,12 @@ from jax.experimental import pallas as pl
 from dlrover_tpu.ops.backend import on_tpu
 from dlrover_tpu.ops.flash_attention import _sds, _vma, in_manual_region
 
+# Kernel names (HLO instruction names, so the start of the profiler's
+# event names): the contract benchmarks/metrics/kernels.rms_norm_roofline.py
+# holds the program to (docs/observability.md).
+KERNEL_FWD = "rms_norm_fwd"
+KERNEL_BWD = "rms_norm_bwd"
+
 
 def _rms_fwd_kernel(x_ref, w_ref, o_ref, rstd_ref, *, eps: float):
     x = x_ref[:].astype(jnp.float32)
@@ -101,6 +107,7 @@ def _rms_fwd(x, weight, eps):
             _sds((rows, 1), jnp.float32, _vma(x2, weight)),
         ],
         interpret=not on_tpu(),
+        name=KERNEL_FWD,
     )(x2, weight)
     return out.reshape(orig_shape), (x2, weight, rstd, orig_shape)
 
@@ -134,6 +141,7 @@ def _rms_bwd_vjp(eps, res, g):
             _sds((8, dim), jnp.float32, _vma(x2, weight, g2)),
         ],
         interpret=not on_tpu(),
+        name=KERNEL_BWD,
     )(x2, weight, rstd, g2)
     dw = dw_partial.sum(axis=0).astype(weight.dtype)
     return dx.reshape(orig_shape), dw

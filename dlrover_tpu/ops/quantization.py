@@ -29,6 +29,11 @@ from jax.experimental import pallas as pl
 
 from dlrover_tpu.ops.backend import on_tpu
 
+# Kernel names (HLO instruction names, so the start of the profiler's
+# event names): a contract for trace readers (docs/observability.md).
+KERNEL_QUANTIZE = "quantize_groupwise"
+KERNEL_DEQUANTIZE = "dequantize_groupwise"
+
 
 def _qmax(bits: int) -> int:
     if bits == 8:
@@ -94,6 +99,7 @@ def quantize(x: jax.Array, bits: int = 8, group_size: int = 128
             jax.ShapeDtypeStruct((rows, 1), jnp.float32),
         ],
         interpret=not on_tpu(),
+        name=KERNEL_QUANTIZE,
     )(x2)
     scales = scales.reshape(orig_shape[:-1] + (groups,))
     q = q.reshape(orig_shape)
@@ -125,6 +131,7 @@ def dequantize(q: jax.Array, scales: jax.Array, bits: int = 8,
         out_specs=pl.BlockSpec((block, group_size), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, group_size), dtype),
         interpret=not on_tpu(),
+        name=KERNEL_DEQUANTIZE,
     )(q2, s2)
     return out.reshape(orig_shape)
 

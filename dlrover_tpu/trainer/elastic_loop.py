@@ -21,10 +21,12 @@ import dataclasses
 import os
 import signal
 import threading
+import time as _time
 from typing import Any, Callable, Dict, Iterable, Optional, Tuple
 
 import jax
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from dlrover_tpu import obs
 from dlrover_tpu.agent.preemption import DrainRequestSource
@@ -227,6 +229,9 @@ class ElasticTrainLoop:
             # transfer primitive, checkpoint/peer_restore.py)
             self._peer_restorer.stripe = True
         self._chaos = None  # built lazily: env may be set post-init
+        # completion accounting of the running loop (obs/stepmarks.py);
+        # a fresh one per run()
+        self._flight = obs.StepsInFlight()
         self._prev_sigterm = None
         # per-step phase attribution (data-wait / h2d / compute /
         # checkpoint), exported beside the metrics file for the agent +
@@ -239,6 +244,7 @@ class ElasticTrainLoop:
             rank=int(os.environ.get(NodeEnv.NODE_RANK, "-1")))
         self._timeline_path = os.environ.get(NodeEnv.TIMELINE_FILE, "")
         self._timeline_exported_at = 0.0
+        self._progress_logged_at = float("-inf")
         # data-pipeline auto-tune (data/prefetch.py): fed the timeline's
         # windowed data_wait fraction at each progress report; the input
         # pipeline consumes `prefetch_tuner.depth_fn` (and its ring
@@ -409,7 +415,6 @@ class ElasticTrainLoop:
 
         if not Context.singleton().replan_enabled:
             return
-        import time as _time
 
         t0 = _time.monotonic()
         plan = None
@@ -800,7 +805,6 @@ class ElasticTrainLoop:
         cache load from the abstract state) so a respawned worker pays
         max(read, compile), not read + compile. Per-phase wall-clock lands
         in `self.last_restore_timings`."""
-        import time as _time
 
         timings: Dict[str, float] = {}
         self.last_restore_timings = timings
@@ -977,105 +981,131 @@ class ElasticTrainLoop:
 
     def _run_inner(self, state, batches, start_step, sampler,
                    raw_metrics):
-        import time as _time
-
         config = self.config
         step = start_step
         if self._chaos is None:
             from dlrover_tpu.diagnostics.chaos import ChaosInjector
 
             self._chaos = ChaosInjector()
-        step_hist = obs.get_registry().histogram(
-            "dlrover_tpu_worker_step_seconds",
-            "Host wall-clock per train-loop iteration (dispatch-bound "
-            "unless a host sync lands in the step)")
         batch_iter = iter(batches)
+        # the step's marks are taken ONCE per iteration (obs/stepmarks.py)
+        # and feed the timeline, steptrace and the train_window span;
+        # each phase is also a profiler annotation, so with a profiler
+        # session on the same intervals lie on the host lines of the
+        # trace, on the device events' clock (with none they cost a flag
+        # test each). `dlrover/...`, never `input`: that name belongs
+        # to whoever feeds the loop.
+        clock = _time.monotonic
+        flight = self._flight = obs.StepsInFlight(clock)
+        window = obs.LoopWindow(first_step=step + 1)
+        boundary = clock()
         while True:
-            # the step BOUNDARY is where a drain request is consumed:
-            # `state` is a complete post-step state here, so the
-            # emergency save never snapshots mid-accumulation
-            drain = self._drain_source.poll()
-            if drain is not None:
-                # the deadline-bounded emergency save can legitimately
-                # block for minutes of Orbax commit: disarm the watchdog
-                # (a save is not a stall), re-arm for save-and-continue
+            marks = obs.StepMarks(clock, TraceAnnotation, started=boundary)
+            with StepTraceAnnotation("dlrover/train_step",
+                                     step_num=step + 1):
+                # the step BOUNDARY is where a drain request is
+                # consumed: `state` is a complete post-step state here,
+                # so the emergency save never snapshots mid-accumulation
+                drain = self._drain_source.poll()
+                if drain is not None:
+                    # the deadline-bounded emergency save can
+                    # legitimately block for minutes of Orbax commit:
+                    # disarm the watchdog (a save is not a stall),
+                    # re-arm for save-and-continue
+                    if self._watchdog is not None:
+                        self._watchdog.stop()
+                    with marks.phase("save", "dlrover/save"):
+                        self._consume_drain(drain, step, state, sampler)
+                    if self._watchdog is not None:
+                        self._watchdog.start()
+                # data-wait measured explicitly: the time this loop
+                # starves on the input pipeline is the diagnosis
+                # engine's "pipeline-bound, not a hardware straggler"
+                # signal
+                try:
+                    with marks.phase("fetch", "dlrover/fetch"):
+                        tokens, targets = next(batch_iter)
+                except StopIteration:
+                    # the data ran out: this iteration's time counts in
+                    # the window, but it is no step
+                    marks.close()
+                    window.add(marks, 0, len(flight), took_step=False)
+                    break
+                if self._trim_batch and len(tokens) > self._trim_batch:
+                    # the re-plan's deliberate batch adjustment: the
+                    # input pipeline still yields the configured batch;
+                    # train on the planned (dp-divisible) prefix.
+                    # Recorded once in the replan_applied event — never
+                    # a silent truncation.
+                    tokens = tokens[:self._trim_batch]
+                    targets = targets[:self._trim_batch]
+                self.profiler.poll(step - start_step)
+                with marks.phase("shard", "dlrover/shard_batch"):
+                    tok, tgt = self.trainer.shard_batch(tokens, targets)
+                with marks.phase("dispatch", "dlrover/dispatch"):
+                    if self._slice_sync is not None:
+                        state, raw_metrics = self._slice_step(
+                            state, tok, tgt, step + 1)
+                    else:
+                        state, raw_metrics = self.trainer.step(
+                            state, tok, tgt)
+                marks.dispatch_done = clock()
+                step += 1
+                # completion accounting: one scalar of this step's
+                # outputs joins the queue, and whatever the device has
+                # finished meanwhile leaves it (is_ready, never a wait)
+                flight.dispatched(next(iter(raw_metrics.values()), None))
+                completed = flight.poll()
+                # scripted fault injection (no-op unless
+                # DLROVER_TPU_CHAOS)
+                self._chaos.maybe_inject(step)
+                if sampler is not None:
+                    # the EFFECTIVE batch (re-plan adjusted when the
+                    # world does not divide the configured one): the
+                    # sampler's position advances by what was actually
+                    # consumed
+                    sampler.record_batch(self.global_batch)
+                if self.checkpointer is not None:
+                    forced = self._stop_requested.is_set()
+                    data_state = self._data_state(sampler)
+                    with marks.phase("save", "dlrover/save"):
+                        saved = self.checkpointer.maybe_save(
+                            step, state, data_state, force=forced,
+                        )
+                    if saved:
+                        # mirror the saved cut into the host-RAM peer
+                        # cache: peer step N and Orbax step N are the
+                        # same cut, so a shard-wise restore across both
+                        # sources stays consistent (with a quantized
+                        # checkpoint the peer copy keeps live precision
+                        # — strictly higher fidelity than the storage
+                        # path's dequantized leaves)
+                        with marks.phase("save", "dlrover/peer_stage"):
+                            self._stage_peer(step, state, data_state)
                 if self._watchdog is not None:
-                    self._watchdog.stop()
-                self._consume_drain(drain, step, state, sampler)
-                if self._watchdog is not None:
-                    self._watchdog.start()
-            # data-wait measured explicitly: the time this loop starves
-            # on the input pipeline is the diagnosis engine's
-            # "pipeline-bound, not a hardware straggler" signal
-            t_step = _time.monotonic()
-            try:
-                tokens, targets = next(batch_iter)
-            except StopIteration:
-                break
-            if self._trim_batch and len(tokens) > self._trim_batch:
-                # the re-plan's deliberate batch adjustment: the input
-                # pipeline still yields the configured batch; train on
-                # the planned (dp-divisible) prefix. Recorded once in
-                # the replan_applied event — never a silent truncation.
-                tokens = tokens[:self._trim_batch]
-                targets = targets[:self._trim_batch]
-            t_data = _time.monotonic()
-            self.profiler.poll(step - start_step)
-            tok, tgt = self.trainer.shard_batch(tokens, targets)
-            if self._slice_sync is not None:
-                state, raw_metrics = self._slice_step(state, tok, tgt,
-                                                      step + 1)
-            else:
-                state, raw_metrics = self.trainer.step(state, tok, tgt)
-            step += 1
-            # scripted fault injection (no-op unless DLROVER_TPU_CHAOS)
-            self._chaos.maybe_inject(step)
-            if sampler is not None:
-                # the EFFECTIVE batch (re-plan adjusted when the world
-                # does not divide the configured one): the sampler's
-                # position advances by what was actually consumed
-                sampler.record_batch(self.global_batch)
-            t_compute_end = _time.monotonic()
-            # from AFTER the batch fetch, as before the timeline landed:
-            # this series' meaning (dispatch-bound step time) must not
-            # silently absorb data wait — that lives in the timeline and
-            # the data_wait_fraction gauge
-            step_hist.observe(t_compute_end - t_data)
-            ckpt_s = 0.0
-            if self.checkpointer is not None:
-                forced = self._stop_requested.is_set()
-                data_state = self._data_state(sampler)
-                saved = self.checkpointer.maybe_save(
-                    step, state, data_state, force=forced,
+                    self._watchdog.notify_step(step)
+                self.device_telemetry.on_step(step)
+                # the timeline and steptrace see the step as it stands
+                # before the report-cadence work, which reads them
+                seconds = marks.seconds
+                self.timeline.record(
+                    step, marks.elapsed(),
+                    data_wait=seconds["fetch"], h2d=seconds["shard"],
+                    compute=seconds["dispatch"],
+                    checkpoint=seconds["save"],
                 )
-                if saved:
-                    # mirror the saved cut into the host-RAM peer
-                    # cache: peer step N and Orbax step N are the same
-                    # cut, so a shard-wise restore across both sources
-                    # stays consistent (with a quantized checkpoint the
-                    # peer copy keeps live precision — strictly higher
-                    # fidelity than the storage path's dequantized
-                    # leaves)
-                    self._stage_peer(step, state, data_state)
-                ckpt_s = _time.monotonic() - t_compute_end
-            if self._watchdog is not None:
-                self._watchdog.notify_step(step)
-            self.device_telemetry.on_step(step)
-            self.timeline.record(
-                step, _time.monotonic() - t_step,
-                data_wait=t_data - t_step,
-                h2d=getattr(self.trainer, "last_shard_batch_s", 0.0),
-                compute=getattr(self.trainer, "last_step_dispatch_s",
-                                t_compute_end - t_data),
-                checkpoint=ckpt_s,
-            )
-            if self._steptrace is not None:
-                self._record_steptrace(step, t_step, t_data,
-                                       t_compute_end, ckpt_s)
-            if (self.client is not None
-                    and step % config.report_interval_steps == 0):
-                self._report_progress(step)
-                self._flush_telemetry()
+                if self._steptrace is not None:
+                    self._record_steptrace(step, marks)
+                due = step % config.report_interval_steps == 0
+                if due and self.client is not None:
+                    with marks.phase("report", "dlrover/report"):
+                        self._report_progress(step)
+                        self._flush_telemetry()
+                boundary = marks.close()
+                window.add(marks, completed, len(flight))
+                if due:
+                    self._emit_train_window(window)
+                    window = obs.LoopWindow(first_step=step + 1)
             if self._stop_requested.is_set():
                 logger.info("stopping at step %d on request", step)
                 obs.get_flight_recorder().record_event(
@@ -1083,6 +1113,9 @@ class ElasticTrainLoop:
                 break
             if config.max_steps and step - start_step >= config.max_steps:
                 break
+        # what the last full interval left over, before the sync below
+        # makes every step in flight "seen done" at once
+        self._emit_train_window(window)
         # out of the step loop: disarm the watchdog before the final
         # sync/commit waits (a long but legitimate final checkpoint
         # commit is not a step hang)
@@ -1106,6 +1139,16 @@ class ElasticTrainLoop:
             self.timeline.export(self._timeline_path)
         self._flush_telemetry()
         return state, metrics
+
+    @staticmethod
+    def _emit_train_window(window) -> None:
+        """One lifecycle-rate span per report interval (and one for the
+        remainder at loop end), with or without a master: the loop's own
+        account of where its host time went and of what the device
+        finished. Not one span per step: the flight recorder's span ring
+        and the SpanExporter hold lifecycle spans a postmortem needs."""
+        if window.wall_s > 0.0:
+            obs.record_span("train_window", window.wall_s, window.attrs())
 
     # -- multi-slice hierarchical DP ---------------------------------------
     def _slice_step(self, state, tok, tgt, step: int):
@@ -1139,8 +1182,6 @@ class ElasticTrainLoop:
         state, apply_metrics = self.trainer.apply_grads(state,
                                                         fleet_grads)
         if self._steptrace is not None and info.get("trace"):
-            import time as _time
-
             # the sync's clock() marks share the loop's monotonic
             # domain; apply-dispatch end completes the decomposition
             stashed = dict(info["trace"])
@@ -1165,21 +1206,19 @@ class ElasticTrainLoop:
                 pass
         return 0
 
-    def _record_steptrace(self, step: int, t_step: float, t_data: float,
-                          t_compute_end: float, ckpt_s: float) -> None:
-        """Build one per-step trace record from the loop's monotonic
-        marks (+ the stashed SliceGradSync decomposition). Hot path:
-        a handful of float ops and one bounded-ring append."""
-        import time as _time
-
+    def _record_steptrace(self, step: int, marks) -> None:
+        """Build one per-step trace record from the iteration's marks
+        (+ the stashed SliceGradSync decomposition). Hot path: a handful
+        of float ops and one bounded-ring append."""
         now_mono = _time.monotonic()
+        t_step, t_compute_end = marks.started, marks.dispatch_done
         # local wall-clock anchor for the step start, derived from the
         # same monotonic domain as every mark (a wall-clock step between
         # t_step and now lands in the offset estimate, not the phases)
         t0_wall = _time.time() - (now_mono - t_step)
-        data_d = max(0.0, t_data - t_step)
-        h2d_d = max(0.0, float(getattr(self.trainer,
-                                       "last_shard_batch_s", 0.0)))
+        data_d = marks.seconds["fetch"]
+        h2d_d = marks.seconds["shard"]
+        ckpt_s = marks.seconds["save"]
         phases = [("data_wait", 0.0, data_d), ("h2d", data_d, h2d_d)]
         cursor = data_d + h2d_d
         peers = None
@@ -1252,7 +1291,6 @@ class ElasticTrainLoop:
         save, flush the postmortem, and leave with the clean-drain exit
         code (raises :class:`DrainExit`). ``exit=False`` (the master's
         urgent ``checkpoint`` fan-out): save now, keep training."""
-        import time as _time
 
         deadline = float(drain.get("deadline", 0.0) or 0.0)
         reason = str(drain.get("reason", ""))
@@ -1312,8 +1350,6 @@ class ElasticTrainLoop:
         full cache disk."""
         if self._peer_store is None:
             return
-        import time as _time
-
         t0 = _time.monotonic()
         with obs.span("peer_stage", {"step": step}) as stage_span:
             staged = self._peer_store.stage(step, state, data_state,
@@ -1327,15 +1363,31 @@ class ElasticTrainLoop:
 
     # -- progress reporting ------------------------------------------------
     def _report_progress(self, step: int) -> None:
-        """Report-interval bookkeeping: ship the step report (with the
-        timeline's windowed speed evidence), export the timeline ring
-        and the per-chip HBM stats for the agent. All best-effort — the
-        step loop must survive a dead master and a full disk."""
+        """Report-interval bookkeeping: ship the step report (step time
+        and MFU from completions, data-wait fraction from the timeline),
+        export the timeline ring and the per-chip HBM stats for the
+        agent. All best-effort — the step loop must survive a dead
+        master and a full disk."""
         stats = self.timeline.window_stats(
             self.config.report_interval_steps)
-        mean_step = stats.get("mean_step_s", 0.0)
+        # the step time is the DEVICE's: seconds per step the device was
+        # seen to finish since the last report (obs/stepmarks.py). The
+        # timeline's iteration time is dispatch time while the host runs
+        # ahead of the device, and read as a step time it made an MFU of
+        # several hundred percent. No completion since the last report =
+        # no speed evidence (0.0 / -1.0 on the wire), never a dispatch
+        # time.
+        mean_step = self._flight.drain_step_time()
+        # ... and the step is the last one the device was seen to
+        # FINISH, not the last one queued: the master clocks steps/s
+        # (and from it tokens/s, job MFU and the collapse rule's peak)
+        # from the step deltas between reports, and the dispatch
+        # counter races ~32 steps ahead in a fraction of a second at
+        # every start and after every stall, which read as a peak no
+        # steady state can hold (a false throughput_collapse per run)
+        done_step = step - len(self._flight)
         # achieved-vs-peak over the window: the step report's MFU field
-        # feeds the master's per-rank gauge and the collapse rule
+        # feeds the master's per-rank gauge
         self._maybe_cross_check_flops()
         tokens_per_step = self.global_batch * self.config.seq_len
         mfu = obs.mfu.achieved_mfu(
@@ -1360,7 +1412,7 @@ class ElasticTrainLoop:
                     and self._replan_applied == "mesh+batch" else -2)
         try:
             self.client.report_global_step(
-                step, step_time_s=mean_step,
+                done_step, step_time_s=mean_step,
                 data_wait_fraction=stats.get("data_wait_fraction", -1.0),
                 mfu=mfu, degraded_steps=degraded,
                 hbm_peak_bytes=hbm.get("hbm_peak_bytes", 0.0),
@@ -1375,9 +1427,15 @@ class ElasticTrainLoop:
         # overhead budget; at most one export/second bounds the cost at
         # ~0.1 % of training regardless of step time. The end-of-run
         # flush writes the whole ring.
-        import time as _time
-
         now = _time.monotonic()
+        if mean_step > 0 and now - self._progress_logged_at >= 5.0:
+            # one line every five seconds at most: the loop's own account
+            # of its speed, for an operator reading the worker's log
+            self._progress_logged_at = now
+            logger.info(
+                "step %d done, %d in flight: %.1f ms/step%s", done_step,
+                len(self._flight), 1e3 * mean_step,
+                f", MFU {mfu:.3f}" if mfu >= 0 else "")
         if self._timeline_path and now - self._timeline_exported_at >= 1.0:
             self._timeline_exported_at = now
             self.timeline.export(
@@ -1401,7 +1459,7 @@ class ElasticTrainLoop:
             busy_fraction = max(
                 0.0, 1.0 - max(0.0, stats.get("data_wait_fraction", 0.0))
                 - stats.get("checkpoint_fraction", 0.0))
-            export_chip_stats(step=step,
+            export_chip_stats(step=done_step,
                               step_time_s=mean_step * busy_fraction)
         except Exception:  # noqa: BLE001 — stats are advisory
             pass

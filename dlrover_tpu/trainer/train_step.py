@@ -19,7 +19,7 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from dlrover_tpu.common.constants import MeshAxis
+from dlrover_tpu.common.constants import MeshAxis, TraceScope
 from dlrover_tpu.parallel.mesh import use_mesh
 from dlrover_tpu.parallel.moe import moe_aux_loss
 from dlrover_tpu.parallel.sharding import (
@@ -71,12 +71,6 @@ class ShardedTrainer:
     _compiled_step: Any = dataclasses.field(default=None, repr=False)
     precompile_timings: dict = dataclasses.field(default_factory=dict)
     last_used_aot: bool = False
-    # host wall-clock of the last step()/shard_batch() calls — the
-    # "compute (dispatch)" / "h2d" phases of the step timeline
-    # (obs/timeline.py), measured at the source so the loop's own
-    # bookkeeping never pollutes the attribution
-    last_step_dispatch_s: float = 0.0
-    last_shard_batch_s: float = 0.0
 
     def init(self, rng: jax.Array) -> TrainState:
         return self.init_fn(rng)
@@ -122,15 +116,6 @@ class ShardedTrainer:
         self._compiled_step = compiled
 
     def step(self, state: TrainState, tokens, targets):
-        import time as _time
-
-        t0 = _time.monotonic()
-        try:
-            return self._step_inner(state, tokens, targets)
-        finally:
-            self.last_step_dispatch_s = _time.monotonic() - t0
-
-    def _step_inner(self, state: TrainState, tokens, targets):
         if self._compiled_step is not None:
             try:
                 out = self._compiled_step(state, tokens, targets)
@@ -155,16 +140,10 @@ class ShardedTrainer:
         """Forward+backward only: (slice-mean grads, metrics). The
         caller reduces the grads across slices (host-level DCN sync)
         before `apply_grads`. Only on split-built trainers."""
-        import time as _time
-
         if self.grad_fn is None:
             raise RuntimeError("trainer was not built with "
                                "split_grad_apply=True")
-        t0 = _time.monotonic()
-        try:
-            return self.grad_fn(state, tokens, targets)
-        finally:
-            self.last_step_dispatch_s = _time.monotonic() - t0
+        return self.grad_fn(state, tokens, targets)
 
     def apply_grads(self, state: TrainState, grads):
         """Optimizer update from (fleet-reduced) grads → (new_state,
@@ -177,16 +156,11 @@ class ShardedTrainer:
     def shard_batch(self, tokens, targets):
         """Host numpy (global_batch, seq) → device arrays shaped
         (accum, micro, seq) with the micro axis over (data, fsdp)."""
-        import time as _time
-
-        t0 = _time.monotonic()
         accum, micro = self.accum_steps, self.micro_batch
         tokens = tokens.reshape(accum, micro, *tokens.shape[1:])
         targets = targets.reshape(accum, micro, *targets.shape[1:])
         put = lambda x: jax.device_put(x, self.batch_sharding)
-        result = put(tokens), put(targets)
-        self.last_shard_batch_s = _time.monotonic() - t0
-        return result
+        return put(tokens), put(targets)
 
 
 def build_trainer(
@@ -320,7 +294,11 @@ def build_trainer(
                 # 0 — one generic path covers both
                 logits, mutables = model.apply(
                     {"params": p}, tok, mutable=["losses"], rngs=rngs)
-                return loss_fn(logits, tgt) + moe_aux_loss(mutables)
+                # the model's final norm and head open the same scope
+                # (models/llama.py): head + loss read as one in a trace
+                with jax.named_scope(TraceScope.HEAD_LOSS):
+                    loss = loss_fn(logits, tgt)
+                return loss + moe_aux_loss(mutables)
 
             loss, grads = jax.value_and_grad(compute_loss)(params)
             grad_acc = jax.tree.map(
@@ -331,18 +309,20 @@ def build_trainer(
         zero_grads = jax.tree.map(
             lambda p: jnp.zeros(p.shape, jnp.float32), params
         )
-        (loss_sum, grad_sum), _ = jax.lax.scan(
-            micro_step, (jnp.zeros((), jnp.float32), zero_grads),
-            (tokens, targets, jnp.arange(accum_steps)),
-        )
+        with jax.named_scope(TraceScope.GRAD_ACCUM):
+            (loss_sum, grad_sum), _ = jax.lax.scan(
+                micro_step, (jnp.zeros((), jnp.float32), zero_grads),
+                (tokens, targets, jnp.arange(accum_steps)),
+            )
         return loss_sum, grad_sum
 
     def _apply_body(state: TrainState, grads):
         """Optimizer update from already-reduced grads (param dtype):
         (new_state, grad_norm)."""
-        updates, new_opt = tx.update(grads, state.opt_state,
-                                     state.params)
-        new_params = optax.apply_updates(state.params, updates)
+        with jax.named_scope(TraceScope.OPTIMIZER):
+            updates, new_opt = tx.update(grads, state.opt_state,
+                                         state.params)
+            new_params = optax.apply_updates(state.params, updates)
         new_state = TrainState(step=state.step + 1, params=new_params,
                                opt_state=new_opt)
         return new_state, optax.global_norm(grads)

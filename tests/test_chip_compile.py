@@ -19,6 +19,7 @@ monkeypatch, never through an option of the program.
 
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -26,8 +27,15 @@ import optax
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
+from dlrover_tpu.common.constants import TraceScope
 from dlrover_tpu.models.llama import Llama, LlamaConfig, cross_entropy_loss
-from dlrover_tpu.ops.flash_attention import flash_attention
+from dlrover_tpu.ops import norms
+from dlrover_tpu.ops.flash_attention import (
+    KERNEL_DKV,
+    KERNEL_DQ,
+    KERNEL_FWD,
+    flash_attention,
+)
 from dlrover_tpu.ops.norms import fused_rms_norm, mesh_rms_norm
 from dlrover_tpu.ops.quantization import dequantize, quantize
 from dlrover_tpu.parallel.mesh import MeshSpec, create_mesh, use_mesh
@@ -161,3 +169,17 @@ def test_full_width_train_step(topo, chip_path, n_devices):
     assert text.count("tpu_custom_call") >= 2 * (2 * 2 + 1)
     if n_devices == 4:
         assert "all-gather" in text
+    # what the trace readers hold the program to (docs/observability.md):
+    # each kernel's name is its custom-call's HLO instruction name, which
+    # is the start of the profiler's event name ...
+    for kernel in (KERNEL_FWD, KERNEL_DQ, KERNEL_DKV,
+                   norms.KERNEL_FWD, norms.KERNEL_BWD):
+        assert re.search(rf"%{kernel}(\.\d+)* = .* custom-call\(", text), kernel
+    # ... and the scopes no Flax module gives are in the op_names, with
+    # JAX's own mark on the backward pass
+    op_names = set(re.findall(r'op_name="([^"]*)"', text))
+    for scope in (TraceScope.HEAD_LOSS, TraceScope.OPTIMIZER,
+                  TraceScope.GRAD_ACCUM, TraceScope.EMBED):
+        assert any(re.search(rf"(^|[/(]){scope}([/)]|$)", name)
+                   for name in op_names), scope
+    assert any("transpose(jvp(" in name for name in op_names)

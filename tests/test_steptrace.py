@@ -508,6 +508,28 @@ class TestCriticalPathRule:
                    diagnosis_min_worker_samples=2)
         assert rule.evaluate(self._snapshot(self._summary()), ctx) == []
 
+    def test_a_fleet_of_one_has_no_critical_path(self):
+        """Its only rank trivially gates every step: no report, no
+        profiler request (every single-worker run used to get one)."""
+        from dlrover_tpu.master.diagnosis.rules import CriticalPathRule
+
+        ctx = Context.singleton()
+        ctx.update(straggler_trigger_windows=1,
+                   diagnosis_min_worker_samples=2)
+        alone = summarize_solved([
+            solve_group(0, step, {0: _rec(
+                0, step, [["compute", 0.0, 0.35]], t0=1000.0 + step)})
+            for step in range(10)])
+        assert alone["ranks"] == 1 and alone["steps"] == 10
+        assert alone["by_rank"]["0"]["gating_steps"] == 10
+        rule = CriticalPathRule()
+        assert rule.evaluate(self._snapshot(alone), ctx) == []
+        assert rule.flagged == set()
+        # the same gating share with a second rank in the window flags
+        pair = dict(alone, ranks=2)
+        (report,) = rule.evaluate(self._snapshot(pair), ctx)
+        assert "profile:0" in report.actions
+
     def test_departed_rank_evidence_evicted(self):
         from dlrover_tpu.master.diagnosis.rules import CriticalPathRule
 
