@@ -7,13 +7,23 @@ kernel for the MXU instead of a CUDA binding:
 
 - forward: grid (batch, heads, q_blocks, kv_blocks); the kv axis is the
   innermost (sequential on TPU), accumulating (acc, row-max m, row-sum l) in
-  VMEM scratch; causal blocks above the diagonal are skipped cheaply.
+  VMEM scratch.
+- causal: blocks above the diagonal are skipped whole (their DMA too, by
+  the index maps); inside a block ON the diagonal the kernels compute only
+  the sub-tiles at or below it, at a grain of 128 rows (`causal_plan`): the
+  block is cut into strips of q rows, strip j against k columns
+  [0, (j+1)*128) alone. At seq 2048 that is 2.125 of 4 blocks' worth of
+  scores instead of 3; nothing above a strip's last 128 columns is
+  computed, then masked, any more.
 - block sizes default to 1024x1024 (v5e-tuned: 92 TF/s fwd vs 11 at
   128x128; capped by seq len so small shapes still work).
 - backward: two kernels — dq accumulates over kv blocks; dk/dv accumulate
   over q blocks — using the saved logsumexp and delta = rowsum(dO*O).
 - GQA: kv heads are indexed as h // (num_q_heads // num_kv_heads) directly
   in the BlockSpec index maps; no materialized head broadcast.
+- each kernel is traced and lowered once per signature, not once per call
+  site: `_flash_fwd_call` / `_flash_bwd_call` are jitted, so a 24-layer
+  step program's text holds 3 flash kernels, not 72.
 
 MXU matmuls run in the input dtype (bf16 at full rate) with fp32
 accumulation via `preferred_element_type` — FlashAttention-2 numerics; the
@@ -26,7 +36,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -51,6 +61,16 @@ LN2 = 0.6931471805599453
 # path (parallel/ring_attention.py).
 DEFAULT_BLOCK_Q = 1024
 DEFAULT_BLOCK_K = 1024
+
+# Sub-tile causal skipping inside a block on the diagonal: the grain is a
+# multiple of the lane width (a slice of scores must be), and a block is
+# cut into at most MAX_STRIPS strips, each one more body in the kernel's
+# text (what every launch traces, lowers and compiles). PIPE_DEPTH: how
+# many strips' score matmuls are issued ahead of a strip's softmax
+# (`_pipelined`). All three chosen on the v5e (PERF.md, PR 30).
+SUBTILE = 128
+MAX_STRIPS = 8
+PIPE_DEPTH = 2
 
 # Grid axes (batch, heads, outer-block) are independent; the innermost
 # axis carries the VMEM accumulators and must stay sequential.
@@ -96,22 +116,6 @@ def _cdiv(a: int, b: int) -> int:
     return (a + b - 1) // b
 
 
-def _causal_dispatch(compute, q_start, k_start,
-                     block_q: int, block_k: int) -> None:
-    """Run `compute(masked)` for a causal (q, k) block pair: skip blocks
-    entirely above the diagonal, and pay the iota/select mask VPU work
-    only on blocks that straddle it. Static per-block skip is impossible
-    (q_start/k_start are dynamic over the grid), so dispatch with
-    pl.when. Shared by the forward and both backward kernels so the
-    boundary conditions cannot drift apart."""
-    needed = k_start <= q_start + block_q - 1
-    full = k_start + block_k - 1 <= q_start
-    pl.when(jnp.logical_and(needed, full))(
-        lambda: compute(False))
-    pl.when(jnp.logical_and(needed, jnp.logical_not(full)))(
-        lambda: compute(True))
-
-
 def fit_block(n: int, block: int) -> int:
     """Largest divisor of n that is <= block.
 
@@ -130,6 +134,156 @@ def fit_block(n: int, block: int) -> int:
         if n % b == 0:
             return b
     return n
+
+
+# ===========================================================================
+# Causal skipping: whole blocks above the diagonal, and sub-tiles above it
+# inside a block that straddles it
+# ===========================================================================
+
+
+class CausalPlan(NamedTuple):
+    """What a causal call does, from its shapes alone (`causal_plan`)."""
+    block_q: int            # fitted blocks
+    block_k: int
+    grain: int              # sub-tile grain of a straddling block; 0 = whole
+    computed_share: float   # share of the seq_q x seq_k scores computed
+
+
+def _grain(block_q: int, block_k: int) -> int:
+    """Grain of the strips a straddling block is cut into: the finest
+    multiple of the lane width that splits a square block into 2 to
+    MAX_STRIPS strips; 0 (no sub-tiling) for unequal or short blocks."""
+    if block_q != block_k:
+        return 0
+    for g in range(SUBTILE, block_q // 2 + 1, SUBTILE):
+        if block_q % g == 0 and block_q // g <= MAX_STRIPS:
+            return g
+    return 0
+
+
+class _Tile(NamedTuple):
+    """q rows [r0, r0 + rn) of a (q, k) block against its k columns
+    [c0, c0 + cn); a block's tiling is a tuple of these."""
+    r0: int
+    rn: int
+    c0: int
+    cn: int
+    mask: int
+
+    @property
+    def rows(self) -> slice:
+        return slice(self.r0, self.r0 + self.rn)
+
+    @property
+    def cols(self) -> slice:
+        return slice(self.c0, self.c0 + self.cn)
+
+
+_UNMASKED = 0
+_BY_POSITION = 1    # mask by the block's place in the sequence (dynamic)
+_ON_DIAGONAL = 2    # the block's own diagonal is the sequence's (static)
+
+
+def _whole(block_q: int, block_k: int, mask: int) -> tuple:
+    return (_Tile(0, block_q, 0, block_k, mask),)
+
+
+def _straddling(block_q: int, block_k: int) -> tuple:
+    """Tiling of a block that straddles the diagonal. With a grain
+    (`_grain`): strips of q rows, strip j, rows [j*g, (j+1)*g), against
+    the k columns [0, (j+1)*g) alone; equal blocks straddle the diagonal
+    only where q_start == k_start, so the block's own diagonal is the
+    sequence's and the mask is static. All three kernels cut the block
+    this way (dK/dV adds a strip's share into the first (j+1)*g rows of
+    its accumulators). One tile a strip: on the chip a strip split into
+    an unmasked part and the g x g square on the diagonal was slower than
+    one matmul over both, masked; largest strip first is the order the
+    forward runs fastest in (PERF.md, PR 30). Without a grain: the whole
+    block, masked by position, as before."""
+    grain = _grain(block_q, block_k)
+    if not grain:
+        return _whole(block_q, block_k, _BY_POSITION)
+    return tuple(_Tile(lo, grain, 0, lo + grain, _ON_DIAGONAL)
+                 for lo in range(block_q - grain, -1, -grain))
+
+
+def causal_plan(seq_q: int, seq_k: int,
+                block_q: int = DEFAULT_BLOCK_Q,
+                block_k: int = DEFAULT_BLOCK_K) -> CausalPlan:
+    """The path a causal call takes and the share of the score area its
+    kernels compute (the rest is skipped, not masked), counted over the
+    tilings the kernels run. A pure function of the shapes: the
+    mechanism's engagement counter, static per shape. At seq 2048 with
+    1024-blocks and grain 128: (1 + 2 * 36/64) / 4."""
+    block_q = fit_block(seq_q, block_q)
+    block_k = fit_block(seq_k, block_k)
+    straddling = sum(t.rn * t.cn for t in _straddling(block_q, block_k))
+    computed = 0
+    for q_start in range(0, seq_q, block_q):
+        for k_start in range(0, seq_k, block_k):
+            needed, full = _needed_full(q_start, k_start, block_q, block_k)
+            if needed:
+                computed += block_q * block_k if full else straddling
+    return CausalPlan(block_q, block_k, _grain(block_q, block_k),
+                      computed / (seq_q * seq_k))
+
+
+def _needed_full(q_start, k_start, block_q: int, block_k: int):
+    """Where a (q, k) block pair lies: (not entirely above the diagonal,
+    entirely at or below it). Python ints or traced grid positions."""
+    return (k_start <= q_start + block_q - 1,
+            k_start + block_k - 1 <= q_start)
+
+
+def _dispatch(scores, update, causal: bool, q_start, k_start,
+              block_q: int, block_k: int) -> None:
+    """Run a kernel's two stages (`_pipelined`) over one (q, k) block
+    pair. Causal: skip blocks entirely above the diagonal, run blocks
+    entirely below it whole and unmasked, and in a block that straddles
+    it compute only the strips' tiles (`_straddling`): what lies above
+    the diagonal beyond a strip's last g columns is never computed.
+    Static per-block skip is impossible (q_start/k_start are dynamic over
+    the grid), so dispatch with pl.when. Shared by the forward and both
+    backward kernels so the boundary conditions cannot drift apart."""
+    whole = _whole(block_q, block_k, _UNMASKED)
+    if not causal:
+        _pipelined(whole, scores, update)
+        return
+    needed, full = _needed_full(q_start, k_start, block_q, block_k)
+    pl.when(jnp.logical_and(needed, full))(
+        lambda: _pipelined(whole, scores, update))
+    pl.when(jnp.logical_and(needed, jnp.logical_not(full)))(
+        lambda: _pipelined(_straddling(block_q, block_k), scores, update))
+
+
+def _pipelined(tiling, scores, update) -> None:
+    """Each tile in two stages, `update(tile, scores(tile))`, issued as a
+    software pipeline: the score matmuls of the next PIPE_DEPTH tiles come
+    before a tile's update in program order. The MXU takes matmuls in
+    program order, so without this it waits out every strip's softmax
+    before the next strip's first matmul (on the chip the forward was
+    slower in 8 strips than whole; PERF.md, PR 30). A whole block is one
+    tile: stage one, then stage two, as before."""
+    pending = []
+    for tile in tiling:
+        pending.append((tile, scores(tile)))
+        if len(pending) > PIPE_DEPTH:
+            update(*pending.pop(0))
+    for item in pending:
+        update(*item)
+
+
+def _masked(s, tile: _Tile, q_start, k_start):
+    """A tile's scores with those above the diagonal at NEG_INF."""
+    if tile.mask == _UNMASKED:
+        return s
+    r0, c0 = tile.r0, tile.c0
+    if tile.mask == _BY_POSITION:
+        r0, c0 = q_start + r0, k_start + c0
+    q_idx = r0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    k_idx = c0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    return jnp.where(q_idx >= k_idx, s, NEG_INF)
 
 
 # ===========================================================================
@@ -153,38 +307,34 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
     q_start = qi * block_q
     k_start = ki * block_k
 
-    def _compute(masked: bool):
-        # Inputs stay in their native dtype (bf16) so the MXU runs at full
-        # rate; accumulation is fp32 via preferred_element_type (the
-        # FlashAttention-2 numerics). fp32 operands pass through unchanged.
-        q = q_ref[0, 0]
-        k = k_ref[0, 0]
-        v = v_ref[0, 0]
+    # Inputs stay in their native dtype (bf16) so the MXU runs at full
+    # rate; accumulation is fp32 via preferred_element_type (the
+    # FlashAttention-2 numerics). fp32 operands pass through unchanged.
+    def _scores(tile):
+        q = q_ref[0, 0, tile.rows]
+        k = k_ref[0, 0, tile.cols]
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * (
             sm_scale * LOG2E)
-        if masked:
-            q_idx = q_start + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_idx = k_start + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_idx >= k_idx, s, NEG_INF)
-        m_prev = m_ref[:]
+        return _masked(s, tile, q_start, k_start)
+
+    def _update(tile, s):
+        # one online-softmax update of the tile's rows
+        rows = tile.rows
+        v = v_ref[0, 0, tile.cols]
+        m_prev = m_ref[rows]
         m_cur = jnp.max(s, axis=1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
         p = jnp.exp2(s - m_new)
         alpha = jnp.exp2(m_prev - m_new)
-        l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * alpha + jnp.dot(
+        l_ref[rows] = l_ref[rows] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[rows] = acc_ref[rows] * alpha + jnp.dot(
             p.astype(v.dtype), v, preferred_element_type=jnp.float32)
-        m_ref[:] = m_new
+        m_ref[rows] = m_new
 
-    if causal:
-        # Only blocks straddling the diagonal pay the iota/select VPU
-        # work (at seq 2048 that's 2 of 3 computed blocks; at 8k only
-        # 8 of 36).
-        _causal_dispatch(_compute, q_start, k_start, block_q, block_k)
-    else:
-        _compute(False)
+    # Causal: blocks below the diagonal run whole and unmasked; a block on
+    # it (2 of the 3 computed at seq 2048; 8 of 36 at 8k) computes only
+    # the sub-tiles its strips meet, 36 of 64 at grain 128.
+    _dispatch(_scores, _update, causal, q_start, k_start, block_q, block_k)
 
     @pl.when(ki == num_k_blocks - 1)
     def _finalize():
@@ -196,11 +346,27 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 def _flash_fwd(q, k, v, sm_scale: float, causal: bool,
                block_q: int, block_k: int):
+    """(out, lse) of one forward launch; blocks are fitted here, so that
+    every request for the same fitted blocks shares one traced kernel."""
+    return _flash_fwd_call(
+        q, k, v, sm_scale=sm_scale, causal=causal,
+        block_q=fit_block(q.shape[2], block_q),
+        block_k=fit_block(k.shape[2], block_k),
+        interpret=not on_tpu())
+
+
+# Under jit with everything but the arrays static: a model calls this once
+# per layer, and JAX traces and lowers a jitted function once per
+# signature, so the step program's text holds one forward kernel, not one
+# per layer (the backward pair likewise). `interpret` is an argument, not
+# read inside, because the trace is cached across backends.
+@functools.partial(jax.jit, static_argnames=(
+    "sm_scale", "causal", "block_q", "block_k", "interpret"))
+def _flash_fwd_call(q, k, v, *, sm_scale: float, causal: bool,
+                    block_q: int, block_k: int, interpret: bool):
     batch, num_heads, seq_q, head_dim = q.shape
     _, num_kv_heads, seq_k, _ = k.shape
     group = num_heads // num_kv_heads
-    block_q = fit_block(seq_q, block_q)
-    block_k = fit_block(seq_k, block_k)
     num_q_blocks = _cdiv(seq_q, block_q)
     num_k_blocks = _cdiv(seq_k, block_k)
 
@@ -247,7 +413,7 @@ def _flash_fwd(q, k, v, sm_scale: float, causal: bool,
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
         compiler_params=_DIM_SEMANTICS,
-        interpret=not on_tpu(),
+        interpret=interpret,
         name=KERNEL_FWD,
     )(q, k, v)
     return out, lse
@@ -256,6 +422,20 @@ def _flash_fwd(q, k, v, sm_scale: float, causal: bool,
 # ===========================================================================
 # Backward kernels
 # ===========================================================================
+
+
+def _bwd_scores(tile, *, q_ref, k_ref, v_ref, do_ref, sm_scale: float,
+                q_start, k_start):
+    """Stage one of both backward kernels: a tile's scores (exp2 domain,
+    masked) and dP = dO V^T, the matmuls that wait on nothing."""
+    q = q_ref[0, 0, tile.rows]
+    do = do_ref[0, 0, tile.rows]
+    k = k_ref[0, 0, tile.cols]
+    v = v_ref[0, 0, tile.cols]
+    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * (
+        sm_scale * LOG2E)
+    dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
+    return _masked(s, tile, q_start, k_start), dp
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -272,30 +452,21 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     q_start = qi * block_q
     k_start = ki * block_k
 
-    def _compute(masked: bool):
-        q = q_ref[0, 0]
-        k = k_ref[0, 0]
-        v = v_ref[0, 0]
-        do = do_ref[0, 0]
-        lse = lse_ref[0, 0] * LOG2E    # nat -> exp2 domain (per row)
-        delta = delta_ref[0, 0]
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * (
-            sm_scale * LOG2E)
-        if masked:
-            q_idx = q_start + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_idx = k_start + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_idx >= k_idx, s, NEG_INF)
-        p = jnp.exp2(s - lse)
-        dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta) * sm_scale).astype(k.dtype)
-        dq_acc_ref[:] += jnp.dot(ds, k, preferred_element_type=jnp.float32)
+    _scores = functools.partial(
+        _bwd_scores, q_ref=q_ref, k_ref=k_ref, v_ref=v_ref, do_ref=do_ref,
+        sm_scale=sm_scale, q_start=q_start, k_start=k_start)
 
-    if causal:
-        _causal_dispatch(_compute, q_start, k_start, block_q, block_k)
-    else:
-        _compute(False)
+    def _update(tile, scores):
+        rows = tile.rows
+        s, dp = scores
+        k = k_ref[0, 0, tile.cols]
+        lse = lse_ref[0, 0, rows] * LOG2E   # nat -> exp2 domain (per row)
+        delta = delta_ref[0, 0, rows]
+        p = jnp.exp2(s - lse)
+        ds = (p * (dp - delta) * sm_scale).astype(k.dtype)
+        dq_acc_ref[rows] += jnp.dot(ds, k, preferred_element_type=jnp.float32)
+
+    _dispatch(_scores, _update, causal, q_start, k_start, block_q, block_k)
 
     @pl.when(ki == num_k_blocks - 1)
     def _finalize():
@@ -317,35 +488,27 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     q_start = qi * block_q
     k_start = ki * block_k
 
-    def _compute(masked: bool):
-        q = q_ref[0, 0]
-        k = k_ref[0, 0]
-        v = v_ref[0, 0]
-        do = do_ref[0, 0]
-        lse = lse_ref[0, 0] * LOG2E    # nat -> exp2 domain (per row)
-        delta = delta_ref[0, 0]
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * (
-            sm_scale * LOG2E)
-        if masked:
-            q_idx = q_start + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_idx = k_start + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_idx >= k_idx, s, NEG_INF)
-        p = jnp.exp2(s - lse)
-        dv_acc_ref[:] += jnp.dot(p.astype(do.dtype).T, do,
-                                 preferred_element_type=jnp.float32)
-        dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta) * sm_scale).astype(q.dtype)
-        dk_acc_ref[:] += jnp.dot(ds.T, q,
-                                 preferred_element_type=jnp.float32)
+    _scores = functools.partial(
+        _bwd_scores, q_ref=q_ref, k_ref=k_ref, v_ref=v_ref, do_ref=do_ref,
+        sm_scale=sm_scale, q_start=q_start, k_start=k_start)
 
-    if causal:
-        # For a kv block, only q blocks at or below the diagonal
-        # contribute; blocks strictly below it need no mask.
-        _causal_dispatch(_compute, q_start, k_start, block_q, block_k)
-    else:
-        _compute(False)
+    def _update(tile, scores):
+        rows, cols = tile.rows, tile.cols
+        s, dp = scores
+        q = q_ref[0, 0, rows]
+        do = do_ref[0, 0, rows]
+        lse = lse_ref[0, 0, rows] * LOG2E   # nat -> exp2 domain (per row)
+        delta = delta_ref[0, 0, rows]
+        p = jnp.exp2(s - lse)
+        dv_acc_ref[cols] += jnp.dot(p.astype(do.dtype).T, do,
+                                    preferred_element_type=jnp.float32)
+        ds = (p * (dp - delta) * sm_scale).astype(q.dtype)
+        dk_acc_ref[cols] += jnp.dot(ds.T, q,
+                                    preferred_element_type=jnp.float32)
+
+    # Causal: for a kv block, only q blocks at or below the diagonal
+    # contribute; blocks strictly below it need no mask.
+    _dispatch(_scores, _update, causal, q_start, k_start, block_q, block_k)
 
     @pl.when(qi == num_q_blocks - 1)
     def _finalize():
@@ -355,15 +518,25 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _flash_bwd(res, g, *, sm_scale: float, causal: bool,
                block_q: int, block_k: int, delta=None):
-    """delta = rowsum(dO·O) may be passed precomputed — ring callers
-    invoke this once per visiting KV block with step-invariant dO/O."""
+    """(dq, dk, dv) of one backward launch pair. delta = rowsum(dO·O) may
+    be passed precomputed — ring callers invoke this once per visiting KV
+    block with step-invariant dO/O."""
+    q, k = res[0], res[1]
+    return _flash_bwd_call(
+        res, g, delta, sm_scale=sm_scale, causal=causal,
+        block_q=fit_block(q.shape[2], block_q),
+        block_k=fit_block(k.shape[2], block_k),
+        interpret=not on_tpu())
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "sm_scale", "causal", "block_q", "block_k", "interpret"))
+def _flash_bwd_call(res, do, delta, *, sm_scale: float, causal: bool,
+                    block_q: int, block_k: int, interpret: bool):
     q, k, v, out, lse = res
-    do = g
     batch, num_heads, seq_q, head_dim = q.shape
     _, num_kv_heads, seq_k, _ = k.shape
     group = num_heads // num_kv_heads
-    block_q = fit_block(seq_q, block_q)
-    block_k = fit_block(seq_k, block_k)
     num_q_blocks = _cdiv(seq_q, block_q)
     num_k_blocks = _cdiv(seq_k, block_k)
 
@@ -404,7 +577,7 @@ def _flash_bwd(res, g, *, sm_scale: float, causal: bool,
         out_shape=_sds(q.shape, q.dtype, _vma(q, k, v, do)),
         scratch_shapes=[pltpu.VMEM((block_q, head_dim), jnp.float32)],
         compiler_params=_DIM_SEMANTICS,
-        interpret=not on_tpu(),
+        interpret=interpret,
         name=KERNEL_DQ,
     )(q, k, v, do, lse, delta)
 
@@ -469,7 +642,7 @@ def _flash_bwd(res, g, *, sm_scale: float, causal: bool,
             pltpu.VMEM((block_k, head_dim), jnp.float32),
         ],
         compiler_params=_DIM_SEMANTICS,
-        interpret=not on_tpu(),
+        interpret=interpret,
         name=KERNEL_DKV,
     )(q, k, v, do, lse, delta)
 

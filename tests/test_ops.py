@@ -8,6 +8,10 @@ import numpy as np
 import pytest
 
 from dlrover_tpu.ops.flash_attention import (
+    KERNEL_DKV,
+    KERNEL_DQ,
+    KERNEL_FWD,
+    causal_plan,
     fit_block,
     flash_attention,
     reference_attention,
@@ -132,6 +136,113 @@ class TestFlashAttentionBackward:
         g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
         for a, b in zip(g_flash, g_ref):
             np.testing.assert_allclose(a, b, atol=5e-4, rtol=5e-4)
+
+
+# (seq_q, seq_k, block_q, block_k, heads, kv_heads, dim, causal, grain):
+# the last is the sub-tile grain `causal_plan` must choose for the shape
+# (0 = today's whole-block path), so each case is known to hit its path.
+_SUBTILE_CASES = {
+    "cell_2048_b1024": (2048, 2048, 1024, 1024, 2, 2, 128, True, 128),
+    "768_b256_full_straddling_skipped": (768, 768, 256, 256, 2, 2, 64,
+                                         True, 128),
+    "512_b256": (512, 512, 256, 256, 2, 2, 64, True, 128),
+    "384_one_block_three_strips": (384, 384, 1024, 1024, 2, 2, 64, True,
+                                   128),
+    "96_no_subtile": (96, 96, 1024, 1024, 2, 2, 64, True, 0),
+    "seq_k_longer": (256, 512, 256, 256, 2, 2, 64, True, 128),
+    "seq_q_longer": (512, 256, 256, 256, 2, 2, 64, True, 128),
+    "unequal_blocks_256x128": (512, 512, 256, 128, 2, 2, 64, True, 0),
+    "gqa_2to1": (512, 512, 256, 256, 4, 2, 64, True, 128),
+    "gqa_4to1": (512, 512, 256, 256, 4, 1, 64, True, 128),
+    "not_causal": (512, 512, 256, 256, 2, 2, 64, False, 128),
+}
+
+
+class TestFlashAttentionSubTiles:
+    """Causal skipping at sub-tile grain inside a block on the diagonal:
+    out, dq, dk, dv against the fp32 reference at shapes that reach each
+    path, and the plan that says which path a shape takes."""
+
+    @pytest.mark.parametrize("case", list(_SUBTILE_CASES))
+    def test_out_and_grads_match_reference(self, case):
+        (seq_q, seq_k, block_q, block_k, heads, kv_heads, dim, causal,
+         grain) = _SUBTILE_CASES[case]
+        assert causal_plan(seq_q, seq_k, block_q, block_k).grain == grain
+        q, _, _ = _qkv(heads=heads, seq=seq_q, dim=dim)
+        _, k, v = _qkv(heads=heads, kv_heads=kv_heads, seq=seq_k, dim=dim,
+                       seed=1)
+
+        def flash(q, k, v):
+            out = flash_attention(q, k, v, causal, None, block_q, block_k)
+            return jnp.sum(out ** 2), out
+
+        def ref(q, k, v):
+            out = reference_attention(q, k, v, causal)
+            return jnp.sum(out ** 2), out
+
+        g_flash, out = jax.grad(flash, argnums=(0, 1, 2), has_aux=True)(
+            q, k, v)
+        g_ref, out_ref = jax.grad(ref, argnums=(0, 1, 2), has_aux=True)(
+            q, k, v)
+        np.testing.assert_allclose(out, out_ref, atol=2e-5, rtol=2e-5)
+        for name, a, b in zip(("dq", "dk", "dv"), g_flash, g_ref):
+            np.testing.assert_allclose(a, b, atol=5e-4, rtol=5e-4,
+                                       err_msg=name)
+
+    @pytest.mark.parametrize("shape,grain,share", [
+        # the cells: 1 whole block + 2 on the diagonal at 36/64, of 4
+        ((2048, 2048, 1024, 1024), 128, 2.125 / 4),
+        # sub-tiling off: 3 whole blocks of 4
+        ((128, 128, 64, 64), 0, 3 / 4),
+        ((512, 512, 256, 128), 0, 6 / 8),
+        # one block, whole: nothing to skip without a grain
+        ((96, 96, 1024, 1024), 0, 1.0),
+        # one block of three strips: 6 of 9 sub-tiles
+        ((384, 384, 1024, 1024), 128, 6 / 9),
+        # 3 x 3 blocks of 256: 3 whole, 3 on the diagonal at 3/4, 3 skipped
+        ((768, 768, 256, 256), 128, (3 + 3 * 0.75) / 9),
+        # trailing kv blocks have no q block at all
+        ((256, 512, 256, 256), 128, 0.75 / 2),
+        # seq 8k: 28 whole blocks + 8 on the diagonal, of 64
+        ((8192, 8192, 1024, 1024), 128, (28 + 8 * 36 / 64) / 64),
+    ])
+    def test_causal_plan(self, shape, grain, share):
+        plan = causal_plan(*shape)
+        assert plan.grain == grain
+        assert plan.computed_share == pytest.approx(share, rel=1e-12)
+        assert (plan.block_q, plan.block_k) == (
+            fit_block(shape[0], shape[2]), fit_block(shape[1], shape[3]))
+
+
+# The lowered text of the stack below with the finished kernels (measured
+# here, 65,894 characters) plus 25 %: a kernel body that grows, or a kernel
+# lowered once per layer again (4 layers: 12 custom calls, about 4x the
+# text), grows what every launch traces, lowers and compiles.
+_STACK_TEXT_BOUND = 82_000
+
+
+def test_flash_stack_lowers_each_kernel_once(monkeypatch):
+    """Set-up guard: a 4-layer stack of flash_attention under jax.grad, at
+    the benchmark cells' attention shape, lowered for the TPU from here
+    (no chip, no TPU compiler: lowering only). JAX lowers a jitted function
+    once per signature and calls it, so the module holds 3 flash custom
+    calls, not 3 per layer."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    q = jax.ShapeDtypeStruct((2, 16, 2048, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((2, 8, 2048, 128), jnp.bfloat16)
+
+    def loss(q, k, v):
+        x = q
+        for _ in range(4):
+            x = flash_attention(x, k, v, True) + x
+        return jnp.sum(x.astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(
+        q, kv, kv).lower(lowering_platforms=("tpu",)).as_text()
+    assert text.count("tpu_custom_call") == 3
+    for kernel in (KERNEL_FWD, KERNEL_DQ, KERNEL_DKV):
+        assert kernel in text, kernel
+    assert len(text) < _STACK_TEXT_BOUND, len(text)
 
 
 class TestFusedRmsNorm:
