@@ -56,17 +56,20 @@ def batches_of(seed, cfg, traffic):
 
 
 def control(args, cfg, traffic) -> None:
+    plain = harness.model_reference(cfg)
     for seed in args.seeds:
         batches = batches_of(seed, cfg, traffic)
         t0 = time.monotonic()
-        truth = reference.follow(seed, cfg, batches)
+        truth = reference.follow(plain, seed, cfg, batches)
         emit(args.out, what="reference", workload=args.workload, seed=seed,
              seconds=time.monotonic() - t0, losses=truth["losses"])
         readings = {
-            "int8": lambda: reference.follow(seed, cfg, batches, "int8"),
-            "bf16": lambda: reference.follow(seed, cfg, batches, "bf16"),
+            "int8": lambda: reference.follow(plain, seed, cfg, batches,
+                                             "int8"),
+            "bf16": lambda: reference.follow(plain, seed, cfg, batches,
+                                             "bf16"),
             "half_batch": lambda: reference.follow(
-                seed, cfg, batches,
+                plain, seed, cfg, batches,
                 keep_rows=traffic["global_batch"] // 2),
         }
         for name in args.controls:
@@ -85,7 +88,8 @@ def program(args, cfg, traffic, entry) -> None:
 
     from benchmarks.windows import steady
 
-    model_module = harness.load_module("models", cfg["model"])
+    model_module = harness.model_class(cfg)
+    plain = harness.model_reference(cfg)
     loop = steady.build_loop(model_module, cfg, traffic,
                              jax.devices()[:entry["chips"]])
     change_fn = model_module.change_norms_fn(loop.trainer)
@@ -100,7 +104,8 @@ def program(args, cfg, traffic, entry) -> None:
         del state
         gc.collect()
         t0 = time.monotonic()
-        truth = reference.follow(seed, cfg, batches_of(seed, cfg, traffic))
+        truth = reference.follow(plain, seed, cfg,
+                                 batches_of(seed, cfg, traffic))
         emit(args.out, what="program", workload=args.workload, seed=seed,
              program_seconds=program_s,
              reference_seconds=time.monotonic() - t0,
@@ -126,9 +131,7 @@ def main() -> int:
     args = parser.parse_args()
     entry, cfg, traffic = harness.cell(harness.benchmark(), args.workload)
     if args.tiny:
-        from benchmarks.worker import tiny
-
-        cfg, traffic = tiny(cfg, traffic)
+        cfg, traffic = harness.model_class(cfg).tiny(cfg, traffic)
     else:
         import jax
 

@@ -9,6 +9,14 @@ import os
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
+MODELS = os.path.join(HERE, "models")   # where a model class's files lie
+
+# What a model class answers (benchmarks/README.md has the contract):
+# ``<key>.py``, stdlib at import, the parent reads it ...
+MODEL_CLASS = ("build", "first_grad_norms", "change_norms_fn", "change_norms",
+               "flops_per_token", "attention_layers", "tiny")
+# ... and ``<key>_reference.py``, JAX and nothing of the program.
+MODEL_REFERENCE = ("leaves", "layer_prefix", "layer_kind", "block")
 
 
 def load_json(*parts: str) -> dict:
@@ -32,13 +40,35 @@ def cell(bench: dict, workload: str) -> tuple:
 
 
 def load_module(folder: str, name: str):
-    """The module ``benchmarks/<folder>/<name>.py``; names may hold dots."""
+    """The module ``<folder>/<name>.py``, ``folder`` taken from
+    ``benchmarks/`` unless it is a path of its own; names may hold dots."""
     path = os.path.join(HERE, folder, name + ".py")
     spec = importlib.util.spec_from_file_location(
-        f"benchmarks.{folder}.{name.replace('.', '_')}", path)
+        f"benchmarks.{os.path.basename(folder)}.{name.replace('.', '_')}",
+        path)
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
     return module
+
+
+def _answering(module, functions: tuple):
+    for function in functions:
+        if not callable(getattr(module, function, None)):
+            raise AttributeError(
+                f"model class file {module.__file__} lacks {function}(): "
+                "benchmarks/README.md has the contract of a model class")
+    return module
+
+
+def model_class(cfg: dict):
+    """The configuration's model class, ``models/<key>.py``."""
+    return _answering(load_module(MODELS, cfg["model"]), MODEL_CLASS)
+
+
+def model_reference(cfg: dict):
+    """The class's plain reference, ``models/<key>_reference.py``."""
+    return _answering(load_module(MODELS, cfg["model"] + "_reference"),
+                      MODEL_REFERENCE)
 
 
 def metrics_of(bench: dict, workload: str, group: str) -> list:

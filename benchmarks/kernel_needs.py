@@ -1,46 +1,71 @@
 """What each named kernel needs, from shapes alone: FLOPs and HBM bytes of
 one launch, for the per-kernel roofline readers. Stdlib only.
 
-``flops.flash_attention_needs`` (the accepted yardstick) counts one layer's
-attention forward plus backward; here the same counts are split where the
-program's kernel names split them (``flash_attn_fwd``; ``flash_attn_dq`` +
-``flash_attn_dkv``), and a test holds forward + backward equal to it exactly.
-The norm kernels have no entry: their operands live in the chip's fast
-memory space, for which there is no public peak (PERF.md, PR 28), so their
-reader reports a share of the device's time instead.
+An attention layer is one entry of the model class's ``attention_layers``:
+``heads``, ``kv_heads``, ``head_dim`` and ``window`` (None: causal to the
+start). The counts are split where the program's kernel names split them
+(``flash_attn_fwd``; ``flash_attn_dq`` + ``flash_attn_dkv``). The norm
+kernels have no entry: their operands live in the chip's fast memory space,
+for which there is no public peak (PERF.md, PR 28), so their reader reports a
+share of the device's time instead.
 """
 
 from __future__ import annotations
 
+import math
+
 from benchmarks import flops
 
-def _attention_sizes(cfg: dict, batch: int, seq_len: int) -> tuple:
-    d = flops.head_dim(cfg)
-    heads, kv_heads = cfg["num_attention_heads"], cfg["num_key_value_heads"]
-    width = 2  # bfloat16, as flops.flash_attention_needs has it
+
+def scored_pairs(seq_len: int, window) -> float:
+    """(query, key) pairs one head scores: the accepted ``s^2 / 2`` of a
+    causal layer, and ``w s - w^2 / 2`` where each query sees only the last
+    ``w < s`` keys (the same convention, equal at ``w = s``; the exact count
+    ``sum_i min(i + 1, w)`` lies the diagonal's half cells above)."""
+    if window is None or window >= seq_len:
+        return seq_len * seq_len / 2.0
+    return window * seq_len - window * window / 2.0
+
+
+def _attention_sizes(layer: dict, batch: int, seq_len: int) -> tuple:
+    d, heads, kv_heads = layer["head_dim"], layer["heads"], layer["kv_heads"]
+    width = 2  # bfloat16
     q_bytes = batch * heads * seq_len * d * width
     kv_bytes = batch * kv_heads * seq_len * d * width
     stats = batch * heads * seq_len * 4
-    matmul = 2.0 * batch * heads * seq_len * seq_len * d / 2.0
+    matmul = 2.0 * batch * heads * scored_pairs(seq_len, layer["window"]) * d
     return q_bytes, kv_bytes, stats, matmul
 
 
-def flash_attention_fwd(cfg: dict, batch: int, seq_len: int) -> dict:
-    """One layer's causal attention forward: QK^T and PV (2 matmuls, halved
-    by the mask); reads q, k, v, writes o and the fp32 row statistics."""
-    q_bytes, kv_bytes, stats, matmul = _attention_sizes(cfg, batch, seq_len)
+def flash_attention_fwd(layer: dict, batch: int, seq_len: int) -> dict:
+    """One layer's attention forward: QK^T and PV (2 matmuls over the scored
+    pairs); reads q, k, v, writes o and the fp32 row statistics."""
+    q_bytes, kv_bytes, stats, matmul = _attention_sizes(layer, batch, seq_len)
     return {"flops": 2.0 * matmul,
             "bytes": float(q_bytes + 2 * kv_bytes + q_bytes + stats)}
 
 
-def flash_attention_bwd(cfg: dict, batch: int, seq_len: int) -> dict:
+def flash_attention_bwd(layer: dict, batch: int, seq_len: int) -> dict:
     """dQ and dK/dV together, as one layer-step needs them: 4 matmuls (dV,
     dP, dQ, dK; recomputing S is the kernels' own choice and not credited);
     reads q, k, v, o, do and the statistics once, writes dq, dk, dv."""
-    q_bytes, kv_bytes, stats, matmul = _attention_sizes(cfg, batch, seq_len)
+    q_bytes, kv_bytes, stats, matmul = _attention_sizes(layer, batch, seq_len)
     return {"flops": 4.0 * matmul,
             "bytes": float(3 * q_bytes + 2 * kv_bytes + stats
                            + q_bytes + 2 * kv_bytes)}
+
+
+def roofline_share(run: dict, needs, seconds: float, launches: float):
+    """Share of their roofline, in %, of ``launches`` launches (one a layer
+    and step) that took ``seconds``: the sum of the layers' least times, by
+    the model class's ``attention_layers`` and ``needs`` (one of the two
+    above), times the steps traced, over the time taken."""
+    layers = run["model"].attention_layers(run["cfg"])
+    batch, seq_len = per_chip_batch(run), run["traffic"]["seq_len"]
+    least_a_step = math.fsum(
+        flops.roofline_seconds(needs(layer, batch, seq_len),
+                               run["device"]["kind"])[0] for layer in layers)
+    return 100.0 * least_a_step * (launches / len(layers)) / seconds
 
 
 def kernel_events(traced: dict, kernel: str) -> tuple:

@@ -1,12 +1,13 @@
 """Flash attention's backward's share of its roofline, from the device
 trace: the device time of chip 0's events named ``flash_attn_dq*`` and
-``flash_attn_dkv*`` (one launch of each per layer-step), per layer-step,
-against the larger of needed FLOPs over peak FLOP/s and needed bytes over
-peak HBM bytes/s for dQ and dK/dV together (``benchmarks/kernel_needs.py``;
-compute-bound at these shapes; recomputing S in both kernels is their own
-choice and is not credited). Nothing where either name is missing."""
+``flash_attn_dkv*`` (one launch of each a layer and step) against the larger
+of needed FLOPs over peak FLOP/s and needed bytes over peak HBM bytes/s for
+dQ and dK/dV together, summed over the model class's attention layers
+(``benchmarks/kernel_needs.py``; compute-bound at these shapes; recomputing S
+in both kernels is their own choice and is not credited). Nothing where
+either name is missing."""
 
-from benchmarks import flops, kernel_needs
+from benchmarks import kernel_needs
 
 
 def read(run: dict):
@@ -15,10 +16,6 @@ def read(run: dict):
     dkv_s, dkv_n = kernel_needs.kernel_events(traced, "flash_attn_dkv")
     if not dq_n or not dkv_n:
         return None
-    least, _ = flops.roofline_seconds(
-        kernel_needs.flash_attention_bwd(
-            run["cfg"], kernel_needs.per_chip_batch(run),
-            run["traffic"]["seq_len"]),
-        run["device"]["kind"])
-    layer_steps = (dq_n + dkv_n) / 2.0
-    return 100.0 * least * layer_steps / (dq_s + dkv_s)
+    return kernel_needs.roofline_share(
+        run, kernel_needs.flash_attention_bwd, dq_s + dkv_s,
+        (dq_n + dkv_n) / 2.0)

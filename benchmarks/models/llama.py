@@ -2,11 +2,76 @@
 built from a configuration file's published keys, and what the comparison
 needs to read out of the program's training state.
 
-A new model class is a new file beside this one with the same four
-functions; ``worker.py`` finds it by the configuration's ``model`` key.
+A new model class is new files beside these: ``<key>.py`` with the same
+functions (``harness.MODEL_CLASS``; stdlib at import, the parent reads the
+counts without JAX) and ``<key>_reference.py``, its plain reference;
+``harness.py`` finds both by the configuration's ``model`` key.
 """
 
 from __future__ import annotations
+
+
+def tiny(cfg: dict, traffic: dict) -> tuple:
+    """The rehearsal's sizes: every width shrunk, so nothing it prints can be
+    taken for a measurement."""
+    cfg = dict(cfg, hidden_size=128, intermediate_size=256,
+               num_hidden_layers=2, num_attention_heads=4,
+               num_key_value_heads=2, vocab_size=256)
+    traffic = dict(traffic, seq_len=64, rows=512)
+    if traffic.get("checkpoint"):
+        traffic["checkpoint"] = dict(traffic["checkpoint"], min_free_bytes=0)
+    return cfg, traffic
+
+
+# -- what a step and the attention kernels need, from the sizes alone --------
+# Copied in idea from ``dlrover_tpu/obs/mfu.py`` (6 x matmul parameters plus
+# the causal attention term, a gather embedding credited with nothing) so
+# that a later PR can change the program's own accounting without moving the
+# benchmark's.
+
+
+def head_dim(cfg: dict) -> int:
+    return cfg.get("head_dim") or cfg["hidden_size"] // cfg["num_attention_heads"]
+
+
+def param_counts(cfg: dict) -> dict:
+    """Parameters of a Llama-shaped decoder, split by what they cost."""
+    h, i, v = cfg["hidden_size"], cfg["intermediate_size"], cfg["vocab_size"]
+    layers = cfg["num_hidden_layers"]
+    q = cfg["num_attention_heads"] * head_dim(cfg)
+    kv = cfg["num_key_value_heads"] * head_dim(cfg)
+    per_layer_matmul = h * q + 2 * h * kv + q * h + 3 * h * i
+    tied = bool(cfg.get("tie_word_embeddings"))
+    return {
+        "matmul": layers * per_layer_matmul + v * h,   # blocks + head
+        "norm": (2 * layers + 1) * h,
+        "embedding": 0 if tied else v * h,             # a gather: no FLOPs
+    }
+
+
+def param_count(cfg: dict) -> int:
+    return sum(param_counts(cfg).values())
+
+
+def flops_per_token(cfg: dict, seq_len: int) -> float:
+    """Model FLOPs one trained token needs, forward and backward, nothing
+    recomputed: 6 per matmul parameter, and causal attention's
+    QK^T + PV = 4 h s forward, x3 with the backward, /2 for the mask."""
+    attention = 6.0 * cfg["num_hidden_layers"] * (
+        cfg["num_attention_heads"] * head_dim(cfg)) * seq_len
+    return 6.0 * param_counts(cfg)["matmul"] + attention
+
+
+def attention_layers(cfg: dict) -> list:
+    """One entry a layer, as ``kernel_needs`` takes them: every layer the
+    same, causal to the start (``window`` None)."""
+    return [{"heads": cfg["num_attention_heads"],
+             "kv_heads": cfg["num_key_value_heads"],
+             "head_dim": head_dim(cfg), "window": None}
+            for _ in range(cfg["num_hidden_layers"])]
+
+
+# -- the program, and what the comparison reads out of its state -------------
 
 
 def build(cfg: dict, traffic: dict):

@@ -1,5 +1,8 @@
-"""The plain reference: a Llama-shaped decoder, its loss, its gradients and
-the factored-RMS update, in straightforward ``jax.numpy`` and float32.
+"""The plain reference, the part every model class shares: rows and weights
+from the seed, the operand precisions, the head's loss, the factored-RMS
+update and a layer-by-layer training step, in straightforward ``jax.numpy``
+and float32. What is a class's own (its leaves, its block's forward) lies in
+``models/<key>_reference.py`` and is handed in as ``model``.
 
 It imports nothing of ``dlrover_tpu`` and takes nothing the program has made:
 weights come from ``--seed`` by the same rule flax uses (a key folded from the
@@ -22,7 +25,9 @@ from __future__ import annotations
 
 import functools
 import hashlib
+import json
 import random
+import typing
 
 import jax
 import jax.numpy as jnp
@@ -32,7 +37,6 @@ HIGHEST = jax.lax.Precision.HIGHEST
 EPSILON = 1e-30          # optax.scale_by_factored_rms defaults
 DECAY_EXPONENT = 0.8
 MIN_DIM_TO_FACTOR = 128
-INIT_STDDEV = 0.02
 
 
 # ---------------------------------------------------------------------------
@@ -95,51 +99,35 @@ def _flax_fold(path: tuple, count: int) -> int:
     return int.from_bytes(m.digest()[:4], "big")
 
 
-def leaf_shapes(cfg: dict) -> dict:
-    """name -> (shape, scope path, count in scope) of every parameter."""
-    h, i, v = cfg["hidden_size"], cfg["intermediate_size"], cfg["vocab_size"]
-    d = cfg.get("head_dim") or h // cfg["num_attention_heads"]
-    q, kv = cfg["num_attention_heads"] * d, cfg["num_key_value_heads"] * d
-    leaves = {"embed": ((v, h), (), 1)}
-    for layer in range(cfg["num_hidden_layers"]):
-        name = f"layer_{layer}"
-        for norm in ("attn_norm", "mlp_norm"):
-            leaves[f"{name}/{norm}/weight"] = ((h,), (name, norm), 1)
-        for proj, shape in (("q_proj", (h, q)), ("k_proj", (h, kv)),
-                            ("v_proj", (h, kv)), ("o_proj", (q, h))):
-            leaves[f"{name}/attn/{proj}/kernel"] = (
-                shape, (name, "attn", proj), 1)
-        for proj, shape in (("gate_proj", (h, i)), ("up_proj", (h, i)),
-                            ("down_proj", (i, h))):
-            leaves[f"{name}/mlp/{proj}/kernel"] = (
-                shape, (name, "mlp", proj), 1)
-    leaves["final_norm/weight"] = ((h,), ("final_norm",), 1)
-    if not cfg.get("tie_word_embeddings"):
-        leaves["lm_head"] = ((h, v), (), 2)
-    return leaves
+class Leaf(typing.NamedTuple):
+    """One parameter as a model class's ``leaves(cfg)`` describes it."""
+    shape: tuple
+    path: tuple             # the flax scope it is made in
+    count: int              # which parameter of that scope, from 1
+    stddev: float | None    # of its normal initialiser; None: ones
 
 
-@functools.partial(jax.jit, static_argnums=(2,))
-def _init_leaf(root, fold, shape):
+@functools.partial(jax.jit, static_argnums=(2, 3))
+def _init_normal(root, fold, shape, stddev):
     # one program per shape: the path's hash is an argument
     return jax.random.normal(jax.random.fold_in(root, fold), shape,
-                             jnp.float32) * INIT_STDDEV
+                             jnp.float32) * stddev
 
 
-def init_leaf(seed: int, cfg: dict, name: str):
-    shape, path, count = leaf_shapes(cfg)[name]
-    if len(shape) == 1:
-        return jnp.ones(shape, jnp.float32)
-    return _init_leaf(jax.random.PRNGKey(seed),
-                      np.uint32(_flax_fold(path, count)), shape)
+def init_leaf(seed: int, leaf: Leaf):
+    if leaf.stddev is None:
+        return jnp.ones(leaf.shape, jnp.float32)
+    return _init_normal(jax.random.PRNGKey(seed),
+                        np.uint32(_flax_fold(leaf.path, leaf.count)),
+                        leaf.shape, leaf.stddev)
 
 
-def init_params(seed: int, cfg: dict) -> dict:
-    return {name: init_leaf(seed, cfg, name) for name in leaf_shapes(cfg)}
+def init_params(seed: int, leaves: dict) -> dict:
+    return {name: init_leaf(seed, leaf) for name, leaf in leaves.items()}
 
 
 # ---------------------------------------------------------------------------
-# The model
+# What every class's model is made of
 # ---------------------------------------------------------------------------
 
 
@@ -152,7 +140,7 @@ def _int8(x, axis: int):
     return x + jax.lax.stop_gradient(rounded - x)
 
 
-def _product(spec: str, a, b, mode: str, a_axis: int, b_axis: int):
+def product(spec: str, a, b, mode: str, a_axis: int, b_axis: int):
     """einsum in the given operand precision; ``*_axis`` is each operand's
     contracted axis (the one an int8 scale runs along)."""
     if mode == "f32":
@@ -167,8 +155,8 @@ def _product(spec: str, a, b, mode: str, a_axis: int, b_axis: int):
     raise ValueError(f"unknown precision mode {mode!r}")
 
 
-def _linear(x, w, mode):
-    return _product("...k,kn->...n", x, w, mode, -1, 0)
+def linear(x, w, mode):
+    return product("...k,kn->...n", x, w, mode, -1, 0)
 
 
 def rms_norm(x, weight, eps):
@@ -176,60 +164,11 @@ def rms_norm(x, weight, eps):
         jnp.mean(x * x, axis=-1, keepdims=True) + eps) * weight
 
 
-def rope(x, theta: float):
-    """Rotary embedding, halves rotated against each other (the
-    published models' layout), on (batch, seq, heads, head_dim)."""
-    d = x.shape[-1]
-    freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    angles = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * freq
-    cos, sin = jnp.cos(angles)[None, :, None, :], jnp.sin(angles)[None, :, None, :]
-    x1, x2 = x[..., :d // 2], x[..., d // 2:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-
-def attention(q, k, v, mode):
-    """Causal softmax attention, grouped-query: (b, s, heads, d) with
-    k and v on fewer heads, each shared by heads/kv_heads queries."""
-    b, s, heads, d = q.shape
-    group = heads // k.shape[2]
-    k = jnp.repeat(k, group, axis=2)
-    v = jnp.repeat(v, group, axis=2)
-    scores = _product("bqhd,bkhd->bhqk", q, k, mode, -1, -1) / np.sqrt(d)
-    mask = jnp.tril(jnp.ones((s, s), bool))
-    scores = jnp.where(mask[None, None], scores, -jnp.inf)
-    probs = jax.nn.softmax(scores, axis=-1)
-    return _product("bhqk,bkhd->bqhd", probs, v, mode, -1, 1)
-
-
-def block(x, p: dict, cfg: dict, mode: str):
-    """One decoder block on (batch, seq, hidden); ``p`` holds the block's
-    nine leaves by their short names."""
-    b, s, _ = x.shape
-    d = cfg.get("head_dim") or cfg["hidden_size"] // cfg["num_attention_heads"]
-    eps, theta = cfg["rms_norm_eps"], cfg["rope_theta"]
-    y = rms_norm(x, p["attn_norm/weight"], eps)
-    q = _linear(y, p["attn/q_proj/kernel"], mode).reshape(b, s, -1, d)
-    k = _linear(y, p["attn/k_proj/kernel"], mode).reshape(b, s, -1, d)
-    v = _linear(y, p["attn/v_proj/kernel"], mode).reshape(b, s, -1, d)
-    out = attention(rope(q, theta), rope(k, theta), v, mode)
-    x = x + _linear(out.reshape(b, s, -1), p["attn/o_proj/kernel"], mode)
-    y = rms_norm(x, p["mlp_norm/weight"], eps)
-    gate = _linear(y, p["mlp/gate_proj/kernel"], mode)
-    up = _linear(y, p["mlp/up_proj/kernel"], mode)
-    return x + _linear(jax.nn.silu(gate) * up, p["mlp/down_proj/kernel"], mode)
-
-
-def head_loss(x, final_norm, head, targets, cfg: dict, mode: str):
+def head_loss(x, final_norm, head, targets, eps: float, mode: str):
     """Final norm, output head and the mean next-token cross entropy."""
-    logits = _linear(rms_norm(x, final_norm, cfg["rms_norm_eps"]), head, mode)
+    logits = linear(rms_norm(x, final_norm, eps), head, mode)
     logp = jax.nn.log_softmax(logits, axis=-1)
     return -jnp.mean(jnp.take_along_axis(logp, targets[..., None], -1))
-
-
-def layer_params(params: dict, layer: int) -> dict:
-    prefix = f"layer_{layer}/"
-    return {k[len(prefix):]: v for k, v in params.items()
-            if k.startswith(prefix)}
 
 
 # ---------------------------------------------------------------------------
@@ -289,26 +228,42 @@ def _apply(ps: dict, gs: dict, moments: dict, count, lr):
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.jit, static_argnums=(2, 3))
-def _block_forward(x, p, cfg_items, mode):
-    return block(x, p, dict(cfg_items), mode)
+class _Block:
+    """A class's block with its configuration, as one hashable argument of a
+    jitted function: a configuration holds lists (a kind for every layer)."""
+
+    def __init__(self, forward, cfg: dict):
+        self.forward, self.cfg = forward, cfg
+        self._key = (forward, json.dumps(cfg, sort_keys=True))
+
+    def __hash__(self):
+        return hash(self._key)
+
+    def __eq__(self, other):
+        return isinstance(other, _Block) and self._key == other._key
 
 
-@functools.partial(jax.jit, static_argnums=(6, 7), donate_argnums=(1, 2))
-def _block_backward(x, p, moments, dy, count, lr, cfg_items, mode):
-    _, vjp = jax.vjp(lambda x_, p_: block(x_, p_, dict(cfg_items), mode), x, p)
+# ``layer`` is the first layer of its kind, so layers of one kind share a
+# compiled program
+@functools.partial(jax.jit, static_argnums=(2, 3, 4))
+def _block_forward(x, p, block, layer, mode):
+    return block.forward(x, p, block.cfg, layer, mode)
+
+
+@functools.partial(jax.jit, static_argnums=(6, 7, 8), donate_argnums=(1, 2))
+def _block_backward(x, p, moments, dy, count, lr, block, layer, mode):
+    _, vjp = jax.vjp(
+        lambda x_, p_: block.forward(x_, p_, block.cfg, layer, mode), x, p)
     dx, gp = vjp(dy)
     new_p, new_m, norms = _apply(p, gp, moments, count, lr)
     return dx, new_p, new_m, norms
 
 
 @functools.partial(jax.jit, static_argnums=(6, 7), donate_argnums=(1, 2))
-def _head_backward(x, p, moments, targets, count, lr, cfg_items, mode):
-    cfg = dict(cfg_items)
-
+def _head_backward(x, p, moments, targets, count, lr, eps, mode):
     def f(x_, p_):
         return head_loss(x_, p_["final_norm/weight"], p_["lm_head"],
-                         targets, cfg, mode)
+                         targets, eps, mode)
 
     loss, (dx, gp) = jax.value_and_grad(f, argnums=(0, 1))(x, p)
     new_p, new_m, norms = _apply(p, gp, moments, count, lr)
@@ -323,58 +278,65 @@ def _embed_backward(embed, moment, tokens, dx, count, lr):
 
 
 class Trainer:
-    """The reference's training state and its step. ``cfg`` is a
-    configuration file's dict; untied embeddings only (both configurations'
-    case)."""
+    """The reference's training state and its step: an embedding, the
+    class's blocks one after the other, a final norm and an untied head
+    (every configuration's case). ``model`` is the class's reference
+    (``harness.model_reference``), ``cfg`` a configuration file's dict."""
 
-    def __init__(self, seed: int, cfg: dict, mode: str = "f32"):
+    def __init__(self, model, seed: int, cfg: dict, mode: str = "f32"):
         if cfg.get("tie_word_embeddings"):
             raise NotImplementedError("tied embeddings: no cell has them")
         self.cfg, self.mode, self.seed = cfg, mode, seed
         self.lr = float(cfg["optimizer"]["learning_rate"])
-        self._items = tuple(sorted(
-            (k, v) for k, v in cfg.items()
-            if isinstance(v, (int, float, bool)) and not isinstance(v, str)))
-        self.params = init_params(seed, cfg)
+        self.leaves = model.leaves(cfg)
+        self.prefixes = [model.layer_prefix(layer)
+                         for layer in range(cfg["num_hidden_layers"])]
+        kinds = [model.layer_kind(cfg, layer)
+                 for layer in range(len(self.prefixes))]
+        self.first_of_kind = [kinds.index(kind) for kind in kinds]
+        self.block = _Block(model.block, cfg)
+        self.params = init_params(seed, self.leaves)
         self.moments = {name: init_moment(p.shape)
                         for name, p in self.params.items()}
         self.count = 0
 
-    def _store(self, tree: dict, layer: int, group: dict) -> None:
-        for name, value in group.items():
-            tree[f"layer_{layer}/{name}"] = value
+    def _layer(self, tree: dict, layer: int, pop: bool = False) -> dict:
+        """One layer's entries of ``tree`` by their short names."""
+        prefix = self.prefixes[layer]
+        take = tree.pop if pop else tree.get
+        return {name[len(prefix):]: take(name) for name in list(tree)
+                if name.startswith(prefix)}
 
     def step(self, tokens, targets) -> dict:
         """One update on a global batch; returns the loss and every leaf's
         gradient norm (floats)."""
         tokens, targets = jnp.asarray(tokens), jnp.asarray(targets)
-        layers = self.cfg["num_hidden_layers"]
+        layers = range(len(self.prefixes))
         count = jnp.asarray(self.count, jnp.int32)
         x = self.params["embed"][tokens]
         inputs = []
-        for layer in range(layers):
+        for layer in layers:
             inputs.append(x)
-            x = _block_forward(x, layer_params(self.params, layer),
-                               self._items, self.mode)
+            x = _block_forward(x, self._layer(self.params, layer), self.block,
+                               self.first_of_kind[layer], self.mode)
         top = ("final_norm/weight", "lm_head")
         loss, dx, new_p, new_m, norms = _head_backward(
             x, {k: self.params.pop(k) for k in top},
             {k: self.moments.pop(k) for k in top},
-            targets, count, self.lr, self._items, self.mode)
+            targets, count, self.lr, self.cfg["rms_norm_eps"], self.mode)
         self.params.update(new_p)
         self.moments.update(new_m)
         grad_norms = dict(norms)
-        for layer in reversed(range(layers)):
-            names = list(layer_params(self.params, layer))
-            p = {n: self.params.pop(f"layer_{layer}/{n}") for n in names}
-            m = {n: self.moments.pop(f"layer_{layer}/{n}") for n in names}
+        for layer in reversed(layers):
+            prefix = self.prefixes[layer]
             dx, new_p, new_m, norms = _block_backward(
-                inputs.pop(), p, m, dx, count, self.lr, self._items,
-                self.mode)
-            self._store(self.params, layer, new_p)
-            self._store(self.moments, layer, new_m)
-            for name, value in norms.items():
-                grad_norms[f"layer_{layer}/{name}"] = value
+                inputs.pop(), self._layer(self.params, layer, pop=True),
+                self._layer(self.moments, layer, pop=True), dx, count,
+                self.lr, self.block, self.first_of_kind[layer], self.mode)
+            for name in new_p:
+                self.params[prefix + name] = new_p[name]
+                self.moments[prefix + name] = new_m[name]
+                grad_norms[prefix + name] = norms[name]
         embed, moment, norm = _embed_backward(
             self.params.pop("embed"), self.moments.pop("embed"), tokens, dx,
             count, self.lr)
@@ -387,8 +349,8 @@ class Trainer:
     def change_norms(self) -> dict:
         """Norm of each leaf's change since the seed's initial value, the
         initial leaf made again one at a time."""
-        return {name: float(_change_norm(p, init_leaf(self.seed, self.cfg,
-                                                      name)))
+        return {name: float(_change_norm(p, init_leaf(self.seed,
+                                                      self.leaves[name])))
                 for name, p in self.params.items()}
 
 
@@ -398,14 +360,14 @@ def _change_norm(now, initial):
     return jnp.sqrt(jnp.sum(delta * delta))
 
 
-def follow(seed: int, cfg: dict, batches: list, mode: str = "f32",
+def follow(model, seed: int, cfg: dict, batches: list, mode: str = "f32",
            keep_rows=None) -> dict:
-    """Drive a fresh reference through ``batches`` ((tokens, targets) each)
-    and return what the comparison reads: each step's loss, the first
-    gradient's norm by leaf, each leaf's change after the last step.
-    ``keep_rows`` plants a fault: only that many rows of each batch are
-    trained on, the mean taken over them."""
-    trainer = Trainer(seed, cfg, mode)
+    """Drive a fresh reference of the class ``model`` through ``batches``
+    ((tokens, targets) each) and return what the comparison reads: each
+    step's loss, the first gradient's norm by leaf, each leaf's change after
+    the last step. ``keep_rows`` plants a fault: only that many rows of each
+    batch are trained on, the mean taken over them."""
+    trainer = Trainer(model, seed, cfg, mode)
     steps = [trainer.step(tokens[:keep_rows], targets[:keep_rows])
              for tokens, targets in batches]
     return {"losses": [s["loss"] for s in steps],

@@ -75,8 +75,8 @@ def gather(records: list, bench: dict, workload: str, started_wall: float,
     """Everything a metric's reader may look at, under one roof."""
     entry, cfg, traffic = harness.cell(bench, workload)
     run = {"bench": bench, "workload": entry, "cfg": cfg, "traffic": traffic,
-           "started_wall": started_wall, "asked_seconds": seconds,
-           "traced_run": trace}
+           "model": harness.model_class(cfg), "started_wall": started_wall,
+           "asked_seconds": seconds, "traced_run": trace}
     for record in records:
         run[record["record"]] = record
     return run

@@ -4,14 +4,16 @@ in seconds and is tested on hand-made lists; ``load`` is the only part that
 needs JAX (``jax.profiler.ProfileData``) and the only part that knows how
 today's trace names things.
 
-What a TPU v5e trace looks like today (read by hand, PR 26): one plane per
-chip, ``/device:TPU:<n>``, with the lines ``Steps``, ``XLA Modules`` (one
-event per program run), ``XLA Ops`` (one event per executed HLO op, start and
-duration; ~9,600 distinct ops and ~9,500 events a step for the 24-layer
-model) and ``Async XLA Ops``. An op's name is its whole HLO text. A Pallas
-kernel is a ``custom-call`` with ``custom_call_target="tpu_custom_call"``; no
-kernel carries a name of its own (the program passes none), so kernels are
-told apart by their operands' shapes. Host threads are lines of ``/host:CPU``.
+What a TPU v5e trace looks like today (read by hand, PRs 26 and 28): one
+plane per chip, ``/device:TPU:<n>``, with the lines ``Steps``, ``XLA Modules``
+(one event per program run), ``XLA Ops`` (one event per executed HLO op, start
+and duration; ~9,600 distinct ops and ~9,500 events a step for the 24-layer
+model) and ``Async XLA Ops``. An op's name is its whole HLO text without
+``metadata=`` (so no ``op_name``). A Pallas kernel is a ``custom-call`` with
+``custom_call_target="tpu_custom_call"`` whose instruction name is the name
+the program gave its ``pallas_call`` (``%flash_attn_fwd.3 = ...``; since PR
+28), which is how ``kernel_needs.kernel_events`` finds it. Host threads are
+lines of ``/host:CPU``.
 """
 
 from __future__ import annotations

@@ -267,7 +267,7 @@ def run(ctx) -> int:
         1 for index, call in enumerate(feed.calls)
         if call["digest"] != digest(truth.batch(index, batch)))
     followed = reference.follow(
-        seed, cfg,
+        ctx.model_reference, seed, cfg,
         [truth.batch(k, batch) for k in range(traffic["warmup_steps"])])
     compared = check.compare(program, followed, rows_wrong)
     finite = all(map(math.isfinite, program["losses"] + [

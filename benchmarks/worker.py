@@ -1,6 +1,8 @@
 """The process that holds the chip: started by ``run.py`` under
 ``python -m dlrover_tpu.run --standalone``, it finds the cell's configuration,
-traffic mix, model class and window kind by name and runs the window."""
+traffic mix, model class (a class that lacks one of the contract's functions
+fails here, by the function's name) and window kind by name and runs the
+window."""
 
 from __future__ import annotations
 
@@ -23,34 +25,25 @@ class Context:
     chips: int
     cfg: dict
     traffic: dict
-    model: object
+    model: object               # the model class, ``models/<key>.py``
+    model_reference: object     # its plain reference, ``<key>_reference.py``
     report: harness.Report
     workdir: str
     rehearse: bool = False      # off the TPU, at a tiny width: never a result
     in_process: bool = False    # no launcher above: no master client
 
 
-def tiny(cfg: dict, traffic: dict) -> tuple:
-    """The rehearsal's sizes: every width shrunk, so nothing it prints can be
-    taken for a measurement."""
-    cfg = dict(cfg, hidden_size=128, intermediate_size=256,
-               num_hidden_layers=2, num_attention_heads=4,
-               num_key_value_heads=2, vocab_size=256)
-    traffic = dict(traffic, seq_len=64, rows=512)
-    if traffic.get("checkpoint"):
-        traffic["checkpoint"] = dict(traffic["checkpoint"], min_free_bytes=0)
-    return cfg, traffic
-
-
 def context(workload: str, seed: int, seconds: float, trace: bool,
             report_path: str, workdir: str, rehearse: bool = False,
             in_process: bool = False) -> Context:
     entry, cfg, traffic = harness.cell(harness.benchmark(), workload)
+    model = harness.model_class(cfg)
     if rehearse:
-        cfg, traffic = tiny(cfg, traffic)
+        cfg, traffic = model.tiny(cfg, traffic)
     return Context(workload=workload, seed=seed, seconds=seconds, trace=trace,
                    chips=entry["chips"], cfg=cfg, traffic=traffic,
-                   model=harness.load_module("models", cfg["model"]),
+                   model=model,
+                   model_reference=harness.model_reference(cfg),
                    report=harness.Report(report_path), workdir=workdir,
                    rehearse=rehearse, in_process=in_process)
 

@@ -16,11 +16,20 @@ import re
 import numpy as np
 import pytest
 
-from benchmarks import check, feed, flops, harness, reference, trace_reduce
+from benchmarks import (
+    check,
+    feed,
+    flops,
+    harness,
+    kernel_needs,
+    reference,
+    trace_reduce,
+)
 from benchmarks import run as bench_run
 from benchmarks import worker
 
 BENCH = harness.benchmark()
+LLAMA = harness.model_reference({"model": "llama"})
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 # the rehearsal's widths read other gaps than the chip's sizes; these are the
@@ -56,7 +65,8 @@ def test_benchmark_json_names_units_and_keys():
 def test_every_file_is_found_by_name():
     for entry in BENCH["workloads"]:
         _, cfg, traffic = harness.cell(BENCH, entry["name"])
-        assert hasattr(harness.load_module("models", cfg["model"]), "build")
+        assert harness.model_class(cfg).build
+        assert harness.model_reference(cfg).block
         assert hasattr(harness.load_module("windows", traffic["window"]),
                        "run")
         assert set(check.limits_for(entry["name"])) == {
@@ -95,21 +105,29 @@ def test_flops_agree_with_the_programs_parameter_count(name, params,
 
     config = next(c for c in BENCH["configs"] if c["name"] == name)
     cfg = harness.load_json(harness.ROOT, config["file"])
-    assert flops.param_count(cfg) == params
-    assert flops.param_count(cfg) == LlamaConfig(
+    model = harness.model_class(cfg)
+    assert model.param_count(cfg) == params
+    assert model.param_count(cfg) == LlamaConfig(
         vocab_size=cfg["vocab_size"], hidden_size=cfg["hidden_size"],
         intermediate_size=cfg["intermediate_size"],
         num_layers=cfg["num_hidden_layers"],
         num_heads=cfg["num_attention_heads"],
         num_kv_heads=cfg["num_key_value_heads"]).param_count()
-    assert flops.flops_per_token(cfg, 2048) == pytest.approx(per_token,
+    assert model.flops_per_token(cfg, 2048) == pytest.approx(per_token,
                                                              rel=5e-4)
-    assert sum(np.prod(shape) for shape, _, _ in
-               reference.leaf_shapes(cfg).values()) == params
-    needs = flops.flash_attention_needs(cfg, 2, 2048)
-    assert needs["flops"] * cfg["num_hidden_layers"] == pytest.approx(
+    assert sum(np.prod(leaf.shape) for leaf in
+               harness.model_reference(cfg).leaves(cfg).values()) == params
+    # the attention term of the class's count is its layers' kernels' needs
+    layers = model.attention_layers(cfg)
+    assert len(layers) == cfg["num_hidden_layers"]
+    kernels = sum(needs(layer, 2, 2048)["flops"] for layer in layers
+                  for needs in (kernel_needs.flash_attention_fwd,
+                                kernel_needs.flash_attention_bwd))
+    assert kernels == pytest.approx(
         6.0 * cfg["num_hidden_layers"] * cfg["hidden_size"] * 2048 * 4096)
-    assert flops.roofline_seconds(needs, "TPU v5 lite")[1] == "compute"
+    assert kernels / 4096 == pytest.approx(
+        model.flops_per_token(cfg, 2048)
+        - 6.0 * model.param_counts(cfg)["matmul"])
 
 
 def test_a_device_kind_without_a_peak_raises():
@@ -219,7 +237,7 @@ def test_the_reference_agrees_with_the_programs_plain_llama():
     params = nn.unbox(model.init(jax.random.PRNGKey(seed),
                                  batches[0][0][:1]))["params"]
     initial = _named(params)
-    mine = reference.init_params(seed, TINY)
+    mine = reference.init_params(seed, LLAMA.leaves(TINY))
     assert set(mine) == set(initial)
     for name in mine:
         np.testing.assert_allclose(mine[name], initial[name], atol=1e-7)
@@ -240,7 +258,7 @@ def test_the_reference_agrees_with_the_programs_plain_llama():
     theirs = {"losses": losses, "grad_norms": first,
               "change_norms": {k: float(jnp.linalg.norm(v - initial[k]))
                                for k, v in _named(params).items()}}
-    got = check.compare(theirs, reference.follow(seed, TINY, batches), 0)
+    got = check.compare(theirs, reference.follow(LLAMA, seed, TINY, batches), 0)
     assert got["loss_gap"]["value"] < 1e-6
     assert got["grad_gap"]["value"] < 1e-5
     assert got["change_gap"]["value"] < 1e-5
@@ -261,14 +279,14 @@ def test_the_control_comes_out_not_correct(seed):
     not, and neither does the reference with half of each batch left out."""
     rows = reference.Rows(seed, 1024, 64, 256)
     batches = [rows.batch(k, 2) for k in range(3)]
-    truth = reference.follow(seed, SMALL, batches)
-    stated = check.compare(reference.follow(seed, SMALL, batches, "bf16"),
+    truth = reference.follow(LLAMA, seed, SMALL, batches)
+    stated = check.compare(reference.follow(LLAMA, seed, SMALL, batches, "bf16"),
                            truth, 0)
     assert check.verdict(stated, SMALL_LIMITS)[0], stated
-    control = check.compare(reference.follow(seed, SMALL, batches, "int8"),
+    control = check.compare(reference.follow(LLAMA, seed, SMALL, batches, "int8"),
                             truth, 0)
     assert not check.verdict(control, SMALL_LIMITS)[0], control
-    half = check.compare(reference.follow(seed, SMALL, batches, keep_rows=1),
+    half = check.compare(reference.follow(LLAMA, seed, SMALL, batches, keep_rows=1),
                          truth, 0)
     assert not check.verdict(half, SMALL_LIMITS)[0], half
     assert half["grad_gap"]["value"] > 10 * stated["grad_gap"]["value"]
@@ -336,7 +354,7 @@ def test_a_run_past_the_chip_check_is_correct_only_unbroken(
     if fault == "saving_mix":
         # the mix kept for the saving cell (PERF.md section 7), through the
         # same window: the loop saves, the window waits for the commit
-        _, ctx.traffic = worker.tiny(ctx.cfg, harness.load_json(
+        _, ctx.traffic = ctx.model.tiny(ctx.cfg, harness.load_json(
             harness.HERE, "traffic", "save_b2_s2048_i40.json"))
     window = harness.load_module("windows", ctx.traffic["window"])
     assert window.run(ctx) == 0

@@ -21,7 +21,7 @@ NEW_TRACE_METRICS = ("kernels.flash_attn_fwd_roofline",
 def _cell(name: str = "internlm2_1p8b.steady") -> dict:
     entry, cfg, traffic = harness.cell(BENCH, name)
     return {"workload": entry, "cfg": cfg, "traffic": traffic,
-            "device": {"kind": KIND}}
+            "model": harness.model_class(cfg), "device": {"kind": KIND}}
 
 
 def _span(start, end, **attrs):
@@ -109,17 +109,32 @@ def test_span_readers_give_nothing_without_a_train_window_span(name):
 
 @pytest.mark.parametrize("config", [c["name"] for c in BENCH["configs"]])
 def test_kernel_needs_forward_plus_backward_is_the_accepted_count(config):
+    """The count PRs 26-30 were measured against, written out: one causal
+    layer's forward plus backward is 6 matmuls of 2 b heads s^2 d, halved by
+    the mask; every operand read and every result written once in bf16
+    (forward q, k, v -> o; backward q, k, v, o, do -> dq, dk, dv) plus the
+    fp32 row statistics, once each way."""
     cfg = harness.load_json(harness.ROOT, next(
         c["file"] for c in BENCH["configs"] if c["name"] == config))
+    layer = harness.model_class(cfg).attention_layers(cfg)[0]
+    heads, kv_heads = cfg["num_attention_heads"], cfg["num_key_value_heads"]
+    d = cfg["hidden_size"] // heads
+    assert layer == {"heads": heads, "kv_heads": kv_heads, "head_dim": d,
+                     "window": None}
     for batch, seq in ((2, 2048), (1, 4096), (8, 512)):
-        whole = flops.flash_attention_needs(cfg, batch, seq)
-        fwd = kernel_needs.flash_attention_fwd(cfg, batch, seq)
-        bwd = kernel_needs.flash_attention_bwd(cfg, batch, seq)
-        assert fwd["flops"] + bwd["flops"] == whole["flops"]
-        assert fwd["bytes"] + bwd["bytes"] == whole["bytes"]
+        fwd = kernel_needs.flash_attention_fwd(layer, batch, seq)
+        bwd = kernel_needs.flash_attention_bwd(layer, batch, seq)
+        q_bytes = batch * heads * seq * d * 2
+        kv_bytes = batch * kv_heads * seq * d * 2
+        stats = batch * heads * seq * 4
+        assert fwd["flops"] + bwd["flops"] == (
+            6.0 * 2.0 * batch * heads * seq * seq * d / 2.0)
+        assert fwd["bytes"] + bwd["bytes"] == float(
+            (q_bytes + 2 * kv_bytes + q_bytes + stats)
+            + (3 * q_bytes + 2 * kv_bytes + stats + q_bytes + 2 * kv_bytes))
         assert bwd["flops"] == 2 * fwd["flops"]
     assert flops.roofline_seconds(
-        kernel_needs.flash_attention_fwd(cfg, 2, 2048), KIND)[1] == "compute"
+        kernel_needs.flash_attention_fwd(layer, 2, 2048), KIND)[1] == "compute"
 
 
 # -- device events by name ---------------------------------------------------
@@ -137,10 +152,11 @@ def _traced(run: dict) -> dict:
     """A trace in which each attention kernel runs at exactly half its
     roofline, three launches of each under two instance names, and the
     norms take 2 % of the device's time."""
-    cfg, seq = run["cfg"], run["traffic"]["seq_len"]
+    seq = run["traffic"]["seq_len"]
+    layer = run["model"].attention_layers(run["cfg"])[0]
 
     def least(needs):
-        return flops.roofline_seconds(needs(cfg, 2, seq), KIND)[0]
+        return flops.roofline_seconds(needs(layer, 2, seq), KIND)[0]
 
     fwd = least(kernel_needs.flash_attention_fwd)
     bwd = least(kernel_needs.flash_attention_bwd)
@@ -177,17 +193,19 @@ def test_kernel_readers_find_the_kernels_by_name(name, expected):
 
 
 def test_fwd_and_bwd_weighted_by_their_needs_give_the_old_whole():
-    """The accepted ``kernels.flash_attn_roofline`` holds all three
-    kernels against forward + backward together; the two new shares,
-    weighted by their parts of that least time, are the same number."""
+    """``kernels.flash_attn_roofline`` (PRs 26-30, retired in PR 31) held
+    all three kernels against forward + backward together; the two shares
+    that stay, weighted by their parts of that least time, are that
+    number, so its history in the ledger can still be read against them."""
     run = _cell()
     run["traced"] = _traced(run)
     fwd = harness.load_module(
         "metrics", "kernels.flash_attn_fwd_roofline").read(run)
     bwd = harness.load_module(
         "metrics", "kernels.flash_attn_bwd_roofline").read(run)
-    cfg, seq = run["cfg"], run["traffic"]["seq_len"]
-    part = {k: flops.roofline_seconds(needs(cfg, 2, seq), KIND)[0]
+    seq = run["traffic"]["seq_len"]
+    layer = run["model"].attention_layers(run["cfg"])[0]
+    part = {k: flops.roofline_seconds(needs(layer, 2, seq), KIND)[0]
             for k, needs in (("fwd", kernel_needs.flash_attention_fwd),
                              ("bwd", kernel_needs.flash_attention_bwd))}
     whole = 100.0 * (part["fwd"] + part["bwd"]) / (
