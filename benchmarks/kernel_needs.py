@@ -1,5 +1,6 @@
 """What each named kernel needs, from shapes alone: FLOPs and HBM bytes of
-one launch, for the per-kernel roofline readers. Stdlib only.
+one launch, for the per-kernel roofline readers; and what the readers take
+from a reduced trace, by kernel name or by scope. Stdlib only.
 
 An attention layer is one entry of the model class's ``attention_layers``:
 ``heads``, ``kv_heads``, ``head_dim`` and ``window`` (None: causal to the
@@ -7,7 +8,8 @@ start). The counts are split where the program's kernel names split them
 (``flash_attn_fwd``; ``flash_attn_dq`` + ``flash_attn_dkv``). The norm
 kernels have no entry: their operands live in the chip's fast memory space,
 for which there is no public peak (PERF.md, PR 28), so their reader reports a
-share of the device's time instead.
+share of the device's time instead. What a class's program does outside a
+kernel is read by scope (``scope_share``).
 """
 
 from __future__ import annotations
@@ -87,3 +89,18 @@ def kernel_events(traced: dict, kernel: str) -> tuple:
 
 def per_chip_batch(run: dict) -> int:
     return run["traffic"]["global_batch"] // max(1, run["workload"]["chips"])
+
+
+def scope_share(traced: dict, name: str):
+    """Share, in %, of chip 0's device-event time spent under the scope
+    ``name``: a Flax module's, a ``jax.named_scope``'s, a transformation's
+    (``transpose(jvp(``: the backward) or ``unscoped``, as
+    ``trace_reduce.scopes_of`` takes them from an instruction's ``op_name``.
+    Over all of that chip's device-event time, as
+    ``kernels.rms_norm_share`` is. None where the record has no time by
+    scope (the window held no text of its program) or the program no such
+    scope."""
+    by_scope = traced.get("by_scope") or {}
+    if name not in by_scope:
+        return None
+    return 100.0 * by_scope[name] / math.fsum(traced["by_name"].values())

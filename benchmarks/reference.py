@@ -1,8 +1,9 @@
 """The plain reference, the part every model class shares: rows and weights
 from the seed, the operand precisions, the head's loss, the factored-RMS
 update and a layer-by-layer training step, in straightforward ``jax.numpy``
-and float32. What is a class's own (its leaves, its block's forward) lies in
-``models/<key>_reference.py`` and is handed in as ``model``.
+and float32. What is a class's own (its leaves, its block's forward and any
+term its block adds to the objective) lies in ``models/<key>_reference.py``
+and is handed in as ``model``.
 
 It imports nothing of ``dlrover_tpu`` and takes nothing the program has made:
 weights come from ``--seed`` by the same rule flax uses (a key folded from the
@@ -244,7 +245,10 @@ class _Block:
 
 
 # ``layer`` is the first layer of its kind, so layers of one kind share a
-# compiled program
+# compiled program. A block returns ``x``, or ``(x, extra)`` where its layer
+# adds a term to the objective: ``extra`` is a scalar already weighted, as the
+# program's model sows it into ``losses``. Which of the two is Python's to see
+# while tracing, so a block that returns ``x`` alone traces as it always has.
 @functools.partial(jax.jit, static_argnums=(2, 3, 4))
 def _block_forward(x, p, block, layer, mode):
     return block.forward(x, p, block.cfg, layer, mode)
@@ -252,9 +256,11 @@ def _block_forward(x, p, block, layer, mode):
 
 @functools.partial(jax.jit, static_argnums=(6, 7, 8), donate_argnums=(1, 2))
 def _block_backward(x, p, moments, dy, count, lr, block, layer, mode):
-    _, vjp = jax.vjp(
+    out, vjp = jax.vjp(
         lambda x_, p_: block.forward(x_, p_, block.cfg, layer, mode), x, p)
-    dx, gp = vjp(dy)
+    # the objective is the head's loss plus every layer's extra: d/d extra = 1
+    dx, gp = vjp((dy, jnp.ones_like(out[1])) if isinstance(out, tuple)
+                 else dy)
     new_p, new_m, norms = _apply(p, gp, moments, count, lr)
     return dx, new_p, new_m, norms
 
@@ -308,17 +314,21 @@ class Trainer:
                 if name.startswith(prefix)}
 
     def step(self, tokens, targets) -> dict:
-        """One update on a global batch; returns the loss and every leaf's
-        gradient norm (floats)."""
+        """One update on a global batch; returns the loss (the head's plus
+        what the layers' blocks add: the whole objective, as the program
+        reports it) and every leaf's gradient norm (floats)."""
         tokens, targets = jnp.asarray(tokens), jnp.asarray(targets)
         layers = range(len(self.prefixes))
         count = jnp.asarray(self.count, jnp.int32)
         x = self.params["embed"][tokens]
-        inputs = []
+        inputs, extras = [], []
         for layer in layers:
             inputs.append(x)
             x = _block_forward(x, self._layer(self.params, layer), self.block,
                                self.first_of_kind[layer], self.mode)
+            if isinstance(x, tuple):
+                x, extra = x
+                extras.append(extra)
         top = ("final_norm/weight", "lm_head")
         loss, dx, new_p, new_m, norms = _head_backward(
             x, {k: self.params.pop(k) for k in top},
@@ -343,7 +353,7 @@ class Trainer:
         self.params["embed"], self.moments["embed"] = embed, moment
         grad_norms["embed"] = norm
         self.count += 1
-        return {"loss": float(loss),
+        return {"loss": float(loss) + sum(map(float, extras)),
                 "grad_norms": {k: float(v) for k, v in grad_norms.items()}}
 
     def change_norms(self) -> dict:
@@ -364,9 +374,10 @@ def follow(model, seed: int, cfg: dict, batches: list, mode: str = "f32",
            keep_rows=None) -> dict:
     """Drive a fresh reference of the class ``model`` through ``batches``
     ((tokens, targets) each) and return what the comparison reads: each
-    step's loss, the first gradient's norm by leaf, each leaf's change after
-    the last step. ``keep_rows`` plants a fault: only that many rows of each
-    batch are trained on, the mean taken over them."""
+    step's loss (the whole objective), the first gradient's norm by leaf,
+    each leaf's change after the last step. ``keep_rows`` plants a fault:
+    only that many rows of each batch are trained on, the mean taken over
+    them."""
     trainer = Trainer(model, seed, cfg, mode)
     steps = [trainer.step(tokens[:keep_rows], targets[:keep_rows])
              for tokens, targets in batches]

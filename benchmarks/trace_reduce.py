@@ -1,8 +1,9 @@
 """From a profiler trace to numbers: device busy time, idle gaps, the time of
-named kernels. The interval arithmetic is plain Python on (start, end) pairs
-in seconds and is tested on hand-made lists; ``load`` is the only part that
-needs JAX (``jax.profiler.ProfileData``) and the only part that knows how
-today's trace names things.
+named kernels and the time under each scope of the program. The interval
+arithmetic is plain Python on (start, end) pairs in seconds and is tested on
+hand-made lists; ``load`` is the only part that needs JAX
+(``jax.profiler.ProfileData``) and the only part that knows how today's trace
+names things.
 
 What a TPU v5e trace looks like today (read by hand, PRs 26 and 28): one
 plane per chip, ``/device:TPU:<n>``, with the lines ``Steps``, ``XLA Modules``
@@ -14,16 +15,29 @@ model) and ``Async XLA Ops``. An op's name is its whole HLO text without
 the program gave its ``pallas_call`` (``%flash_attn_fwd.3 = ...``; since PR
 28), which is how ``kernel_needs.kernel_events`` finds it. Host threads are
 lines of ``/host:CPU``.
+
+What XLA compiles has no name of the program's own, but the compiled step's
+text has every instruction's ``op_name``: the path of JAX transformations,
+Flax modules and ``jax.named_scope`` names it was traced under
+(``jit(_train_step)/grad_accum/while/body/closed_call/transpose(jvp(Llama))/
+layer_3/mlp/down_proj/dot_general``). ``op_names`` reads that text into
+instruction name -> ``op_name``, and ``reduce`` joins it with the events'
+instruction names into ``by_scope`` (``kernel_needs.scope_share`` reads it).
+An instruction's name (``fusion.1``) is unique in its program only, so the
+join holds to the events inside that program's runs on the ``XLA Modules``
+line, which are named ``<module>(<fingerprint>)`` (``program_runs``).
 """
 
 from __future__ import annotations
 
+import bisect
 import glob
 import os
 import re
 
 DEVICE_PLANE = "/device:TPU:"
 OPS_LINE = "XLA Ops"
+MODULES_LINE = "XLA Modules"
 HOST_PLANE = "/host:CPU"
 
 
@@ -104,16 +118,125 @@ def short_name(name: str, limit: int = 160) -> str:
 
 
 # ---------------------------------------------------------------------------
+# device time by the program's scopes
+# ---------------------------------------------------------------------------
+
+UNSCOPED = "unscoped"
+OUTSIDE = "outside_program"     # beside UNSCOPED: how much of it is this
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .*\{\s*$")
+_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = ")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
+
+
+def op_names(hlo_text: str) -> dict:
+    """instruction name -> ``op_name`` over a compiled program's text
+    (``compiled.as_text()``). An instruction without one of its own that
+    calls a computation, as a fusion does, takes that computation's root's
+    (where the root is a tuple or a bitcast and has none: that of the last
+    instruction before it that has); one that finds none is left out."""
+    named, calls, last_named = {}, {}, {}
+    computation = None
+    for line in hlo_text.splitlines():
+        found = _INSTRUCTION.match(line)
+        if not found:
+            opened = _COMPUTATION.match(line)
+            if opened:
+                computation = opened.group(1)
+            continue
+        name = found.group(1)
+        op_name = _OP_NAME.search(line)
+        if op_name:
+            named[name] = last_named[computation] = op_name.group(1)
+        else:
+            called = _CALLS.search(line)
+            if called:
+                calls[name] = called.group(1)
+    for name, computation in calls.items():
+        if computation in last_named:
+            named[name] = last_named[computation]
+    return named
+
+
+def scopes_of(op_name: str) -> list:
+    """The names one ``op_name`` counts under, outermost first, each once:
+    every component of its path, a component that a transformation wraps
+    (``transpose(jvp(Llama))``) as the transformation alone
+    (``transpose(jvp(``: the backward) and as what it wraps (``Llama``).
+    The ``jit(...)`` the path starts with is the program's name and is
+    left out; the last component is the primitive."""
+    parts = op_name.split("/")
+    while parts and parts[0].startswith(("jit(", "pjit(")):
+        parts.pop(0)
+    out: list = []
+    for part in parts:
+        opened = part.rfind("(") + 1
+        inner = part[opened:].rstrip(")")
+        for name in ((part[:opened], inner) if opened and inner else (part,)):
+            if name and name not in out:
+                out.append(name)
+    return out
+
+
+_MODULE = re.compile(r"^HloModule ([\w.\-]+)", re.M)
+
+
+def module_name(hlo_text: str):
+    """The name a compiled program's text opens with (``jit__train_step``)."""
+    found = _MODULE.search(hlo_text)
+    return found.group(1) if found else None
+
+
+def program_runs(modules: dict, module) -> dict:
+    """plane name -> the sorted (start, end) of the runs of the program
+    named ``module`` among that chip's ``XLA Modules`` events (name, start,
+    end), which are named ``<module>(<fingerprint>)``."""
+    return {plane: sorted((start, end) for name, start, end in events
+                          if module and name.startswith(module + "("))
+            for plane, events in modules.items()}
+
+
+def time_by_scope(events, op_name_of: dict, runs=None) -> dict:
+    """Seconds of one chip's (name, start, end) events under every name of
+    ``scopes_of`` the event's instruction has. An event whose instruction
+    the program's text does not name, or names without an ``op_name``,
+    counts as ``unscoped``; so does one that starts outside ``runs``
+    (that chip's ``program_runs``; None: not held to any), whatever its
+    name: it is another program's instruction, and counts as
+    ``outside_program`` too."""
+    by_scope: dict = {UNSCOPED: 0.0}
+    scopes_by_event: dict = {}
+    starts = [run[0] for run in runs or ()]
+    for event, start, end in events:
+        scopes = scopes_by_event.get(event)
+        if scopes is None:
+            instruction = event.lstrip("%").split(" ", 1)[0]
+            scopes = scopes_by_event[event] = scopes_of(
+                op_name_of.get(instruction, "")) or [UNSCOPED]
+        if runs is not None:
+            at = bisect.bisect_right(starts, start) - 1
+            if at < 0 or start >= runs[at][1]:
+                scopes = [UNSCOPED, OUTSIDE]
+        for scope in scopes:
+            by_scope[scope] = by_scope.get(scope, 0.0) + (end - start)
+    return by_scope
+
+
+# ---------------------------------------------------------------------------
 # the reduction every traced run makes
 # ---------------------------------------------------------------------------
 
 
-def reduce(devices: dict, host_input: list, top: int = 10) -> dict:
+def reduce(devices: dict, host_input: list, top: int = 10,
+           op_name_of: dict | None = None, runs=None) -> dict:
     """``devices``: plane name -> [(op name, start s, end s)];
     ``host_input``: [(start, end)] of the benchmark's own ``input``
-    annotations. Returns busy and window seconds averaged over the chips, the
-    idle gaps and the heaviest ops of the first chip, and every chip's
-    events by name for the kernel readers."""
+    annotations; ``op_name_of``: ``op_names`` of the traced program, where
+    the window has it, and ``runs``: plane name -> that program's
+    ``program_runs``. Returns busy and window seconds averaged over the
+    chips, the idle gaps and the heaviest ops of the first chip, that chip's
+    events by name for the kernel readers and, with ``op_name_of``, its
+    time by scope."""
     if not devices:
         return {}
     per_chip = []
@@ -142,7 +265,7 @@ def reduce(devices: dict, host_input: list, top: int = 10) -> dict:
         entry[0] += seconds
         entry[1] += first["count_by_name"][name]
     ops = sorted(classes.items(), key=lambda kv: -kv[1][0])[:top]
-    return {
+    reduced = {
         "busy_s": sum(c["busy_s"] for c in per_chip) / len(per_chip),
         "window_s": sum(c["span"][1] - c["span"][0]
                         for c in per_chip) / len(per_chip),
@@ -153,6 +276,11 @@ def reduce(devices: dict, host_input: list, top: int = 10) -> dict:
         "by_name": first["by_name"],
         "count_by_name": first["count_by_name"],
     }
+    if op_name_of:
+        reduced["by_scope"] = time_by_scope(
+            devices[first["plane"]], op_name_of,
+            None if runs is None else runs.get(first["plane"], []))
+    return reduced
 
 
 # ---------------------------------------------------------------------------
@@ -169,13 +297,15 @@ def find_xplane(trace_dir: str) -> str:
 
 
 def load(trace_dir: str) -> tuple:
-    """(devices, host_input, outline): device op events by plane, the
-    ``input`` annotations' intervals, and a plain outline of planes and lines
-    for a reader who wants to look at the trace by hand."""
+    """(devices, host_input, outline, modules): device op events by plane,
+    the ``input`` annotations' intervals, a plain outline of planes and lines
+    for a reader who wants to look at the trace by hand, and the programs'
+    runs by plane (the ``XLA Modules`` line, as ``devices`` has the ops)."""
     from jax.profiler import ProfileData
 
     data = ProfileData.from_file(find_xplane(trace_dir))
     devices: dict = {}
+    modules: dict = {}
     host_input: list = []
     outline: list = []
     for plane in data.planes:
@@ -183,12 +313,13 @@ def load(trace_dir: str) -> tuple:
             events = list(line.events)
             outline.append({"plane": plane.name, "line": line.name,
                             "events": len(events)})
-            if plane.name.startswith(DEVICE_PLANE) and line.name == OPS_LINE:
-                devices.setdefault(plane.name, []).extend(
+            kept = {OPS_LINE: devices, MODULES_LINE: modules}.get(line.name)
+            if plane.name.startswith(DEVICE_PLANE) and kept is not None:
+                kept.setdefault(plane.name, []).extend(
                     (e.name, e.start_ns * 1e-9,
                      (e.start_ns + e.duration_ns) * 1e-9) for e in events)
             elif plane.name.startswith(HOST_PLANE):
                 host_input.extend(
                     (e.start_ns * 1e-9, (e.start_ns + e.duration_ns) * 1e-9)
                     for e in events if e.name == "input")
-    return devices, host_input, outline
+    return devices, host_input, outline, modules

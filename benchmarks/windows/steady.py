@@ -160,14 +160,23 @@ def run(ctx) -> int:
     state, start = loop.restore_or_init(rng, sampler)
     compiled = getattr(loop.trainer, "_compiled_step", None)
     memory = compiled.memory_analysis() if compiled is not None else None
+    seconds = time.monotonic() - t0
+    # the step program's text: its kernels and, for a trace's time by scope,
+    # its name and each instruction's op_name (host work, before the window)
+    text = compiled.as_text() if compiled is not None else ""
+    t0 = time.monotonic()
+    module = trace_reduce.module_name(text)
+    op_name_of = trace_reduce.op_names(text) if ctx.trace else {}
     report.emit(
-        record="init", seconds=time.monotonic() - t0, start_step=start,
+        record="init", seconds=seconds, start_step=start,
         precompile=dict(loop.trainer.precompile_timings),
         argument_bytes=getattr(memory, "argument_size_in_bytes", None),
         temp_bytes=getattr(memory, "temp_size_in_bytes", None),
         code_bytes=getattr(memory, "generated_code_size_in_bytes", None),
-        kernels_in_program=(compiled.as_text().count("tpu_custom_call")
-                            if compiled is not None else None))
+        kernels_in_program=(text.count("tpu_custom_call")
+                            if compiled is not None else None),
+        op_names_s=time.monotonic() - t0, op_names_read=len(op_name_of))
+    del text
 
     # warm-up: the window's own call and feed, one step each
     change_fn = ctx.model.change_norms_fn(loop.trainer)
@@ -252,8 +261,11 @@ def run(ctx) -> int:
 
     if ctx.trace and tracing["to"] is not None:
         t0 = time.monotonic()
-        device_events, host_input, outline = trace_reduce.load(tracing["dir"])
-        reduced = trace_reduce.reduce(device_events, host_input)
+        device_events, host_input, outline, modules = trace_reduce.load(
+            tracing["dir"])
+        reduced = trace_reduce.reduce(
+            device_events, host_input, op_name_of=op_name_of,
+            runs=trace_reduce.program_runs(modules, module))
         report.emit(record="traced", reduce_seconds=time.monotonic() - t0,
                     traced_wall_s=tracing["to"] - tracing["from"],
                     outline=outline, **reduced)

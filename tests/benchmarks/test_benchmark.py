@@ -13,8 +13,10 @@ import json
 import os
 import re
 
+import bench_cells
 import numpy as np
 import pytest
+from bench_cells import BENCH, TINY_LIMITS
 
 from benchmarks import (
     check,
@@ -28,16 +30,9 @@ from benchmarks import (
 from benchmarks import run as bench_run
 from benchmarks import worker
 
-BENCH = harness.benchmark()
 LLAMA = harness.model_reference({"model": "llama"})
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
-# the rehearsal's widths read other gaps than the chip's sizes; these are the
-# tiny model's own limits (program on the CPU: 4e-5, 8e-4, 1.5e-3, 3e-4; a
-# planted fault reads 0.2 or more, or counts rows), not the cells'
-TINY_LIMITS = {"rows_wrong": 0, "loss_gap": 1e-3, "grad_gap": 1e-2,
-               "change_gap": 1e-2, "grad_gap_whole": 1e-2,
-               "compiles_in_window": 0, "saves_uncommitted": 0}
 
 
 def test_benchmark_json_names_units_and_keys():
@@ -62,21 +57,27 @@ def test_benchmark_json_names_units_and_keys():
                for w in BENCH["workloads"])
 
 
-def test_every_file_is_found_by_name():
-    for entry in BENCH["workloads"]:
-        _, cfg, traffic = harness.cell(BENCH, entry["name"])
-        assert harness.model_class(cfg).build
-        assert harness.model_reference(cfg).block
-        assert hasattr(harness.load_module("windows", traffic["window"]),
-                       "run")
-        assert set(check.limits_for(entry["name"])) == {
-            "rows_wrong", "loss_gap", "grad_gap", "grad_gap_whole",
-            "change_gap", "compiles_in_window", "saves_uncommitted"}
-    for config in BENCH["configs"]:
-        body = harness.load_json(harness.ROOT, config["file"])
-        assert body["source"] == config["source"]
-        assert body["reduced"] == config["reduced"]
-        assert all(key in body for key in config["reduced"])
+@pytest.mark.parametrize("cell", [w["name"] for w in BENCH["workloads"]])
+def test_every_cells_files_are_found_by_name(cell, lookup):
+    _, cfg, traffic = harness.cell(BENCH, cell)
+    assert harness.model_class(cfg).build
+    assert harness.model_reference(cfg).block
+    assert hasattr(harness.load_module("windows", traffic["window"]), "run")
+    assert set(bench_cells.limits_for(cell)) == {
+        "rows_wrong", "loss_gap", "grad_gap", "grad_gap_whole",
+        "change_gap", "compiles_in_window", "saves_uncommitted"}
+
+
+@pytest.mark.parametrize("config", BENCH["configs"], ids=lambda c: c["name"])
+def test_every_configurations_file_says_what_its_entry_says(config):
+    body = harness.load_json(harness.ROOT, config["file"])
+    assert body["source"] == config["source"]
+    assert body["reduced"] == config["reduced"]
+    assert all(key in body for key in config["reduced"])
+    assert any(w["config"] == config["name"] for w in BENCH["workloads"])
+
+
+def test_every_metric_has_its_reader():
     for metric in BENCH["end_to_end"] + BENCH["per_layer"]:
         assert callable(harness.load_module("metrics", metric["name"]).read)
 

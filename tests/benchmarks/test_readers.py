@@ -4,11 +4,15 @@ dicts; nothing here starts JAX."""
 
 from __future__ import annotations
 
+import glob
+import os
+
+import bench_cells
 import pytest
+from bench_cells import BENCH
 
 from benchmarks import flops, harness, kernel_needs, span_reduce
 
-BENCH = harness.benchmark()
 KIND = "TPU v5 lite"
 PEAK = flops.peaks(KIND)
 NEW_SPAN_METRICS = ("step.device_step_ms", "loop.host_overhead_ms",
@@ -107,34 +111,83 @@ def test_span_readers_give_nothing_without_a_train_window_span(name):
     assert read(run) is None
 
 
-@pytest.mark.parametrize("config", [c["name"] for c in BENCH["configs"]])
-def test_kernel_needs_forward_plus_backward_is_the_accepted_count(config):
-    """The count PRs 26-30 were measured against, written out: one causal
-    layer's forward plus backward is 6 matmuls of 2 b heads s^2 d, halved by
-    the mask; every operand read and every result written once in bf16
-    (forward q, k, v -> o; backward q, k, v, o, do -> dq, dk, dv) plus the
-    fp32 row statistics, once each way."""
-    cfg = harness.load_json(harness.ROOT, next(
-        c["file"] for c in BENCH["configs"] if c["name"] == config))
-    layer = harness.model_class(cfg).attention_layers(cfg)[0]
-    heads, kv_heads = cfg["num_attention_heads"], cfg["num_key_value_heads"]
-    d = cfg["hidden_size"] // heads
-    assert layer == {"heads": heads, "kv_heads": kv_heads, "head_dim": d,
-                     "window": None}
+@pytest.mark.parametrize("layer", [
+    {"heads": 16, "kv_heads": 8, "head_dim": 128, "window": None},
+    {"heads": 32, "kv_heads": 8, "head_dim": 128, "window": None},
+    {"heads": 32, "kv_heads": 4, "head_dim": 128, "window": None},
+    {"heads": 64, "kv_heads": 8, "head_dim": 128, "window": 512},
+    {"heads": 8, "kv_heads": 2, "head_dim": 32, "window": None},
+], ids=["internlm2_1p8b", "mistral_7b", "wide_head_on_hidden_2048",
+        "windowed", "twokind_local"])
+def test_kernel_needs_forward_plus_backward_is_the_accepted_count(layer):
+    """The count PRs 26-30 were measured against, written out on layer
+    entries written out (the two accepted configurations', a head of 128 on
+    32 heads whatever the hidden size, a windowed one, the tests' own): one
+    layer's forward plus backward is 6 matmuls of 2 b heads d over the
+    scored pairs, s^2 / 2 under the causal mask; every operand read and
+    every result written once in bf16 (forward q, k, v -> o; backward q, k,
+    v, o, do -> dq, dk, dv) plus the fp32 row statistics, once each way."""
+    heads, kv_heads, d = layer["heads"], layer["kv_heads"], layer["head_dim"]
     for batch, seq in ((2, 2048), (1, 4096), (8, 512)):
         fwd = kernel_needs.flash_attention_fwd(layer, batch, seq)
         bwd = kernel_needs.flash_attention_bwd(layer, batch, seq)
         q_bytes = batch * heads * seq * d * 2
         kv_bytes = batch * kv_heads * seq * d * 2
         stats = batch * heads * seq * 4
+        w = layer["window"]
+        pairs = (seq * seq / 2.0 if w is None or w >= seq
+                 else w * seq - w * w / 2.0)
         assert fwd["flops"] + bwd["flops"] == (
-            6.0 * 2.0 * batch * heads * seq * seq * d / 2.0)
+            6.0 * 2.0 * batch * heads * pairs * d)
         assert fwd["bytes"] + bwd["bytes"] == float(
             (q_bytes + 2 * kv_bytes + q_bytes + stats)
             + (3 * q_bytes + 2 * kv_bytes + stats + q_bytes + 2 * kv_bytes))
         assert bwd["flops"] == 2 * fwd["flops"]
     assert flops.roofline_seconds(
         kernel_needs.flash_attention_fwd(layer, 2, 2048), KIND)[1] == "compute"
+
+
+@pytest.mark.parametrize("config", [c["name"] for c in BENCH["configs"]])
+def test_attention_layers_is_the_classs_to_say(config, lookup):
+    """Of every configuration, the tests' own among them: one entry a layer
+    with the four keys the needs are counted from. That a head is
+    ``hidden // heads`` wide and every layer causal to the start is Llama's
+    shape, and held of the configurations whose class is ``llama`` only."""
+    entry = next(c for c in BENCH["configs"] if c["name"] == config)
+    cfg = harness.load_json(harness.ROOT, entry["file"])
+    layers = harness.model_class(cfg).attention_layers(cfg)
+    assert len(layers) == cfg["num_hidden_layers"]
+    for layer in layers:
+        assert set(layer) == {"heads", "kv_heads", "head_dim", "window"}
+        assert layer["heads"] % layer["kv_heads"] == 0
+        assert kernel_needs.flash_attention_fwd(layer, 2, 2048)["flops"] > 0
+    if cfg["model"] == "llama":
+        heads = cfg["num_attention_heads"]
+        assert layers[0] == {"heads": heads,
+                             "kv_heads": cfg["num_key_value_heads"],
+                             "head_dim": cfg["hidden_size"] // heads,
+                             "window": None}
+    else:       # the tests' own: a head width that hidden does not give
+        tied = cfg["hidden_size"] // layers[0]["heads"]
+        assert layers[0]["head_dim"] != tied
+
+
+def test_every_for_every_test_runs_over_the_tests_own_class_too():
+    """The guard: ``bench_cells.BENCH``, which has the tests' own class and
+    cell entered, is the only benchmark a test file here can get: none asks
+    the harness for the accepted one or takes it from ``bench_cells``. So
+    whatever a test runs over the configurations or the cells, or holds of
+    them all, it runs over and holds of a class that is not Llama's shape."""
+    assert bench_cells.OWN_CONFIG in BENCH["configs"]
+    assert bench_cells.OWN_CELL in BENCH["workloads"]
+    assert len(BENCH["configs"]) >= 3 and len(BENCH["workloads"]) >= 3
+    files = glob.glob(os.path.join(bench_cells.HERE, "test_*.py"))
+    assert len(files) >= 3
+    for path in files:
+        with open(path) as f:
+            text = f.read()
+        assert "harness.benchmark" + "()" not in text, path
+        assert "ACC" + "EPTED" not in text, path
 
 
 # -- device events by name ---------------------------------------------------
@@ -235,6 +288,18 @@ def test_every_new_metric_is_in_benchmark_json_with_its_reader():
     for name in NEW_SPAN_METRICS + NEW_TRACE_METRICS:
         assert name in listed, name
         assert listed[name]["moves"] == "tokens_per_s"
-        assert "workloads" not in listed[name]
+        # a kernel's metric lists the cells whose program launches it: every
+        # cell of class ``llama`` does; a cell on other kernels stays out
+        # (the tests' own is entered in ``workloads`` and in neither list)
+        if name.startswith("kernels.flash_attn"):
+            named = listed[name]["workloads"]
+            cells = [w["name"] for w in BENCH["workloads"]]
+            assert set(named) <= set(cells)
+            assert {c for c in cells if harness.cell(BENCH, c)[1]["model"]
+                    == "llama"} <= set(named)
+            assert bench_cells.CELL in cells
+            assert bench_cells.CELL not in named
+        else:
+            assert "workloads" not in listed[name]
         assert listed[name]["source"] == (
             "host_clock" if name in NEW_SPAN_METRICS else "device_trace")
