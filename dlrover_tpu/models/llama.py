@@ -16,6 +16,7 @@ XLA path (`attn_impl="reference"`), selected per config.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any
 
 import flax.linen as nn
@@ -327,12 +328,17 @@ class DecoderBlock(nn.Module):
 
 
 class Llama(nn.Module):
-    """Decoder-only LM. `__call__(tokens) -> logits`."""
+    """Decoder-only LM. `__call__(tokens) -> logits`; `hidden_and_head`
+    gives what the logits are made of, for a loss that never forms them
+    whole (`head_cross_entropy`)."""
 
     config: LlamaConfig
 
     @nn.compact
-    def __call__(self, tokens: jax.Array) -> jax.Array:
+    def hidden_and_head(self, tokens: jax.Array):
+        """(final hidden states (b, s, hidden), head matrix (hidden,
+        vocab)), both in the compute dtype: `__call__` is their product.
+        Tied embeddings give the transposed embedding."""
         cfg = self.config
         embed = self.param(
             "embed",
@@ -351,23 +357,25 @@ class Llama(nn.Module):
             )
         for layer in range(cfg.num_layers):
             x = block_cls(cfg, name=f"layer_{layer}")(x, positions)
-        # one scope with the loss (trainer/train_step.py opens it again
-        # around `loss_fn`): final norm + head matmul + loss are one
-        # item in a trace's account of the step
+        # one scope with head and loss (`__call__` and the losses below
+        # open it again): final norm + head matmul + loss are one item
+        # in a trace's account of the step
         with jax.named_scope(TraceScope.HEAD_LOSS):
             x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.norm_impl,
                         name="final_norm")(x)
             if cfg.tie_embeddings:
-                logits = jnp.dot(x, embed.astype(cfg.dtype).T)
-            else:
-                head = self.param(
-                    "lm_head",
-                    _logical(nn.initializers.normal(0.02), "embed",
-                             "vocab"),
-                    (cfg.hidden_size, cfg.vocab_size), cfg.param_dtype,
-                )
-                logits = jnp.dot(x, head.astype(cfg.dtype))
-            return logits.astype(jnp.float32)
+                return x, embed.astype(cfg.dtype).T
+            head = self.param(
+                "lm_head",
+                _logical(nn.initializers.normal(0.02), "embed", "vocab"),
+                (cfg.hidden_size, cfg.vocab_size), cfg.param_dtype,
+            )
+            return x, head.astype(cfg.dtype)
+
+    def __call__(self, tokens: jax.Array) -> jax.Array:
+        x, head = self.hidden_and_head(tokens)
+        with jax.named_scope(TraceScope.HEAD_LOSS):
+            return jnp.dot(x, head).astype(jnp.float32)
 
 
 def cross_entropy_loss(logits: jax.Array, targets: jax.Array) -> jax.Array:
@@ -375,3 +383,117 @@ def cross_entropy_loss(logits: jax.Array, targets: jax.Array) -> jax.Array:
     logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
     nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
     return jnp.mean(nll)
+
+
+# A slice's logits, in the dtype they are held in, may take this much.
+# The chip chose it (PERF.md section 6, PR 34): one slice is fastest, and
+# each further one costs a read and a write of the float32 accumulator of
+# the head's gradient (the second 3.7 ms of a 28 ms head and loss at
+# 2 x 2048 tokens x 92,544, 2.7 of 19 ms at x 32,000). So logits of that
+# size (758 and 262 MB) go in one, and a sequence twice as long in two.
+HEAD_LOSS_SLICE_BYTES = 1 << 30
+
+
+def head_loss_slices(rows: int, seq_len: int, vocab: int,
+                     itemsize: int) -> int:
+    """How many slices of the sequence `head_cross_entropy` takes for
+    `rows` sequences a device: the fewest that divide `seq_len` and keep a
+    slice's logits within `HEAD_LOSS_SLICE_BYTES`."""
+    for slices in range(1, seq_len):
+        if seq_len % slices == 0 and (
+                rows * (seq_len // slices) * vocab * itemsize
+                <= HEAD_LOSS_SLICE_BYTES):
+            return slices
+    return seq_len
+
+
+def _slice_loss_and_grads(x, head, targets, scale):
+    """One slice: (summed nll, d loss / d x, d loss / d head in float32),
+    the gradients already times `scale` (1 / tokens of the whole batch)."""
+    logits = jnp.einsum("bsh,hv->bsv", x, head).astype(jnp.float32)
+    top = jnp.max(logits, axis=-1, keepdims=True)
+    unnormalised = jnp.exp(logits - top)
+    total = jnp.sum(unnormalised, axis=-1, keepdims=True)
+    # the target's column by comparison, not by gather: it fuses into the
+    # passes that are made anyway (a gather kept 1.4 GB more on the chip)
+    hit = (jax.lax.broadcasted_iota(jnp.int32, logits.shape, 2)
+           == targets[..., None])
+    picked = jnp.sum(jnp.where(hit, logits, 0.0), axis=-1)
+    nll = jnp.sum(top[..., 0] + jnp.log(total[..., 0]) - picked)
+    dlogits = ((unnormalised / total - hit.astype(jnp.float32))
+               * scale).astype(x.dtype)
+    dx = jnp.einsum("bsv,hv->bsh", dlogits, head)
+    dhead = jnp.einsum("bsh,bsv->hv", x, dlogits,
+                       preferred_element_type=jnp.float32)
+    return nll, dx, dhead
+
+
+def _head_loss_and_grads(x, head, targets, slices):
+    batch, seq_len, hidden = x.shape
+    scale = 1.0 / (batch * seq_len)
+    with jax.named_scope(TraceScope.HEAD_LOSS):
+        if slices == 1:
+            nll, dx, dhead = _slice_loss_and_grads(x, head, targets, scale)
+        else:
+            # slices of the SEQUENCE, so a batch sharded over data / fsdp
+            # stays sharded; one traced body whatever their number
+            def cut(a):
+                return jnp.swapaxes(a.reshape(
+                    batch, slices, seq_len // slices, *a.shape[2:]), 0, 1)
+
+            def body(carry, slice_):
+                nll, dx, dhead = _slice_loss_and_grads(
+                    slice_[0], head, slice_[1], scale)
+                return (carry[0] + nll, carry[1] + dhead), dx
+
+            (nll, dhead), dx = jax.lax.scan(
+                body, (jnp.zeros((), jnp.float32),
+                       jnp.zeros(head.shape, jnp.float32)),
+                (cut(x), cut(targets)))
+            dx = jnp.swapaxes(dx, 0, 1).reshape(batch, seq_len, hidden)
+        # rounded once, where the logits path's weight-gradient matmul
+        # rounds: after every slice is summed in float32
+        return nll * scale, dx, dhead.astype(head.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def head_cross_entropy(x: jax.Array, head: jax.Array, targets: jax.Array,
+                       slices: int = 1) -> jax.Array:
+    """`cross_entropy_loss(jnp.dot(x, head), targets)` as ONE function
+    that forms its gradients on the way forward: x (b, s, hidden) final
+    hidden states, head (hidden, vocab), targets (b, s).
+
+    The logits are computed a slice of the sequence at a time (`slices`
+    divides s), in the operands' dtype with float32 accumulation as
+    `jnp.dot` gives them; log-sum-exp and loss in float32. The same pass
+    forms softmax - onehot and from it both gradients, so head-sized
+    matmuls are three a step, not four, and what is kept for the backward
+    is the two gradients: no (b, s, vocab) array outlives its slice. The
+    backward rule only scales them by the incoming cotangent."""
+    return _head_loss_and_grads(x, head, targets, slices)[0]
+
+
+def _head_cross_entropy_fwd(x, head, targets, slices):
+    loss, dx, dhead = _head_loss_and_grads(x, head, targets, slices)
+    # Both gradients before anything that reads either. Left alone, XLA
+    # puts off the head's (nothing needs it before the optimizer), lets
+    # go of the logits meanwhile and runs their matmul a second time for
+    # it (`fusion.N.remat`; PERF.md section 6, PR 34): the fourth
+    # head-sized matmul this function exists to remove.
+    dx, dhead = jax.lax.optimization_barrier((dx, dhead))
+    return loss, (dx, dhead)
+
+
+def _head_cross_entropy_bwd(slices, grads, cotangent):
+    # traced apart from the scope around the call: open it again
+    with jax.named_scope(TraceScope.HEAD_LOSS):
+        dx, dhead = grads
+        return (dx * cotangent.astype(dx.dtype),
+                dhead * cotangent.astype(dhead.dtype), None)
+
+
+head_cross_entropy.defvjp(_head_cross_entropy_fwd, _head_cross_entropy_bwd)
+# how a trainer recognises softmax cross-entropy and reaches its fused
+# form (trainer/train_step.py:build_trainer); a wrapped or other loss
+# carries none and gets whole logits
+cross_entropy_loss.from_hidden = head_cross_entropy

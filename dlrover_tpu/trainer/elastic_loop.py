@@ -383,13 +383,16 @@ class ElasticTrainLoop:
                 "recompile",
                 {"phase": "relower",
                  "devices": self.dp,
-                 "mesh": dict(self.mesh.shape)}):
+                 "mesh": dict(self.mesh.shape)}) as relower_span:
             trainer = build_trainer(
                 model, tx, self.mesh, sample, loss_fn,
                 accum_steps=self.accum, micro_batch=self.micro_global,
                 rules=config.rules,
                 split_grad_apply=slice_mode,
             )
+            relower_span.set_attr("head_loss_path", trainer.head_loss_path)
+            relower_span.set_attr("head_loss_slices",
+                                  trainer.head_loss_slices)
             if self._plan_mesh_spec is not None:
                 import jax
 

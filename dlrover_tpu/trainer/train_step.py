@@ -20,7 +20,8 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from dlrover_tpu.common.constants import MeshAxis, TraceScope
-from dlrover_tpu.parallel.mesh import use_mesh
+from dlrover_tpu.common.log import default_logger
+from dlrover_tpu.parallel.mesh import data_axes, dp_size, use_mesh
 from dlrover_tpu.parallel.moe import moe_aux_loss
 from dlrover_tpu.parallel.sharding import (
     DEFAULT_RULES,
@@ -69,6 +70,12 @@ class ShardedTrainer:
     grad_fn: Any = dataclasses.field(default=None, repr=False)
     apply_fn: Any = dataclasses.field(default=None, repr=False)
     _compiled_step: Any = dataclasses.field(default=None, repr=False)
+    # static per program: "fused" where the model offers its final hidden
+    # states and head apart from `__call__` and the loss carries its fused
+    # form (models/llama.py:head_cross_entropy), which then takes the
+    # sequence in `head_loss_slices` slices; else "logits", whole (1)
+    head_loss_path: str = "logits"
+    head_loss_slices: int = 1
     precompile_timings: dict = dataclasses.field(default_factory=dict)
     last_used_aot: bool = False
 
@@ -97,7 +104,9 @@ class ShardedTrainer:
 
         abstract = self.abstract_state(
             jax.random.PRNGKey(0) if rng is None else rng)
-        with obs.span("recompile", {"phase": "aot"}) as aot_span:
+        with obs.span("recompile", {
+                "phase": "aot", "head_loss_path": self.head_loss_path,
+                "head_loss_slices": self.head_loss_slices}) as aot_span:
             t0 = _time.monotonic()
             lowered = self.step_fn.lower(
                 abstract, self.batch_abstract, self.batch_abstract)
@@ -127,8 +136,6 @@ class ShardedTrainer:
                 # jitted path recompiles correctly. Runtime errors (OOM,
                 # XlaRuntimeError) propagate — state may already be
                 # donated, so re-running would only mask the real error.
-                from dlrover_tpu.common.log import default_logger
-
                 default_logger.warning(
                     "AOT-compiled step rejected its arguments (%s); "
                     "falling back to the jitted path", e)
@@ -213,9 +220,17 @@ def build_trainer(
     (tolerating an absent slice), apply_fn applies the fleet mean.
     """
     rules = list(rules if rules is not None else DEFAULT_RULES)
+    # Head and loss as one function (models/llama.py:head_cross_entropy)
+    # where the inputs say they can be: the model by offering
+    # `hidden_and_head`, the loss by carrying `from_hidden`. A mesh that
+    # shards the sequence keeps whole logits: the slices cut that axis.
+    fused_loss = getattr(loss_fn, "from_hidden", None)
+    fused = (fused_loss is not None
+             and callable(getattr(model, "hidden_and_head", None))
+             and mesh.shape.get(MeshAxis.SEQUENCE, 1) == 1)
+    head_loss_path = "fused" if fused else "logits"
 
-    def _init_boxed(rng):
-        variables = model.init(rng, sample_batch)
+    def _boxed_state(variables):
         params = variables["params"]
         # optax maps over the boxed tree, so optimizer moments inherit the
         # logical axis annotations (→ FSDP shards them like the params)
@@ -223,14 +238,37 @@ def build_trainer(
         return TrainState(step=jnp.zeros((), jnp.int32), params=params,
                           opt_state=opt_state)
 
+    def _init_boxed(rng):
+        return _boxed_state(model.init(rng, sample_batch))
+
+    def _abstract_init(rng):
+        """The state and, on the fused path, what head and loss will see
+        (hidden states, head), from ONE abstract pass over the model."""
+        if not fused:
+            return _init_boxed(rng), None
+        hidden_and_head, variables = model.init_with_output(
+            rng, sample_batch, method="hidden_and_head")
+        return _boxed_state(variables), hidden_and_head
+
     # The mesh context is entered INSIDE every traced function so model
     # code can reach the concrete mesh at trace time (current_mesh() —
     # ring/Ulysses attention build an inner shard_map from it), including
     # re-traces from eval_shape in the checkpoint-restore path.
     with use_mesh(mesh):
-        abstract_boxed = jax.eval_shape(
-            _init_boxed, jax.random.key(0)
+        abstract_boxed, hidden_and_head = jax.eval_shape(
+            _abstract_init, jax.random.key(0)
         )
+    head_slices = 1
+    if fused:
+        from dlrover_tpu.models.llama import head_loss_slices
+
+        hidden, head = hidden_and_head
+        # a device holds its share of the micro-batch's rows
+        head_slices = head_loss_slices(
+            max(1, micro_batch // dp_size(mesh)), hidden.shape[1],
+            head.shape[-1], hidden.dtype.itemsize)
+    default_logger.info("head and loss: path=%s slices=%d",
+                        head_loss_path, head_slices)
     state_shardings = mesh_shardings(abstract_boxed, mesh, rules)
     # factored optimizers (adafactor) produce state leaves whose rank
     # differs from the param that named their axes — replicate those
@@ -249,8 +287,6 @@ def build_trainer(
     # Batch (accum, micro, seq): micro over the joint dp axes (dcn +
     # data + fsdp — cross-slice replicas outermost), seq over the
     # sequence axis (a no-op at sequence=1; shards inputs for SP runs).
-    from dlrover_tpu.parallel.mesh import data_axes
-
     batch_shard = NamedSharding(
         mesh, P(None, data_axes(mesh), MeshAxis.SEQUENCE)
     )
@@ -292,12 +328,18 @@ def build_trainer(
                 # (MoE router balancing, parallel/moe.py:172); for models
                 # that never sow, the collection is empty and the sum is
                 # 0 — one generic path covers both
-                logits, mutables = model.apply(
-                    {"params": p}, tok, mutable=["losses"], rngs=rngs)
-                # the model's final norm and head open the same scope
-                # (models/llama.py): head + loss read as one in a trace
-                with jax.named_scope(TraceScope.HEAD_LOSS):
-                    loss = loss_fn(logits, tgt)
+                if fused:
+                    (hidden, head), mutables = model.apply(
+                        {"params": p}, tok, mutable=["losses"], rngs=rngs,
+                        method="hidden_and_head")
+                    loss = fused_loss(hidden, head, tgt, head_slices)
+                else:
+                    logits, mutables = model.apply(
+                        {"params": p}, tok, mutable=["losses"], rngs=rngs)
+                    # the model's final norm and head open the same scope
+                    # (models/llama.py): head + loss read as one in a trace
+                    with jax.named_scope(TraceScope.HEAD_LOSS):
+                        loss = loss_fn(logits, tgt)
                 return loss + moe_aux_loss(mutables)
 
             loss, grads = jax.value_and_grad(compute_loss)(params)
@@ -458,6 +500,8 @@ def build_trainer(
         micro_batch=micro_batch,
         grad_fn=grad_fn,
         apply_fn=apply_fn,
+        head_loss_path=head_loss_path,
+        head_loss_slices=head_slices,
         batch_abstract=jax.ShapeDtypeStruct(
             (accum_steps, micro_batch, *sample_batch.shape[1:]),
             jnp.int32, sharding=batch_shard),
