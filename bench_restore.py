@@ -1,7 +1,7 @@
 """Elastic-restore benchmark: SIGKILL -> first step after restore, in seconds.
 
-The north-star metric (BASELINE.md): elastic-restore wall-clock < 30 s after
-a single-host kill. This bench runs the REAL stack — a standalone JobMaster,
+The north-star metric: elastic-restore wall-clock < 30 s after a
+single-host kill. This bench runs the REAL stack — a standalone JobMaster,
 an ElasticAgent, and a training worker subprocess using ElasticTrainLoop with
 flash (async Orbax) checkpointing — then SIGKILLs the worker mid-training and
 clocks kill -> failure detection -> re-rendezvous -> respawn -> restore ->
@@ -11,8 +11,8 @@ Prints ONE JSON line:
     {"metric": "elastic_restore_seconds", "value": S, "unit": "...",
      "vs_baseline": 30.0 / S}
 
-Run directly (`python bench_restore.py`) or via bench.py, which folds the
-number into the headline metric. Worker mode (`--worker`) is internal.
+Run directly (`python bench_restore.py`). Worker mode (`--worker`) is
+internal.
 
 Reference behavior being measured: the agent restart path
 (dlrover/python/elastic_agent/torch/training.py:429-521) combined with the
@@ -39,8 +39,8 @@ SAVE_INTERVAL = 2
 GLOBAL_BATCH = 8
 SEQ_LEN = 128
 
-# --at-scale: the REAL bench model (1.47B wide-MLP Llama, bf16 params,
-# factored-rms state — bench.py's headline config) so the clocked restore
+# --at-scale: the 1.47B wide-MLP Llama (bf16 params, factored-rms
+# state — chip_smoke.py's width) so the clocked restore
 # moves a multi-GB checkpoint through Orbax + device_put + re-jit, the
 # actual cost the <30 s north star is about.
 SCALE_GLOBAL_BATCH = 2
@@ -430,8 +430,8 @@ def run_bench(timeout_s: float = 480.0, at_scale: bool = False,
         result["ckpt_dir"] = ckpt0
         # the master's goodput ledger saw the whole episode through the
         # worker's step reports + telemetry spans: its productive
-        # fraction + bucket split ride into the bench JSON so BENCH_r06+
-        # tracks them beside the headline seconds
+        # fraction + bucket split ride into the bench JSON beside the
+        # headline seconds
         snap = master.goodput_ledger.snapshot()
         result["goodput_fraction"] = snap.get("goodput_fraction", 0.0)
         result["goodput_buckets"] = {

@@ -26,7 +26,7 @@ from dlrover_tpu import obs
 from dlrover_tpu.agent.master_client import MasterClient
 from dlrover_tpu.common import messages as msg
 from dlrover_tpu.common.config import Context
-from dlrover_tpu.common.constants import NodeEnv
+from dlrover_tpu.common.constants import DefaultValues, NodeEnv
 from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.obs.device import _RISE_THRESHOLD_BYTES
 
@@ -85,13 +85,12 @@ class ResourceMonitor:
     """Report host cpu/mem + TPU chip stats to the master periodically."""
 
     def __init__(self, client: MasterClient, node_type: str = "worker",
-                 interval_s: Optional[float] = None,
+                 interval_s: float = (
+                     DefaultValues.REPORT_RESOURCE_INTERVAL_S),
                  chip_stats_file: str = ""):
         self._client = client
         self._node_type = node_type
-        self._interval_s = (interval_s if interval_s is not None
-                            else Context.singleton()
-                            .report_resource_interval_s)
+        self._interval_s = interval_s
         # explicit path wins; env is the worker-process export contract
         self._chip_stats_file = chip_stats_file
         self._stopped = threading.Event()

@@ -9,10 +9,10 @@ that, on a fixed cadence, decides one of three things —
   productive time the offer would contribute (its remaining lifetime ×
   the fleet's measured windowed goodput fraction) must beat the
   join+re-plan cost — estimated from the ledger's own recent
-  elasticity incarnations — by ``autoscale_claim_margin``;
+  elasticity incarnations — by ``AUTOSCALE_CLAIM_MARGIN`` (1.2);
 - **shed** the slowest slice: the steptrace summary names one rank as
   dominating the fleet's critical path AND the cross-slice (DCN) wait
-  fraction exceeds ``autoscale_shed_wait_fraction`` — the fleet is
+  fraction exceeds ``AUTOSCALE_SHED_WAIT_FRACTION`` (0.3) — the fleet is
   paying more waiting for that slice than it would pay re-planning
   without it;
 - **hold**: anything else, and every candidate blocked by a guardrail
@@ -32,7 +32,7 @@ elasticity kind.
 The **rollback watchdog** guards every actuation: the windowed goodput
 fraction at actuation time is the baseline; ``autoscale_rollback_window_s``
 later the window is re-read, and a drop beyond
-``autoscale_rollback_drop_fraction`` reverts the actuation (a bad claim
+``AUTOSCALE_ROLLBACK_DROP_FRACTION`` (0.2) reverts the actuation (a bad claim
 sheds the slice it claimed) and quarantines that decision CLASS with a
 backoff that doubles per consecutive rollback (capped 8×). A market
 revocation of a slice under watch cancels the watch without penalty —
@@ -54,6 +54,7 @@ from typing import Any, Callable, Dict, List, Optional
 
 from dlrover_tpu import obs
 from dlrover_tpu.common.config import Context
+from dlrover_tpu.common.constants import DefaultValues
 from dlrover_tpu.common.log import default_logger as logger
 
 _DECISION_RING = 128       # decisions retained in memory
@@ -304,7 +305,7 @@ class FleetController:
         return sum(costs) / len(costs)
 
     # -- candidates --------------------------------------------------------
-    def _claim_candidate(self, ctx: Context, now: float,
+    def _claim_candidate(self, now: float,
                          window: Dict[str, Any]
                          ) -> Optional[Dict[str, Any]]:
         if self._provider is None:
@@ -319,6 +320,7 @@ class FleetController:
             return None
         offer = offers[0]
         cost_s = self._actuation_cost_s()
+        margin = DefaultValues.AUTOSCALE_CLAIM_MARGIN
         # predicted productive slice-seconds the offer contributes if
         # the new slice reaches the fleet's measured goodput, amortized
         # over what remains of the offer's lifetime
@@ -328,7 +330,7 @@ class FleetController:
             "goodput_fraction": round(goodput, 4),
             "predicted_gain_s": round(gain_s, 3),
             "actuation_cost_s": round(cost_s, 3),
-            "claim_margin": ctx.autoscale_claim_margin,
+            "claim_margin": margin,
         }
         if self._plan_calibration is not None:
             try:
@@ -337,23 +339,23 @@ class FleetController:
                     evidence["plan_calibration"] = current
             except Exception:  # noqa: BLE001 — advisory evidence
                 pass
-        if gain_s <= ctx.autoscale_claim_margin * cost_s:
+        if gain_s <= margin * cost_s:
             return None
         return {"kind": "claim", "evidence": evidence,
                 "offer_id": offer.offer_id,
                 "reason": (f"offer #{offer.offer_id}: predicted gain "
-                           f"{gain_s:.0f}s > {ctx.autoscale_claim_margin:g}"
-                           f"× join+re-plan cost {cost_s:.0f}s")}
+                           f"{gain_s:.0f}s > {margin:g}× join+re-plan "
+                           f"cost {cost_s:.0f}s")}
 
-    def _shed_candidate(self, ctx: Context,
-                        window: Dict[str, Any]
+    def _shed_candidate(self, window: Dict[str, Any]
                         ) -> Optional[Dict[str, Any]]:
         trace = self._steptrace_summary()
         if not trace or self._rendezvous is None:
             return None
         gating_rank = int(trace.get("dominant_gating_rank", -1))
         dcn_wait = float(trace.get("cross_slice_wait_fraction", -1.0))
-        if gating_rank < 0 or dcn_wait < ctx.autoscale_shed_wait_fraction:
+        threshold = DefaultValues.AUTOSCALE_SHED_WAIT_FRACTION
+        if gating_rank < 0 or dcn_wait < threshold:
             return None
         sid = self._rendezvous.slice_of(gating_rank)
         if sid < 0:
@@ -370,7 +372,7 @@ class FleetController:
             "slice": sid,
             "members": members,
             "cross_slice_wait_fraction": round(dcn_wait, 4),
-            "shed_wait_threshold": ctx.autoscale_shed_wait_fraction,
+            "shed_wait_threshold": threshold,
             "dominant_gating_phase": trace.get("dominant_gating_phase",
                                                ""),
             "goodput_fraction": window.get("goodput_fraction", -1.0),
@@ -381,7 +383,7 @@ class FleetController:
                 "reason": (f"slice {sid} gates the critical path (rank "
                            f"{gating_rank}); cross-slice wait "
                            f"{dcn_wait:.0%} > "
-                           f"{ctx.autoscale_shed_wait_fraction:.0%}")}
+                           f"{threshold:.0%}")}
 
     def _degraded_steps_total(self) -> int:
         if self._ledger is None:
@@ -429,8 +431,8 @@ class FleetController:
         rollback = self._check_watch(ctx, now, window)
         if rollback is not None:
             return rollback
-        candidate = self._claim_candidate(ctx, now, window) \
-            or self._shed_candidate(ctx, window)
+        candidate = self._claim_candidate(now, window) \
+            or self._shed_candidate(window)
         with self._lock:
             if candidate is None:
                 self._hysteresis.clear()
@@ -507,9 +509,9 @@ class FleetController:
         current = float(window.get("goodput_fraction", -1.0))
         baseline = float(watch.get("baseline", -1.0))
         kind = watch["kind"]
+        drop = DefaultValues.AUTOSCALE_ROLLBACK_DROP_FRACTION
         dropped = (baseline > 0.0 and current >= 0.0
-                   and current < baseline
-                   * (1.0 - ctx.autoscale_rollback_drop_fraction))
+                   and current < baseline * (1.0 - drop))
         if not dropped:
             with self._lock:
                 self._quarantine_level[kind] = 0
@@ -523,7 +525,7 @@ class FleetController:
             self._quarantine_level[kind] = level
             multiplier = min(_QUARANTINE_MAX_MULTIPLIER,
                              2 ** (level - 1))
-            quarantine_s = ctx.autoscale_quarantine_backoff_s \
+            quarantine_s = DefaultValues.AUTOSCALE_QUARANTINE_BACKOFF_S \
                 * multiplier
             self._quarantine_until[kind] = now + quarantine_s
             self._mark_outcome_locked(watch["decision_id"],
@@ -558,8 +560,7 @@ class FleetController:
             kind="rollback", now=now,
             reason=(f"{kind} #{watch['decision_id']} rolled back: "
                     f"windowed goodput {current:.0%} < baseline "
-                    f"{baseline:.0%} − "
-                    f"{ctx.autoscale_rollback_drop_fraction:.0%}; "
+                    f"{baseline:.0%} − {drop:.0%}; "
                     f"class quarantined {quarantine_s:.0f}s"),
             evidence={"decision_id": watch["decision_id"],
                       "decision_kind": kind,
@@ -674,12 +675,9 @@ class FleetController:
             }
 
     # -- loop --------------------------------------------------------------
-    def start(self, interval_s: Optional[float] = None) -> None:
-        interval = (interval_s if interval_s is not None
-                    else Context.singleton().autoscale_interval_s)
-
+    def start(self) -> None:
         def _loop():
-            while not self._stopped.wait(interval):
+            while not self._stopped.wait(DefaultValues.AUTOSCALE_INTERVAL_S):
                 try:
                     self.evaluate_once()
                 except Exception:  # noqa: BLE001 — loop must survive

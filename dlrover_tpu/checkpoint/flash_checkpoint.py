@@ -30,6 +30,7 @@ import jax
 import orbax.checkpoint as ocp
 
 from dlrover_tpu import obs
+from dlrover_tpu.common.constants import DefaultValues
 from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.parallel.sharding import mesh_shardings
 
@@ -160,7 +161,9 @@ class FlashCheckpointer:
     def save_emergency(self, step: int, state: Any,
                        data_state: Optional[Dict[str, Any]] = None,
                        deadline: float = 0.0,
-                       min_window_s: Optional[float] = None) -> str:
+                       min_window_s: float = (
+                           DefaultValues.EMERGENCY_CKPT_MIN_WINDOW_S)
+                       ) -> str:
         """Deadline-bounded save on the way out (preemption drain): the
         VM disappears at ``deadline`` (unix ts), so the save must COMMIT
         before then or not start at all. Returns the outcome:
@@ -179,10 +182,6 @@ class FlashCheckpointer:
         """
         import time as _time
 
-        if min_window_s is None:
-            from dlrover_tpu.common.config import Context
-
-            min_window_s = Context.singleton().emergency_ckpt_min_window_s
         now = _time.time()
         remaining = deadline - now if deadline > 0 else float("inf")
         with self._lock:

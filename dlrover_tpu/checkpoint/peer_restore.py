@@ -47,7 +47,7 @@ import numpy as np
 
 from dlrover_tpu import obs
 from dlrover_tpu.common.config import Context
-from dlrover_tpu.common.constants import NodeEnv
+from dlrover_tpu.common.constants import DefaultValues, NodeEnv
 from dlrover_tpu.common.log import default_logger as logger
 
 MANIFEST = "manifest.json"
@@ -820,7 +820,7 @@ class PeerRestorer:
             abstract_by_key[key] = leaf
             wanted[key] = int(np.prod(leaf.shape)
                               * np.dtype(leaf.dtype).itemsize)
-        deadline = time.time() + Context.singleton().peer_restore_timeout_s
+        deadline = time.time() + DefaultValues.PEER_RESTORE_TIMEOUT_S
         t0 = time.monotonic()
         local_dir = self._cache.directory if self._cache else ""
         with obs.span("restore_peer_transfer",

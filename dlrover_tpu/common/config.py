@@ -4,6 +4,11 @@ Capability parity: dlrover/python/common/global_context.py — one place for
 timeouts, thresholds and ports, overridable via env vars (``DLROVER_TPU_<KEY>``)
 or programmatically (tests), and updatable at runtime from a resource-plan
 service (the Brain-equivalent) without restarting the master.
+
+A field lives here only while something sets it: a deployment (ports,
+paths), a cloud's contract, a test, an example. A number with one value
+in use is a constant in ``constants.DefaultValues`` and its reader takes
+it from there (tests/test_common.py holds the rule).
 """
 
 from __future__ import annotations
@@ -19,18 +24,11 @@ class Context:
     _lock = threading.Lock()
 
     def __init__(self):
-        self.master_port: int = DefaultValues.MASTER_PORT
         self.metrics_port: int = DefaultValues.METRICS_PORT
-        self.rdzv_timeout_s: float = DefaultValues.RDZV_TIMEOUT_S
-        self.rdzv_wait_new_node_s: float = DefaultValues.RDZV_WAIT_NEW_NODE_S
-        self.task_timeout_s: float = DefaultValues.TASK_TIMEOUT_S
-        self.heartbeat_interval_s: float = DefaultValues.HEARTBEAT_INTERVAL_S
         self.hang_seconds: float = DefaultValues.HANG_SECONDS
         self.dead_node_timeout_s: float = (
             DefaultValues.DEAD_NODE_TIMEOUT_S
         )
-        self.max_relaunch: int = DefaultValues.MAX_RELAUNCH
-        self.kv_wait_timeout_s: float = DefaultValues.KV_WAIT_TIMEOUT_S
         # client RPC budget (agent/master_client.py): per-call deadline,
         # attempt count, and the jittered-exponential-backoff envelope —
         # tests shrink these so failure paths run in milliseconds
@@ -46,64 +44,29 @@ class Context:
         # advertised address across restarts ("" = env-only resolution)
         self.master_state_dir: str = ""
         self.master_bootstrap_file: str = ""
-        self.master_snapshot_retain: int = (
-            DefaultValues.MASTER_SNAPSHOT_RETAIN
-        )
         self.master_snapshot_min_interval_s: float = (
             DefaultValues.MASTER_SNAPSHOT_MIN_INTERVAL_S
         )
         # sharded control plane (master/rendezvous_shards.py +
         # master/coord_service.py + master/standby.py): per-slice
-        # rendezvous shards, the KV/coordination tier's own port, the
-        # bounded telemetry ingest, and the hot-standby promoter
+        # rendezvous shards, the KV/coordination tier's own port, and
+        # the hot-standby promoter
         self.rdzv_sharded: bool = DefaultValues.RDZV_SHARDED
         self.coord_port: int = DefaultValues.COORD_PORT
-        self.telemetry_queue_size: int = (
-            DefaultValues.TELEMETRY_QUEUE_SIZE
-        )
-        self.kv_gc_keep_generations: int = (
-            DefaultValues.KV_GC_KEEP_GENERATIONS
-        )
         self.standby_health_interval_s: float = (
             DefaultValues.STANDBY_HEALTH_INTERVAL_S
         )
         self.standby_promote_failures: int = (
             DefaultValues.STANDBY_PROMOTE_FAILURES
         )
-        self.monitor_interval_s: float = DefaultValues.MONITOR_INTERVAL_S
-        self.report_resource_interval_s: float = (
-            DefaultValues.REPORT_RESOURCE_INTERVAL_S
-        )
-        self.speed_sample_window: int = DefaultValues.SPEED_SAMPLE_WINDOW
-        self.straggler_median_ratio: float = (
-            DefaultValues.STRAGGLER_MEDIAN_RATIO
-        )
-        # training diagnosis engine (master/diagnosis/): rule thresholds,
-        # cadence and the action kill-switch — see docs/observability.md
-        self.diagnosis_enabled: bool = DefaultValues.DIAGNOSIS_ENABLED
-        self.diagnosis_interval_s: float = (
-            DefaultValues.DIAGNOSIS_INTERVAL_S
-        )
-        self.diagnosis_worker_window: int = (
-            DefaultValues.DIAGNOSIS_WORKER_WINDOW
-        )
+        # training diagnosis engine (master/diagnosis/): the rule
+        # thresholds tests move and the action kill-switch — see
+        # docs/observability.md
         self.diagnosis_min_worker_samples: int = (
             DefaultValues.DIAGNOSIS_MIN_WORKER_SAMPLES
         )
         self.straggler_trigger_windows: int = (
             DefaultValues.STRAGGLER_TRIGGER_WINDOWS
-        )
-        self.straggler_clear_windows: int = (
-            DefaultValues.STRAGGLER_CLEAR_WINDOWS
-        )
-        self.diagnosis_data_wait_fraction: float = (
-            DefaultValues.DIAGNOSIS_DATA_WAIT_FRACTION
-        )
-        self.diagnosis_hbm_pressure_pct: float = (
-            DefaultValues.DIAGNOSIS_HBM_PRESSURE_PCT
-        )
-        self.diagnosis_collapse_ratio: float = (
-            DefaultValues.DIAGNOSIS_COLLAPSE_RATIO
         )
         self.diagnosis_actions_enabled: bool = (
             DefaultValues.DIAGNOSIS_ACTIONS_ENABLED
@@ -120,17 +83,6 @@ class Context:
             DefaultValues.GOODPUT_ALERT_THRESHOLD
         )
         self.goodput_window_s: float = DefaultValues.GOODPUT_WINDOW_S
-        self.goodput_min_coverage: float = (
-            DefaultValues.GOODPUT_MIN_COVERAGE
-        )
-        # fleet time-series plane (obs/tsdb.py): master-side history
-        # store sampling + sidecar-persistence cadences
-        self.tsdb_sample_interval_s: float = (
-            DefaultValues.TSDB_SAMPLE_INTERVAL_S
-        )
-        self.tsdb_flush_interval_s: float = (
-            DefaultValues.TSDB_FLUSH_INTERVAL_S
-        )
         # planner calibration (parallel/calibration.py) + the
         # PlanRegressionRule thresholds (master/diagnosis/rules.py)
         self.calibration_min_samples: int = (
@@ -145,11 +97,7 @@ class Context:
         self.plan_regression_clear_windows: int = (
             DefaultValues.PLAN_REGRESSION_CLEAR_WINDOWS
         )
-        self.seconds_per_scale_check: float = (
-            DefaultValues.SECONDS_PER_SCALE_CHECK
-        )
-        # preemption-aware graceful drain (agent/preemption.py) + the
-        # deadline-bounded emergency checkpoint (checkpoint/, trainer/)
+        # preemption-aware graceful drain (agent/preemption.py)
         self.preempt_default_grace_s: float = (
             DefaultValues.PREEMPT_DEFAULT_GRACE_S
         )
@@ -159,17 +107,11 @@ class Context:
         self.preempt_env_horizon_s: float = (
             DefaultValues.PREEMPT_ENV_HORIZON_S
         )
-        self.emergency_ckpt_min_window_s: float = (
-            DefaultValues.EMERGENCY_CKPT_MIN_WINDOW_S
-        )
         # peer-to-peer elastic restore (checkpoint/peer_restore.py):
         # replacement ranks restore from surviving hosts' staged state,
         # falling back to Orbax shard-wise when no replica survived
         self.peer_restore_enabled: bool = (
             DefaultValues.PEER_RESTORE_ENABLED
-        )
-        self.peer_restore_timeout_s: float = (
-            DefaultValues.PEER_RESTORE_TIMEOUT_S
         )
         self.peer_donor_port: int = DefaultValues.PEER_DONOR_PORT
         # online parallelism re-planning (parallel/planner.py): the
@@ -179,29 +121,17 @@ class Context:
         self.replan_enabled: bool = DefaultValues.REPLAN_ENABLED
         # multi-slice hierarchical DP (parallel/dcn_sync.py): degraded-
         # mode budget while a slice is absent, the per-step DCN collect
-        # deadline, and the wire quantization of the host-level sync
+        # deadline and its poll cadence
         self.slice_absent_max_steps: int = (
             DefaultValues.SLICE_ABSENT_MAX_STEPS
         )
         self.dcn_sync_timeout_s: float = DefaultValues.DCN_SYNC_TIMEOUT_S
         self.dcn_sync_poll_s: float = DefaultValues.DCN_SYNC_POLL_S
-        self.dcn_sync_quant_bits: int = (
-            DefaultValues.DCN_SYNC_QUANT_BITS
-        )
         # step-hang watchdog (trainer/watchdog.py); 0 = disabled
         self.hang_watchdog_s: float = DefaultValues.HANG_WATCHDOG_S
         # per-step critical-path tracing (obs/steptrace.py +
-        # master/steptrace.py): worker record ring + clock-probe
-        # cadence, master assembly ring, and the CriticalPathRule
-        # gating-fraction threshold (0 disables the rule)
-        self.steptrace_enabled: bool = DefaultValues.STEPTRACE_ENABLED
-        self.steptrace_ring: int = DefaultValues.STEPTRACE_RING
-        self.steptrace_probe_interval_s: float = (
-            DefaultValues.STEPTRACE_PROBE_INTERVAL_S
-        )
-        self.steptrace_ring_steps: int = (
-            DefaultValues.STEPTRACE_RING_STEPS
-        )
+        # master/steptrace.py): the CriticalPathRule gating-fraction
+        # threshold (0 disables the rule)
         self.critical_path_gating_fraction: float = (
             DefaultValues.CRITICAL_PATH_GATING_FRACTION
         )
@@ -226,9 +156,6 @@ class Context:
         self.fleet_controller_enabled: bool = (
             DefaultValues.FLEET_CONTROLLER_ENABLED
         )
-        self.autoscale_interval_s: float = (
-            DefaultValues.AUTOSCALE_INTERVAL_S
-        )
         self.autoscale_cooldown_s: float = (
             DefaultValues.AUTOSCALE_COOLDOWN_S
         )
@@ -238,40 +165,17 @@ class Context:
         self.autoscale_max_decisions_per_hour: int = (
             DefaultValues.AUTOSCALE_MAX_DECISIONS_PER_HOUR
         )
-        self.autoscale_rollback_drop_fraction: float = (
-            DefaultValues.AUTOSCALE_ROLLBACK_DROP_FRACTION
-        )
         self.autoscale_rollback_window_s: float = (
             DefaultValues.AUTOSCALE_ROLLBACK_WINDOW_S
-        )
-        self.autoscale_quarantine_backoff_s: float = (
-            DefaultValues.AUTOSCALE_QUARANTINE_BACKOFF_S
-        )
-        self.autoscale_claim_margin: float = (
-            DefaultValues.AUTOSCALE_CLAIM_MARGIN
-        )
-        self.autoscale_shed_wait_fraction: float = (
-            DefaultValues.AUTOSCALE_SHED_WAIT_FRACTION
         )
         # speed-aware dynamic sharding (master/shard/task_manager.py):
         # False = byte-identical legacy round-robin dispatch
         self.dispatch_speed_weighted: bool = (
             DefaultValues.DISPATCH_SPEED_WEIGHTED
         )
-        self.dispatch_weight_floor: float = (
-            DefaultValues.DISPATCH_WEIGHT_FLOOR
-        )
-        # data-pipeline auto-tune (data/prefetch.py): advisory depth /
-        # ring sizing from the timeline's data_wait fraction
+        # data-pipeline auto-tune (data/prefetch.py): advisory depth
+        # sizing from the timeline's data_wait fraction
         self.prefetch_autotune: bool = DefaultValues.PREFETCH_AUTOTUNE
-        self.prefetch_depth_min: int = DefaultValues.PREFETCH_DEPTH_MIN
-        self.prefetch_depth_max: int = DefaultValues.PREFETCH_DEPTH_MAX
-        self.data_wait_tune_fraction: float = (
-            DefaultValues.DATA_WAIT_TUNE_FRACTION
-        )
-        self.relaunch_on_worker_failure: bool = True
-        self.auto_scale_enabled: bool = False
-        self.network_check_enabled: bool = False
         self._load_env_overrides()
 
     def _load_env_overrides(self) -> None:

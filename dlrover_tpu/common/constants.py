@@ -261,13 +261,11 @@ class TraceScope:
 
 
 class DefaultValues:
-    MASTER_PORT = 0                 # 0 → pick a free port
     METRICS_PORT = 0                # /metrics exposition; 0 → free port,
     #                                 -1 → disabled
     RDZV_TIMEOUT_S = 600.0
     RDZV_WAIT_NEW_NODE_S = 30.0     # grace window for extra nodes past min
     TASK_TIMEOUT_S = 1800.0
-    HEARTBEAT_INTERVAL_S = 15.0
     HANG_SECONDS = 1800.0
     # an agent silent this long is declared dead: its rendezvous world is
     # invalidated so survivors re-form (the scale-DOWN path). Liveness is
@@ -314,7 +312,6 @@ class DefaultValues:
     # consecutive failed probes trigger promotion
     STANDBY_HEALTH_INTERVAL_S = 2.0
     STANDBY_PROMOTE_FAILURES = 3
-    KV_WAIT_TIMEOUT_S = 300.0
     MONITOR_INTERVAL_S = 5.0
     REPORT_RESOURCE_INTERVAL_S = 15.0
     SPEED_SAMPLE_WINDOW = 20
@@ -322,7 +319,6 @@ class DefaultValues:
     SECONDS_PER_SCALE_CHECK = 60.0
     # training diagnosis engine (master/diagnosis/): the rule-based
     # inference chain over per-worker step reports + resource stats
-    DIAGNOSIS_ENABLED = True
     DIAGNOSIS_INTERVAL_S = 30.0
     # per-worker step-time window (samples) straggler scoring runs over
     DIAGNOSIS_WORKER_WINDOW = 20
@@ -436,12 +432,9 @@ class DefaultValues:
     # float32 bytes
     DCN_SYNC_QUANT_BITS = 0
     # -- per-step critical-path tracing (obs/steptrace.py) --------------
-    # worker-side: emit one compact trace record per step, batched over
-    # the TelemetryReport channel; False turns the recorder off (the
-    # StepTimeline windowed export keeps running either way)
-    STEPTRACE_ENABLED = True
-    # bounded drop-oldest record ring between flushes (a wedged master
-    # must not grow worker memory)
+    # worker-side: one compact trace record per step, batched over the
+    # TelemetryReport channel, in a bounded drop-oldest record ring
+    # between flushes (a wedged master must not grow worker memory)
     STEPTRACE_RING = 512
     # NTP-style clock-offset refresh cadence against the master (the
     # join-time probe always runs; refreshes ride the report cadence)

@@ -8,7 +8,7 @@ stream role is played by XLA's async dispatch).
 `PrefetchAutoTuner` closes the loop from the step timeline: when the
 windowed ``data_wait`` fraction (obs/timeline.py) says the step loop is
 starving on input, the recommended depth grows toward
-``ctx.prefetch_depth_max``; when the pipeline stops starving it decays
+``PREFETCH_DEPTH_MAX`` (8); when the pipeline stops starving it decays
 back so idle device buffers don't pin HBM. Recommendations are advisory
 and consumed at (re)build boundaries — passing ``tuner.depth_fn`` as
 ``depth`` makes an existing prefetch loop pick up changes batch-to-batch
@@ -22,6 +22,8 @@ import threading
 from typing import Any, Callable, Iterable, Iterator, Optional, Union
 
 import jax
+
+from dlrover_tpu.common.constants import DefaultValues
 
 
 def prefetch_to_device(
@@ -74,19 +76,10 @@ class PrefetchAutoTuner:
     _SHRINK_FRACTION = 0.25
     _SHRINK_CALM_WINDOWS = 2
 
-    def __init__(self, depth: int = 2,
-                 depth_min: Optional[int] = None,
-                 depth_max: Optional[int] = None,
-                 wait_threshold: Optional[float] = None):
-        from dlrover_tpu.common.config import Context
-
-        ctx = Context.singleton()
-        self._min = int(depth_min if depth_min is not None
-                        else ctx.prefetch_depth_min)
-        self._max = int(depth_max if depth_max is not None
-                        else ctx.prefetch_depth_max)
-        self._threshold = float(wait_threshold if wait_threshold is not None
-                                else ctx.data_wait_tune_fraction)
+    def __init__(self, depth: int = 2):
+        self._min = DefaultValues.PREFETCH_DEPTH_MIN
+        self._max = DefaultValues.PREFETCH_DEPTH_MAX
+        self._threshold = DefaultValues.DATA_WAIT_TUNE_FRACTION
         self._lock = threading.Lock()
         self._depth = max(self._min, min(self._max, int(depth)))
         self._calm_windows = 0

@@ -15,7 +15,7 @@ and vice versa. This module splits the coordination tier out:
   lock-free (kv_store.get), so the tier's latency is bounded by the wire,
   not by whatever the control tier is doing.
 - :class:`TelemetryIngestQueue` bounds the OTHER direction: telemetry
-  reports are enqueued (drop-oldest past ``telemetry_queue_size``,
+  reports are enqueued (drop-oldest past ``TELEMETRY_QUEUE_SIZE``, 256,
   counted in ``dlrover_tpu_telemetry_dropped_total``) and replayed onto
   the registry by one background thread — a span storm degrades
   observability, never liveness.

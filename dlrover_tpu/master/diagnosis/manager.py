@@ -2,7 +2,7 @@
 actions.
 
 The master-side consumer of everything PR 2's telemetry plumbing
-collects: on a fixed cadence (``Context.diagnosis_interval_s``) it
+collects: on a fixed cadence (``DIAGNOSIS_INTERVAL_S``, 30 s) it
 snapshots the SpeedMonitor's per-worker step reports and the latest
 NodeResourceStats, runs the rule chain (rules.py), and for every
 conclusion
@@ -35,6 +35,7 @@ from typing import Any, Dict, List, Optional
 from dlrover_tpu import obs
 from dlrover_tpu.common import messages as msg
 from dlrover_tpu.common.config import Context
+from dlrover_tpu.common.constants import DefaultValues
 from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.master.diagnosis.rules import (
     DiagnosisReport,
@@ -504,12 +505,9 @@ class DiagnosisManager:
                     for rank, queue in self._pending.items() if queue}
 
     # -- loop --------------------------------------------------------------
-    def start(self, interval_s: Optional[float] = None) -> None:
-        interval = (interval_s if interval_s is not None
-                    else Context.singleton().diagnosis_interval_s)
-
+    def start(self) -> None:
         def _loop():
-            while not self._stopped.wait(interval):
+            while not self._stopped.wait(DefaultValues.DIAGNOSIS_INTERVAL_S):
                 try:
                     self.diagnose_once()
                 except Exception:  # noqa: BLE001 — loop must survive

@@ -21,6 +21,7 @@ import statistics
 from typing import Any, Dict, List, Optional
 
 from dlrover_tpu.common.config import Context
+from dlrover_tpu.common.constants import DefaultValues
 from dlrover_tpu.master.speed_monitor import WorkerSpeed
 
 # severity levels, mildest first
@@ -136,10 +137,10 @@ class Rule:
 
 class StragglerRule(Rule):
     """Step-time vs moving median with hysteresis: a rank must score over
-    ``straggler_median_ratio`` for ``straggler_trigger_windows``
+    ``STRAGGLER_MEDIAN_RATIO`` (2x) for ``straggler_trigger_windows``
     consecutive evaluations to be flagged (one slow window — a GC pause,
     a checkpoint — is noise), and under it for
-    ``straggler_clear_windows`` to clear. Flagging emits a
+    ``STRAGGLER_CLEAR_WINDOWS`` (2) to clear. Flagging emits a
     ``profile:{rank}`` action so the evidence (an actual device trace)
     collects itself."""
 
@@ -156,7 +157,7 @@ class StragglerRule(Rule):
                                   ctx.diagnosis_min_worker_samples)
         reports: List[DiagnosisReport] = []
         for worker_id, score in scores.items():
-            if score > ctx.straggler_median_ratio:
+            if score > DefaultValues.STRAGGLER_MEDIAN_RATIO:
                 self._under.pop(worker_id, None)
                 count = self._over.get(worker_id, 0) + 1
                 self._over[worker_id] = count
@@ -184,7 +185,7 @@ class StragglerRule(Rule):
                 if worker_id in self._flagged:
                     count = self._under.get(worker_id, 0) + 1
                     self._under[worker_id] = count
-                    if count >= ctx.straggler_clear_windows:
+                    if count >= DefaultValues.STRAGGLER_CLEAR_WINDOWS:
                         self._flagged.discard(worker_id)
                         self._under.pop(worker_id, None)
                         reports.append(DiagnosisReport(
@@ -227,7 +228,8 @@ class DataPipelineBoundRule(Rule):
         for worker_id, speed in snapshot.worker_speeds.items():
             if speed.samples < ctx.diagnosis_min_worker_samples:
                 continue
-            if speed.data_wait_fraction >= ctx.diagnosis_data_wait_fraction:
+            if (speed.data_wait_fraction
+                    >= DefaultValues.DIAGNOSIS_DATA_WAIT_FRACTION):
                 bound.add(worker_id)
                 if worker_id not in self._reported:
                     self._reported.add(worker_id)
@@ -250,7 +252,7 @@ class DataPipelineBoundRule(Rule):
 
 class ThroughputCollapseRule(Rule):
     """Windowed MFU (preferred) or steps/s under
-    ``diagnosis_collapse_ratio`` × the world's observed high-water mark.
+    ``DIAGNOSIS_COLLAPSE_RATIO`` (0.5) × the world's observed high-water mark.
     MFU is the better collapse signal once a FLOPs model is reported:
     it is what the fleet actually pays for, and a report phrased as
     "MFU 0.18 vs peak 0.63" is directly actionable where raw tokens/s
@@ -264,7 +266,6 @@ class ThroughputCollapseRule(Rule):
         self._collapsed = False
 
     def evaluate(self, snapshot, ctx=None):
-        ctx = ctx or Context.singleton()
         if snapshot.peak_mfu > 0.0 and snapshot.running_mfu >= 0.0:
             running, peak = snapshot.running_mfu, snapshot.peak_mfu
             evidence = (f"MFU {running:.3f} vs this world's peak "
@@ -280,7 +281,7 @@ class ThroughputCollapseRule(Rule):
         if peak <= 0.0 or running <= 0.0:
             return []
         ratio = running / peak
-        if ratio < ctx.diagnosis_collapse_ratio:
+        if ratio < DefaultValues.DIAGNOSIS_COLLAPSE_RATIO:
             if self._collapsed:
                 return []
             self._collapsed = True
@@ -303,7 +304,7 @@ class GoodputRule(Rule):
     is actionable (restore-bound vs compile-bound vs data-wait demand
     different fixes). Disabled by default (threshold 0 — an acceptable
     floor is job-specific); the window must be at least
-    ``goodput_min_coverage`` covered before judging, so a fresh world's
+    ``GOODPUT_MIN_COVERAGE`` (half) covered before judging, so a fresh world's
     first minutes are not evidence."""
 
     name = "goodput"
@@ -320,8 +321,8 @@ class GoodputRule(Rule):
         window_s = float(evidence.get("window_s", 0.0))
         elapsed = float(evidence.get("elapsed_rank_seconds", 0.0))
         workers = max(1, snapshot.running_workers)
-        if window_s <= 0.0 or \
-                elapsed < ctx.goodput_min_coverage * window_s * workers:
+        covered = DefaultValues.GOODPUT_MIN_COVERAGE * window_s * workers
+        if window_s <= 0.0 or elapsed < covered:
             return []
         fraction = float(evidence.get("goodput_fraction", -1.0))
         if fraction < 0.0:
@@ -369,7 +370,6 @@ class HbmPressureRule(Rule):
         self._reported: set = set()
 
     def evaluate(self, snapshot, ctx=None):
-        ctx = ctx or Context.singleton()
         reports: List[DiagnosisReport] = []
         pressured = set()
         for worker_id, stats in snapshot.node_stats.items():
@@ -397,7 +397,7 @@ class HbmPressureRule(Rule):
                 pct = 100.0 * node_peak / max_total
                 if pct > worst:
                     worst, signal = pct, "step_peak_watermark"
-            if worst >= ctx.diagnosis_hbm_pressure_pct:
+            if worst >= DefaultValues.DIAGNOSIS_HBM_PRESSURE_PCT:
                 pressured.add(worker_id)
                 if worker_id not in self._reported:
                     self._reported.add(worker_id)
@@ -505,7 +505,7 @@ class CriticalPathRule(Rule):
     data_wait vs checkpoint), so the profile request already knows what
     it is looking for. Hysteresis mirrors StragglerRule
     (``straggler_trigger_windows`` to flag,
-    ``straggler_clear_windows`` to clear); disabled when the fraction
+    ``STRAGGLER_CLEAR_WINDOWS`` to clear); disabled when the fraction
     knob is <= 0, the window has fewer than
     ``diagnosis_min_worker_samples`` traced steps, or no step of the
     window joined more than one rank."""
@@ -579,7 +579,7 @@ class CriticalPathRule(Rule):
                 if worker_id in self._flagged:
                     count = self._under.get(worker_id, 0) + 1
                     self._under[worker_id] = count
-                    if count >= ctx.straggler_clear_windows:
+                    if count >= DefaultValues.STRAGGLER_CLEAR_WINDOWS:
                         self._flagged.discard(worker_id)
                         self._under.pop(worker_id, None)
                         reports.append(DiagnosisReport(

@@ -17,7 +17,12 @@ from typing import Optional
 from dlrover_tpu import obs
 from dlrover_tpu.common.comm import build_server
 from dlrover_tpu.common.config import Context
-from dlrover_tpu.common.constants import JobStage, NodeType, RendezvousName
+from dlrover_tpu.common.constants import (
+    DefaultValues,
+    JobStage,
+    NodeType,
+    RendezvousName,
+)
 from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.master.kv_store import KVStoreService
 from dlrover_tpu.master.state_backend import MasterStateBackend, MutationLog
@@ -55,7 +60,7 @@ class JobMaster:
         params = RendezvousParameters(
             min_nodes=min_nodes,
             max_nodes=max_nodes,
-            wait_new_node_s=ctx.rdzv_wait_new_node_s,
+            wait_new_node_s=DefaultValues.RDZV_WAIT_NEW_NODE_S,
             node_unit=node_unit,
         )
         self.task_manager = TaskManager()
@@ -73,12 +78,13 @@ class JobMaster:
             RendezvousName.TRAINING: training_mgr,
             RendezvousName.NETWORK_CHECK:
                 NetworkCheckRendezvousManager(
-                    RendezvousParameters(min_nodes, max_nodes,
-                                         ctx.rdzv_wait_new_node_s)
+                    RendezvousParameters(
+                        min_nodes, max_nodes,
+                        DefaultValues.RDZV_WAIT_NEW_NODE_S)
                 ),
         }
         self.kv_store = KVStoreService(
-            keep_generations=ctx.kv_gc_keep_generations)
+            keep_generations=DefaultValues.KV_GC_KEEP_GENERATIONS)
         self.sync_service = SyncService(expected_workers=min_nodes)
         self.elastic_ps_service = ElasticPsService()
         self.job_manager = job_manager
@@ -106,21 +112,18 @@ class JobMaster:
         from dlrover_tpu.master.steptrace import StepTraceAssembler
 
         self.steptrace = StepTraceAssembler(tsdb=self.tsdb)
-        self.diagnosis_manager = None
-        if ctx.diagnosis_enabled:
-            from dlrover_tpu.master.diagnosis import DiagnosisManager
+        from dlrover_tpu.master.diagnosis import DiagnosisManager
 
-            self.diagnosis_manager = DiagnosisManager(
-                self.speed_monitor,
-                goodput_ledger=self.goodput_ledger,
-                plan_calibration=self.plan_calibration,
-                steptrace=self.steptrace)
+        self.diagnosis_manager = DiagnosisManager(
+            self.speed_monitor,
+            goodput_ledger=self.goodput_ledger,
+            plan_calibration=self.plan_calibration,
+            steptrace=self.steptrace)
         # the goodput-optimal fleet controller
         # (brain/fleet_controller.py): closes the diagnosis→actuation
         # loop — claims offered preemptible slices, sheds gating ones,
-        # holds behind guardrails. Deliberately gated on its OWN knob,
-        # not the legacy auto_scale_enabled (node-count autoscaling,
-        # JobAutoScaler): the two act on different layers.
+        # holds behind guardrails. Gated on its OWN knob: node-count
+        # autoscaling (JobAutoScaler) acts on a different layer.
         self.capacity_provider = None
         self.fleet_controller = None
         if ctx.fleet_controller_enabled:
@@ -157,12 +160,11 @@ class JobMaster:
             # a shed actuates through the EXISTING slice-unit drain
             # chain (the servicer's notice-phase handler)
             self.fleet_controller.shed_sink = self._controller_shed
-        if self.diagnosis_manager is not None:
-            # learned-discount feedback rides the diagnosis cadence,
-            # not the per-report hot path (the medians only move as
-            # samples accumulate)
-            self.diagnosis_manager.discount_sink = \
-                self.servicer.push_axis_discounts
+        # learned-discount feedback rides the diagnosis cadence, not the
+        # per-report hot path (the medians only move as samples
+        # accumulate)
+        self.diagnosis_manager.discount_sink = \
+            self.servicer.push_axis_discounts
         self._host = host
         self._server, self.port = build_server(
             self.servicer.get_bytes, self.servicer.report_bytes,
@@ -199,7 +201,6 @@ class JobMaster:
             self._attach_optimization(job_args, brain_addr)
         self._init_state_backend(
             state_dir if state_dir is not None else ctx.master_state_dir,
-            ctx.master_snapshot_retain,
             preloaded_state=preloaded_state,
         )
         self._arm_master_chaos()
@@ -237,7 +238,7 @@ class JobMaster:
         self.servicer.coord_addr = self.coord_addr
 
     # -- crash-consistent control-plane state --------------------------
-    def _init_state_backend(self, state_dir: str, retain: int,
+    def _init_state_backend(self, state_dir: str,
                             preloaded_state: Optional[tuple] = None
                             ) -> None:
         """Attach the snapshot store and, when a prior master left valid
@@ -265,8 +266,8 @@ class JobMaster:
             self._snapshot_timer: Optional[threading.Timer] = None
         self.generation = 0
         if state_dir:
-            self._state_backend = MasterStateBackend(state_dir,
-                                                     retain=retain)
+            self._state_backend = MasterStateBackend(
+                state_dir, retain=DefaultValues.MASTER_SNAPSHOT_RETAIN)
             # snapshots stop the moment a higher-generation master owns
             # the lineage.  The gate reads the latched flag, NOT
             # _check_fenced: backend saves run under _snapshot_lock,
@@ -307,8 +308,7 @@ class JobMaster:
             self.servicer.state_sink = self._maybe_snapshot
             if self._coord_server is not None:
                 self.coord_servicer.state_sink = self._maybe_snapshot
-            if self.diagnosis_manager is not None:
-                self.diagnosis_manager.state_sink = self._maybe_snapshot
+            self.diagnosis_manager.state_sink = self._maybe_snapshot
             if self.fleet_controller is not None:
                 self.fleet_controller.state_sink = self._maybe_snapshot
             # the generation bump itself must be durable before the
@@ -347,9 +347,8 @@ class JobMaster:
             "speed_monitor": self.speed_monitor.export_state(),
             "goodput": self.goodput_ledger.export_state(),
             "plan_calibration": self.plan_calibration.export_state(),
+            "diagnosis": self.diagnosis_manager.export_state(),
         }
-        if self.diagnosis_manager is not None:
-            state["diagnosis"] = self.diagnosis_manager.export_state()
         if self.fleet_controller is not None:
             state["fleet_controller"] = \
                 self.fleet_controller.export_state()
@@ -387,7 +386,7 @@ class JobMaster:
             discounts = self.plan_calibration.axis_discounts()
             if discounts:
                 self.servicer.push_axis_discounts(discounts)
-        if self.diagnosis_manager is not None and "diagnosis" in state:
+        if "diagnosis" in state:
             self.diagnosis_manager.restore_state(state["diagnosis"])
         if self.fleet_controller is not None and \
                 "fleet_controller" in state:
@@ -544,9 +543,8 @@ class JobMaster:
         queue carries the order instead."""
         from dlrover_tpu.common import messages as msg
 
-        if self.diagnosis_manager is not None:
-            self.diagnosis_manager.request_drain(
-                [rank], deadline, reason=reason)
+        self.diagnosis_manager.request_drain(
+            [rank], deadline, reason=reason)
         self.servicer._handle_drain(msg.DrainReport(
             node_rank=rank, phase="notice", deadline=deadline,
             reason=reason))
@@ -598,7 +596,7 @@ class JobMaster:
             self.auto_scaler = JobAutoScaler(
                 self.job_manager, optimizer,
                 speed_monitor=self.speed_monitor,
-                interval_s=Context.singleton().seconds_per_scale_check,
+                interval_s=DefaultValues.SECONDS_PER_SCALE_CHECK,
             )
             self.auto_scaler.paral_config_sink = (
                 self.servicer.merge_paral_config)
@@ -617,8 +615,7 @@ class JobMaster:
         if self.auto_scaler is not None:
             self.auto_scaler.start()
         self.task_manager.start_timeout_recovery()
-        if self.diagnosis_manager is not None:
-            self.diagnosis_manager.start()
+        self.diagnosis_manager.start()
         if self.fleet_controller is not None:
             self.fleet_controller.start()
         if self.tsdb_collector is not None:
@@ -774,8 +771,7 @@ class JobMaster:
                 self.metric_collector.stop()
             if self.auto_scaler is not None:
                 self.auto_scaler.stop()
-            if self.diagnosis_manager is not None:
-                self.diagnosis_manager.stop()
+            self.diagnosis_manager.stop()
             if self.fleet_controller is not None:
                 self.fleet_controller.stop()
                 try:

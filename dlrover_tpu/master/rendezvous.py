@@ -22,8 +22,7 @@ from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
 from dlrover_tpu import obs
-from dlrover_tpu.common.config import Context
-from dlrover_tpu.common.constants import RendezvousName
+from dlrover_tpu.common.constants import DefaultValues, RendezvousName
 from dlrover_tpu.common.log import default_logger as logger
 
 
@@ -1303,7 +1302,7 @@ class NetworkCheckRendezvousManager(RendezvousManager):
     def detect_stragglers(self) -> List[int]:
         """elapsed > ratio × median in the latest round (reference:
         _detect_stragglers rdzv_manager.py:446)."""
-        ratio = Context.singleton().straggler_median_ratio
+        ratio = DefaultValues.STRAGGLER_MEDIAN_RATIO
         with self._lock:
             if not self._reports:
                 return []

@@ -18,7 +18,7 @@ import grpc
 from dlrover_tpu import obs
 from dlrover_tpu.common import messages as msg
 from dlrover_tpu.common.config import Context
-from dlrover_tpu.common.constants import RendezvousName
+from dlrover_tpu.common.constants import DefaultValues, RendezvousName
 from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.master.diagnosis.manager import DiagnosisManager
 from dlrover_tpu.master.kv_store import KVStoreService
@@ -130,7 +130,7 @@ class MasterServicer:
 
         self.telemetry_queue = TelemetryIngestQueue(
             self._process_telemetry,
-            maxlen=Context.singleton().telemetry_queue_size)
+            maxlen=DefaultValues.TELEMETRY_QUEUE_SIZE)
 
     # ------------------------------------------------------------------
     # raw byte endpoints (wired into comm.build_server)

@@ -277,7 +277,7 @@ class BatchDatasetManager:
                 state.get("sub_epoch_offset", 0))
         self.todo = deque(task_from(e) for e in state.get("todo", ()))
         # in-flight tasks get a fresh timeout clock: charging the master's
-        # outage against task_timeout_s would requeue (and double-assign)
+        # outage against TASK_TIMEOUT_S would requeue (and double-assign)
         # shards their workers are still legitimately computing
         now = time.time()
         self.doing = {

@@ -14,7 +14,7 @@ import time
 from typing import Dict, Optional, Tuple
 
 from dlrover_tpu.common.config import Context
-from dlrover_tpu.common.constants import TaskType
+from dlrover_tpu.common.constants import DefaultValues, TaskType
 from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.common.messages import DatasetShardParams, Task
 from dlrover_tpu.master.shard.dataset_manager import (
@@ -89,8 +89,8 @@ class TaskManager:
         """(lock held) Deterministic stride deferral: rank r is served
         iff served < polls x weight, with weight = its relative speed
         (SpeedMonitor.relative_speeds) clamped to
-        [ctx.dispatch_weight_floor, 1.0]. Faster workers keep weight 1.0
-        and are never deferred; a 3x-slow rank at the default 0.25 floor
+        [DISPATCH_WEIGHT_FLOOR, 1.0]. Faster workers keep weight 1.0
+        and are never deferred; a 3x-slow rank at the 0.25 floor
         sees at most 3 consecutive WAITs, so progress is guaranteed and
         epoch coverage stays exactly-once (a deferral never pops a
         task, it only delays the pop). Polls count only while the
@@ -102,7 +102,7 @@ class TaskManager:
         score = scores.get(worker_id)
         if score is None or len(scores) < 2:
             return False   # no evidence, or no pack to pace against
-        weight = max(Context.singleton().dispatch_weight_floor,
+        weight = max(DefaultValues.DISPATCH_WEIGHT_FLOOR,
                      min(1.0, score))
         counter = self._dispatch_counters.setdefault(
             (dataset.dataset_name, worker_id), [0, 0])
@@ -151,10 +151,10 @@ class TaskManager:
             }
 
     def recover_timeout_tasks(self) -> None:
-        timeout = Context.singleton().task_timeout_s
         with self._lock:
             for dataset in self._datasets.values():
-                dataset.recover_timeout_tasks(timeout)
+                dataset.recover_timeout_tasks(
+                    DefaultValues.TASK_TIMEOUT_S)
 
     def start_timeout_recovery(self, interval_s: float = 60.0
                                ) -> threading.Thread:

@@ -26,6 +26,7 @@ from typing import Deque, Dict, Iterable, Optional, Set, Tuple
 
 from dlrover_tpu import obs
 from dlrover_tpu.common.config import Context
+from dlrover_tpu.common.constants import DefaultValues
 
 
 @dataclasses.dataclass
@@ -46,9 +47,8 @@ class WorkerSpeed:
 class SpeedMonitor:
     def __init__(self):
         self._lock = threading.Lock()
-        ctx = Context.singleton()
         self._samples: Deque[Tuple[float, int]] = deque(
-            maxlen=ctx.speed_sample_window
+            maxlen=DefaultValues.SPEED_SAMPLE_WINDOW
         )
         self._global_step = 0
         # graftlint: ephemeral(this incarnation's clock anchor)
@@ -60,7 +60,7 @@ class SpeedMonitor:
         self._worker_steps: Dict[int, int] = {}
         # worker_id -> deque[(step_time_s, data_wait_fraction, mfu, ts)]
         # from step reports that carried timing evidence
-        self._worker_window = max(2, ctx.diagnosis_worker_window)
+        self._worker_window = max(2, DefaultValues.DIAGNOSIS_WORKER_WINDOW)
         self._worker_times: Dict[
             int, Deque[Tuple[float, float, float, float]]] = {}
         # worker_id -> deque[(latency_s, records, ts)] from completed

@@ -37,7 +37,7 @@ from collections import OrderedDict
 from typing import Any, Dict, List, Optional, Tuple
 
 from dlrover_tpu import obs
-from dlrover_tpu.common.config import Context
+from dlrover_tpu.common.constants import DefaultValues
 from dlrover_tpu.obs.steptrace import phase_seconds
 
 STEPTRACE_PAYLOAD_VERSION = 1
@@ -200,14 +200,12 @@ class StepTraceAssembler:
     -joined attributions)."""
 
     def __init__(self, tsdb=None, registry=None,
-                 ring_steps: Optional[int] = None,
+                 ring_steps: int = DefaultValues.STEPTRACE_RING_STEPS,
                  summary_window: int = 64):
         self._lock = threading.Lock()
         self._tsdb = tsdb
         self._registry = registry or obs.get_registry()
-        self._ring_steps = max(
-            1, int(ring_steps if ring_steps is not None
-                   else Context.singleton().steptrace_ring_steps))
+        self._ring_steps = max(1, int(ring_steps))
         self._summary_window = max(1, int(summary_window))
         # (gen, step) -> {"recs": {rank: record}, "published": bool,
         #                 "solved": Optional[dict]}

@@ -80,11 +80,8 @@ class LlamaConfig:
 
     @classmethod
     def llama_wide_1b(cls, **kw) -> "LlamaConfig":
-        """Gemma-style wide-MLP variant (i/h = 4 instead of Llama's 2.7),
-        tuned for single-chip MFU: the MLP matmul is the near-peak part
-        of the step (98% of peak measured on v5e at these shapes), so at
-        a fixed HBM budget, trading attention/norm layers for MLP width
-        raises utilization — 0.66 vs 0.63 MFU against llama_1b."""
+        """Gemma-style wide-MLP variant (i/h = 4 instead of Llama's 2.7):
+        1.47B parameters, 20 layers; the width ``chip_smoke.py`` drives."""
         return cls(hidden_size=2048, intermediate_size=8192,
                    num_layers=20, num_heads=16, num_kv_heads=16, **kw)
 
@@ -99,12 +96,6 @@ class LlamaConfig:
         kw.setdefault("max_seq_len", 128)
         return cls(hidden_size=64, intermediate_size=128, num_layers=2,
                    num_heads=4, num_kv_heads=2, rms_norm_eps=1e-5, **kw)
-
-    def flops_per_token(self) -> float:
-        """Approximate training FLOPs/token (fwd+bwd ≈ 6·params +
-        attention term 12·L·H·T·d at seq T) — used for MFU accounting."""
-        params = self.param_count()
-        return 6.0 * params
 
     def param_count(self) -> int:
         h, i, v, L = (self.hidden_size, self.intermediate_size,

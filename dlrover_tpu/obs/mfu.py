@@ -1,9 +1,10 @@
 """Model-FLOPs / MFU accounting: one formula, every consumer.
 
-``bench.py`` proved the conservative accounting (6·params matmul credit
-plus the causal-discounted attention term); this module makes that the
-framework's single source so the worker's step reports, the master's
-gauges and the benches can never drift apart. The analytic model is
+The conservative accounting (6·params matmul credit plus the
+causal-discounted attention term) lives here and nowhere else in the
+program, so the worker's step reports and the master's gauges can never
+drift apart (``benchmarks/`` counts on its own, on purpose: the
+yardstick must not move with the program). The analytic model is
 cross-checkable against what XLA actually compiled via
 :func:`cost_analysis_flops` (``jax.jit(...).lower(...).compile()
 .cost_analysis()``) — callers pass the compiled object in, so this

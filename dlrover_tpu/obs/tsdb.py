@@ -37,6 +37,7 @@ import time
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
+from dlrover_tpu.common.constants import DefaultValues
 from dlrover_tpu.common.log import default_logger as logger
 
 TSDB_VERSION = 1
@@ -518,23 +519,19 @@ class TsdbCollector:
 
     def __init__(self, store: TimeSeriesStore, registry=None,
                  goodput_ledger=None, state_dir: str = "",
-                 sample_interval_s: Optional[float] = None,
-                 flush_interval_s: Optional[float] = None,
+                 sample_interval_s: float = (
+                     DefaultValues.TSDB_SAMPLE_INTERVAL_S),
+                 flush_interval_s: float = (
+                     DefaultValues.TSDB_FLUSH_INTERVAL_S),
                  clock: Callable[[], float] = time.time):
-        from dlrover_tpu.common.config import Context
         from dlrover_tpu.obs.metrics import get_registry
 
-        ctx = Context.singleton()
         self._store = store
         self._registry = registry if registry is not None \
             else get_registry()
         self._goodput = goodput_ledger
-        self._sample_interval_s = (
-            sample_interval_s if sample_interval_s is not None
-            else ctx.tsdb_sample_interval_s)
-        self._flush_interval_s = (
-            flush_interval_s if flush_interval_s is not None
-            else ctx.tsdb_flush_interval_s)
+        self._sample_interval_s = sample_interval_s
+        self._flush_interval_s = flush_interval_s
         self._clock = clock
         self._sidecar = (TimeSeriesSidecar(state_dir)
                          if state_dir else None)

@@ -200,7 +200,7 @@ class PlanCalibration:
 
     def table(self) -> List[Dict[str, Any]]:
         """Every calibrated shape, stamped-first order (by first_ts):
-        what ``bench_replan.py`` emits and ``tools/top.py`` renders."""
+        what ``tools/top.py`` renders."""
         with self._lock:
             ordered = sorted(self._entries.items(),
                              key=lambda kv: kv[1].get("first_ts", 0.0))

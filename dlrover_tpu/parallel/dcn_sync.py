@@ -53,6 +53,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from dlrover_tpu.common.constants import DefaultValues
 from dlrover_tpu.common.log import default_logger as logger
 
 # Legacy (pre-episode-hygiene) key names: used when the master's slice
@@ -511,10 +512,11 @@ class SliceGradSync:
             formed.setdefault(self.slice_id, True)
         self._service_rejoin(step, state_leaves_fn, formed)
         if self.is_leader:
-            self._try_kv_set(self._grad_key(self.slice_id),
-                             encode_leaves(
-                                 leaves, step,
-                                 quant_bits=ctx.dcn_sync_quant_bits))
+            self._try_kv_set(
+                self._grad_key(self.slice_id),
+                encode_leaves(
+                    leaves, step,
+                    quant_bits=DefaultValues.DCN_SYNC_QUANT_BITS))
         t_post = self._clock()    # local contribution on the wire
         contributions: List[List[np.ndarray]] = [
             [np.asarray(leaf, np.float32) for leaf in leaves]]

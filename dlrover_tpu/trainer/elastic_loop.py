@@ -31,7 +31,7 @@ from jax.profiler import StepTraceAnnotation, TraceAnnotation
 from dlrover_tpu import obs
 from dlrover_tpu.agent.preemption import DrainRequestSource
 from dlrover_tpu.checkpoint import FlashCheckpointer
-from dlrover_tpu.common.constants import WorkerExit
+from dlrover_tpu.common.constants import DefaultValues, WorkerExit
 from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.parallel.mesh import MeshSpec, create_mesh, dp_size
 from dlrover_tpu.trainer.sampler import ElasticDistributedSampler
@@ -268,20 +268,15 @@ class ElasticTrainLoop:
         # over the telemetry channel; the join-time probe anchors the
         # offset before the first step, report-cadence refreshes keep
         # the drift allowance small
-        from dlrover_tpu.common.config import Context as _TraceCtx
-
-        _trace_ctx = _TraceCtx.singleton()
         self._clock_sync = obs.ClockSync(
             probe_fn=(self.client.probe_clock
                       if self.client is not None else None))
-        self._steptrace = (
-            obs.StepTraceRecorder(
-                capacity=_trace_ctx.steptrace_ring,
-                rank=int(os.environ.get(NodeEnv.NODE_RANK, "-1")),
-                slice_id=self._slice_id,
-                clock_sync=self._clock_sync)
-            if _trace_ctx.steptrace_enabled else None)
-        if self._steptrace is not None and self.client is not None:
+        self._steptrace = obs.StepTraceRecorder(
+            capacity=DefaultValues.STEPTRACE_RING,
+            rank=int(os.environ.get(NodeEnv.NODE_RANK, "-1")),
+            slice_id=self._slice_id,
+            clock_sync=self._clock_sync)
+        if self.client is not None:
             self._clock_sync.probe()
         # SliceGradSync's per-reduce marks, stashed by _slice_step for
         # the record built at the step boundary
@@ -660,10 +655,9 @@ class ElasticTrainLoop:
             cfg = getattr(model, "config", None)
             self._param_count = param_count
             self._param_bytes = param_bytes
-            # same accounting as bench.py: a gather-lookup embedding
-            # table with an untied head does no matmul — crediting it
-            # would report a higher MFU than the bench measures for the
-            # identical model
+            # a gather-lookup embedding table with an untied head does
+            # no matmul — crediting it would report a higher MFU than
+            # the benchmark's own count gives the identical model
             uncounted = 0.0
             if (getattr(cfg, "embed_impl", "") == "gather"
                     and not getattr(cfg, "tie_embeddings", True)):
@@ -1097,8 +1091,7 @@ class ElasticTrainLoop:
                     compute=seconds["dispatch"],
                     checkpoint=seconds["save"],
                 )
-                if self._steptrace is not None:
-                    self._record_steptrace(step, marks)
+                self._record_steptrace(step, marks)
                 due = step % config.report_interval_steps == 0
                 if due and self.client is not None:
                     with marks.phase("report", "dlrover/report"):
@@ -1184,7 +1177,7 @@ class ElasticTrainLoop:
         ])
         state, apply_metrics = self.trainer.apply_grads(state,
                                                         fleet_grads)
-        if self._steptrace is not None and info.get("trace"):
+        if info.get("trace"):
             # the sync's clock() marks share the loop's monotonic
             # domain; apply-dispatch end completes the decomposition
             stashed = dict(info["trace"])
@@ -1444,13 +1437,11 @@ class ElasticTrainLoop:
             self.timeline.export(
                 self._timeline_path,
                 last_n=2 * self.config.report_interval_steps)
-        if self._steptrace is not None and self.client is not None:
+        if self.client is not None:
             # periodic clock refresh rides the report cadence (one RPC,
             # rate-limited by the probe interval — never per step)
-            from dlrover_tpu.common.config import Context as _Ctx
-
             self._clock_sync.maybe_probe(
-                _Ctx.singleton().steptrace_probe_interval_s)
+                DefaultValues.STEPTRACE_PROBE_INTERVAL_S)
         try:
             from dlrover_tpu.agent.monitor import export_chip_stats
 
@@ -1485,8 +1476,7 @@ class ElasticTrainLoop:
     def _flush_telemetry(self) -> None:
         if self.client is not None:
             self._span_exporter.flush_to(self.client)
-            if self._steptrace is not None:
-                self._steptrace.flush_to(self.client)
+            self._steptrace.flush_to(self.client)
 
     def close(self) -> None:
         self._flush_telemetry()

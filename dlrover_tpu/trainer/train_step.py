@@ -181,9 +181,7 @@ def build_trainer(
     rules: Optional[Sequence] = None,
     donate_state: bool = True,
     offload_opt_state: bool = False,
-    rng_seed: int = 0,
     grad_reduce_bits: int = 0,
-    grad_reduce_axis: Optional[str] = None,
     split_grad_apply: bool = False,
 ) -> ShardedTrainer:
     """Lower (model, optimizer, mesh) into init/step programs.
@@ -198,15 +196,15 @@ def build_trainer(
     host↔HBM transfers around the update, freeing ~2/3 of the train
     state's HBM at the cost of PCIe/DMA traffic per step.
 
-    grad_reduce_bits: 8/4 = the gradient mean over ``grad_reduce_axis``
+    grad_reduce_bits: 8/4 = the gradient mean over the reduce axis
     runs through the quantized collective
     (parallel/quant_collectives.py, the reference quant_reduce.cu
     analog) instead of XLA's implicit fp psum: the whole step is wrapped
     in a shard_map manual over that one axis, every other axis stays
     auto. 0 = exact reduce (default).
 
-    grad_reduce_axis: None resolves hierarchically — the ``dcn`` axis
-    when the mesh spans slices (dcn > 1), else ``data``. A dcn reduce
+    The reduce axis resolves hierarchically — the ``dcn`` axis when the
+    mesh spans slices (dcn > 1), else ``data``. A dcn reduce
     makes the gradient sync explicitly two-level: the in-slice mean
     rides XLA's implicit psum over the (data, fsdp) axes inside each
     slice block, then the cross-slice mean (all-)reduces over the
@@ -313,8 +311,7 @@ def build_trainer(
         # (MoE gating jitter, dropout): folded from the step counter so
         # every restart replays identically, and identical across
         # replicas as SPMD single-program semantics require.
-        step_key = jax.random.fold_in(jax.random.PRNGKey(rng_seed),
-                                      state.step)
+        step_key = jax.random.fold_in(jax.random.PRNGKey(0), state.step)
 
         def micro_step(carry, micro):
             loss_acc, grad_acc = carry
@@ -391,12 +388,11 @@ def build_trainer(
         }
         return new_state, metrics
 
-    if grad_reduce_axis is None:
-        # hierarchical by default: a mesh spanning slices reduces over
-        # the dcn axis (in-slice implicit + cross-slice explicit)
-        grad_reduce_axis = (MeshAxis.DCN
-                            if mesh.shape.get(MeshAxis.DCN, 1) > 1
-                            else MeshAxis.DATA)
+    # hierarchical: a mesh spanning slices reduces over the dcn axis
+    # (in-slice implicit + cross-slice explicit)
+    grad_reduce_axis = (MeshAxis.DCN
+                        if mesh.shape.get(MeshAxis.DCN, 1) > 1
+                        else MeshAxis.DATA)
     n_reduce = mesh.shape.get(grad_reduce_axis, 1)
     # the dcn axis always reduces explicitly (the hierarchical
     # contract), quantized or not; other axes only when quantized

@@ -8,9 +8,9 @@ ONE chip: the `streaming` strategy pass (auto/opt_lib/library.py)
 lowers to the per-layer streaming trainer (trainer/streaming.py) —
 backward runs as a reverse per-layer loop that applies a per-leaf
 optimizer (factored-rms here) in place, so peak memory is params + one
-layer's gradients instead of the full gradient tree. This is how
-`bench.py --llama7b` trains Llama-7B (13.5 GB bf16 params) on a
-15.75 GB v5e at 2.8k tok/s.
+layer's gradients instead of the full gradient tree: the path for a
+model whose bf16 params fit a chip but whose gradient tree does not
+(Llama-7B, 13.5 GB of bf16 params, on a 15.75 GB v5e).
 
 Run on one host (the streaming trainer is single-device by design;
 multi-chip scale-out composes the ordinary trainers with fsdp/PP):

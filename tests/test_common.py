@@ -3,11 +3,14 @@ global context (reference analogues: test_node.py / grpc message tests)."""
 
 import os
 import pickle
+import re
+from pathlib import Path
 
 import pytest
 
 from dlrover_tpu.common.config import Context
 from dlrover_tpu.common.constants import (
+    DefaultValues,
     NodeEventType,
     NodeExitReason,
     NodeStatus,
@@ -112,10 +115,10 @@ class TestMessages:
 
 class TestContext:
     def test_env_override(self, monkeypatch):
-        monkeypatch.setenv("DLROVER_TPU_MAX_RELAUNCH", "9")
+        monkeypatch.setenv("DLROVER_TPU_RPC_RETRIES", "9")
         Context.reset()
         try:
-            assert Context.singleton().max_relaunch == 9
+            assert Context.singleton().rpc_retries == 9
         finally:
             Context.reset()
 
@@ -126,6 +129,41 @@ class TestContext:
         assert ctx.hang_seconds == 123.0
         assert not hasattr(ctx, "nonexistent_key")
         Context.reset()
+
+
+class TestSettingsRule:
+    """A setting exists only while something reads it AND something
+    sets it; a number with one value in use is a ``DefaultValues``
+    constant its reader takes directly (ROADMAP D5)."""
+
+    PACKAGE = Path(__file__).resolve().parent.parent / "dlrover_tpu"
+
+    def _sources(self, skip):
+        return {path: path.read_text()
+                for path in sorted(self.PACKAGE.rglob("*.py"))
+                if path.name != skip or path.parent.name != "common"}
+
+    def _fields(self):
+        return [name for name in vars(Context()) if not name.startswith("_")]
+
+    def test_every_field_is_read(self):
+        text = "\n".join(self._sources("config.py").values())
+        unread = [name for name in self._fields()
+                  if not re.search(rf"\.{name}\b", text)]
+        assert not unread, (
+            f"Context fields no code under dlrover_tpu/ reads: {unread}")
+
+    def test_field_count_does_not_grow(self):
+        assert len(self._fields()) <= 50
+
+    def test_every_constant_is_read(self):
+        text = "\n".join(self._sources("constants.py").values())
+        names = [name for name in vars(DefaultValues) if name.isupper()]
+        assert len(names) > 50
+        orphaned = [name for name in names
+                    if not re.search(rf"\bDefaultValues\.{name}\b", text)]
+        assert not orphaned, (
+            f"DefaultValues constants nothing reads: {orphaned}")
 
 
 class TestMessageSecurity:

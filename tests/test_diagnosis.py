@@ -48,11 +48,6 @@ def _diagnose():
 _DIAG_KNOBS = dict(
     diagnosis_min_worker_samples=2,
     straggler_trigger_windows=2,
-    straggler_clear_windows=2,
-    straggler_median_ratio=2.0,
-    diagnosis_data_wait_fraction=0.5,
-    diagnosis_hbm_pressure_pct=92.0,
-    diagnosis_collapse_ratio=0.5,
     diagnosis_actions_enabled=True,
     diagnosis_action_cooldown_s=0.0,
     diagnosis_profile_steps=3,
@@ -311,7 +306,7 @@ class TestStragglerRule:
         assert rule.flagged == {2}
         # stays flagged, no duplicate report
         assert rule.evaluate(_snap(slow), diag_ctx) == []
-        # recovery: needs straggler_clear_windows consecutive clean
+        # recovery: needs STRAGGLER_CLEAR_WINDOWS (2) consecutive clean
         assert rule.evaluate(_snap(fast), diag_ctx) == []
         assert rule.flagged == {2}
         cleared = rule.evaluate(_snap(fast), diag_ctx)

@@ -104,10 +104,6 @@ _KNOBS = dict(
     autoscale_cooldown_s=0.0,
     autoscale_max_decisions_per_hour=100,
     autoscale_rollback_window_s=60.0,
-    autoscale_rollback_drop_fraction=0.2,
-    autoscale_quarantine_backoff_s=600.0,
-    autoscale_claim_margin=1.2,
-    autoscale_shed_wait_fraction=0.3,
 )
 
 
@@ -457,8 +453,7 @@ def test_report_dataset_task_feeds_the_monitor():
 # -- speed-weighted dispatch -------------------------------------------------
 
 
-_DISPATCH_KNOBS = dict(dispatch_speed_weighted=True,
-                       dispatch_weight_floor=0.25)
+_DISPATCH_KNOBS = dict(dispatch_speed_weighted=True)
 
 
 @pytest.fixture()
@@ -549,8 +544,7 @@ def test_dispatch_needs_a_pack_to_pace_against(dispatch_ctx):
 # -- prefetch autotune -------------------------------------------------------
 
 
-_TUNE_KNOBS = dict(prefetch_autotune=True, prefetch_depth_min=1,
-                   prefetch_depth_max=8, data_wait_tune_fraction=0.2)
+_TUNE_KNOBS = dict(prefetch_autotune=True)
 
 
 @pytest.fixture()
@@ -580,7 +574,7 @@ def test_prefetch_tuner_grows_shrinks_with_dead_band(tune_ctx):
     assert tuner.depth == 2
     for _ in range(20):
         tuner.observe(0.9)
-    assert tuner.depth == 8       # clamped at prefetch_depth_max
+    assert tuner.depth == 8       # clamped at PREFETCH_DEPTH_MAX
     assert tuner.ring_capacity(base_capacity=64) == 64 * 4
 
 
@@ -653,7 +647,6 @@ _ACCEPT_KNOBS = dict(
     autoscale_hysteresis_windows=1,
     autoscale_cooldown_s=0.0,
     autoscale_max_decisions_per_hour=100,
-    autoscale_claim_margin=1.2,
     goodput_window_s=30.0,
 )
 

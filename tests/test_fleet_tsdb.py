@@ -803,7 +803,6 @@ class TestHbmPressureWatermark:
         peak (the thing that actually OOMs on the next batch bump)."""
         from dlrover_tpu.master.diagnosis.rules import HbmPressureRule
 
-        Context.singleton().update(diagnosis_hbm_pressure_pct=92.0)
         trough_only = _snapshot(node_stats={0: {
             "ts": time.time(),
             "chips": [{"index": 0, "hbm_used_mb": 500.0,
@@ -823,7 +822,6 @@ class TestHbmPressureWatermark:
     def test_step_report_watermark_beats_chip_file(self):
         from dlrover_tpu.master.diagnosis.rules import HbmPressureRule
 
-        Context.singleton().update(diagnosis_hbm_pressure_pct=92.0)
         snap = _snapshot(node_stats={0: {
             "ts": time.time(),
             "hbm_peak_mb": 980.0,              # from the step report

@@ -265,8 +265,8 @@ class TestLedgerClassification:
 
 class TestMfuMath:
     def test_flops_per_token_matches_bench_formula(self):
-        """The framework formula and bench.py's accounting are the same
-        function now — golden-check both against the hand formula."""
+        """The program has one FLOPs formula (obs/mfu.py) — golden-check
+        it against the hand formula."""
         from dlrover_tpu.models.llama import LlamaConfig
 
         cfg = LlamaConfig.tiny()
@@ -392,9 +392,7 @@ class TestMfuExposition:
 @pytest.fixture()
 def goodput_ctx():
     ctx = Context.singleton()
-    knobs = dict(goodput_alert_threshold=0.5, goodput_window_s=600.0,
-                 goodput_min_coverage=0.5,
-                 diagnosis_collapse_ratio=0.5)
+    knobs = dict(goodput_alert_threshold=0.5, goodput_window_s=600.0)
     saved = {key: getattr(ctx, key) for key in knobs}
     ctx.update(**knobs)
     yield ctx

@@ -483,7 +483,6 @@ class TestCriticalPathRule:
 
         ctx = Context.singleton()
         ctx.update(straggler_trigger_windows=1,
-                   straggler_clear_windows=2,
                    diagnosis_min_worker_samples=2)
         rule = CriticalPathRule()
         rule.evaluate(self._snapshot(self._summary()), ctx)
