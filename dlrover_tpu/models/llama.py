@@ -223,15 +223,17 @@ class Attention(nn.Module):
         cfg = self.config
         batch, seq, _ = x.shape
         dense = functools_partial_dense(cfg)
+        # the four weight gradients inside this module's backward
+        x, tie = tie_weight_grads(x)
         q = dense("q_proj", (cfg.hidden_size,
                              cfg.num_heads * cfg.head_dim),
-                  ("embed", "heads"))(x)
+                  ("embed", "heads"))(x, tie)
         k = dense("k_proj", (cfg.hidden_size,
                              cfg.num_kv_heads * cfg.head_dim),
-                  ("embed", "kv"))(x)
+                  ("embed", "kv"))(x, tie)
         v = dense("v_proj", (cfg.hidden_size,
                              cfg.num_kv_heads * cfg.head_dim),
-                  ("embed", "kv"))(x)
+                  ("embed", "kv"))(x, tie)
         q = q.reshape(batch, seq, cfg.num_heads, cfg.head_dim)
         k = k.reshape(batch, seq, cfg.num_kv_heads, cfg.head_dim)
         v = v.reshape(batch, seq, cfg.num_kv_heads, cfg.head_dim)
@@ -258,22 +260,106 @@ class Attention(nn.Module):
             out = out.transpose(0, 2, 1, 3).reshape(batch, seq, -1)
         return dense("o_proj",
                      (cfg.num_heads * cfg.head_dim, cfg.hidden_size),
-                     ("heads", "embed"))(out)
+                     ("heads", "embed"))(out, tie)
+
+
+# -- each module's weight gradients inside that module's backward ----------
+# Left alone, XLA puts every projection's weight gradient behind the WHOLE
+# backward pass (nothing needs it before the optimizer; its scheduler walks
+# back from the updated parameters in the order of their tree), so what
+# each reads (gate, up, d h, the normed inputs, the attention output and
+# its cotangent: ~235 MB a layer at hidden 2048 x 8192, batch 2 x 2048)
+# lives until then, and a program that does not fit recomputes forward
+# matmuls to make room (18 a step for 24 layers on a 16 GB chip; PERF.md
+# section 6, PR 36). A module says "mine before you go on":
+#
+#     x, tie = tie_weight_grads(x)        # at the module's input
+#     y = tied_dot(x, kernel, tie)        # every projection of the module
+#
+# and the cotangent of `x`, which the backward pass needs to go on to the
+# layer before, is not formed until each of those weight gradients is.
+# Order only, by a dependence on the data: no gradient is fenced, so a
+# weight gradient still fuses with whatever reads it (the optimizer's sums),
+# and inside the module the order stays XLA's. Once a projection (with or
+# without `optimization_barrier`) is faster where the memory is short and
+# 1 % slower where it is not; once a module does not lose there.
+
+
+def _zero_tie(x):
+    tie = jnp.zeros((), jnp.float32)
+    # inside a check_vma shard_map (the pipeline's stages) the tie varies
+    # over the manual axes as x does, as its cotangent, made of x, will
+    manual = tuple(jax.typeof(x).vma)
+    return jax.lax.pcast(tie, manual, to="varying") if manual else tie
+
+
+@jax.custom_vjp
+def tie_weight_grads(x: jax.Array):
+    """(x, tie): `x` itself and a float32 zero for the module's `tied_dot`s
+    to take. The backward rule scales the cotangent of `x` by 1 + the
+    cotangent of `tie`, which is 0 times each taker's weight gradient."""
+    return x, _zero_tie(x)
+
+
+def _tie_weight_grads_fwd(x):
+    return (x, _zero_tie(x)), None
+
+
+def _tie_weight_grads_bwd(_, cotangents):
+    dx, dtie = cotangents
+    return (dx * (1 + dtie).astype(dx.dtype),)
+
+
+tie_weight_grads.defvjp(_tie_weight_grads_fwd, _tie_weight_grads_bwd)
+
+
+@jax.custom_vjp
+def tied_dot(x: jax.Array, w: jax.Array, tie: jax.Array) -> jax.Array:
+    """`jnp.dot(x, w)`, x (..., in) and w (in, out), whose backward rule
+    forms both gradients as `jnp.dot`'s own transpose does (same
+    contractions, same dtypes, the operands' dtype out of float32
+    accumulation) and hands `tie` (from `tie_weight_grads`) a cotangent
+    that is 0 and yet read off the weight gradient: 0 x sum(dW^2), the sum
+    the optimizer's gradient norm takes anyway."""
+    return jnp.dot(x, w)
+
+
+def _tied_dot_fwd(x, w, tie):
+    return jnp.dot(x, w), (x, w)
+
+
+def _tied_dot_bwd(operands, g):
+    x, w = operands
+    rows = tuple(range(g.ndim - 1))
+    dx = jax.lax.dot_general(g, w, (((g.ndim - 1,), (1,)), ((), ())),
+                             preferred_element_type=g.dtype)
+    dw = jax.lax.dot_general(g, x, ((rows, rows), ((), ())),
+                             preferred_element_type=g.dtype).T
+    # not a constant to XLA: 0 x inf is NaN, so the product stays, and a
+    # gradient that is not finite has ended the run anyway
+    return dx, dw, 0 * jnp.sum(jnp.square(dw.astype(jnp.float32)))
+
+
+tied_dot.defvjp(_tied_dot_fwd, _tied_dot_bwd)
 
 
 def functools_partial_dense(cfg: LlamaConfig):
-    """A kernel-only linear with named logical axes."""
+    """A kernel-only linear with named logical axes; called with its
+    input and the module's `tie` (`tie_weight_grads`), or with its input
+    alone by a module that opens none and gets the plain product."""
 
     def make(name, shape, axes):
         class _Dense(nn.Module):
             @nn.compact
-            def __call__(self, x):
+            def __call__(self, x, tie=None):
                 kernel = self.param(
                     "kernel",
                     _logical(nn.initializers.normal(0.02), *axes),
                     shape, cfg.param_dtype,
                 )
-                return jnp.dot(x, kernel.astype(cfg.dtype))
+                if tie is None:
+                    return jnp.dot(x, kernel.astype(cfg.dtype))
+                return tied_dot(x, kernel.astype(cfg.dtype), tie)
 
         return _Dense(name=name)
 
@@ -287,12 +373,14 @@ class MLP(nn.Module):
     def __call__(self, x):
         cfg = self.config
         dense = functools_partial_dense(cfg)
+        # the three weight gradients inside this module's backward
+        x, tie = tie_weight_grads(x)
         gate = dense("gate_proj", (cfg.hidden_size, cfg.intermediate_size),
-                     ("embed", "mlp"))(x)
+                     ("embed", "mlp"))(x, tie)
         up = dense("up_proj", (cfg.hidden_size, cfg.intermediate_size),
-                   ("embed", "mlp"))(x)
+                   ("embed", "mlp"))(x, tie)
         return dense("down_proj", (cfg.intermediate_size, cfg.hidden_size),
-                     ("mlp", "embed"))(nn.silu(gate) * up)
+                     ("mlp", "embed"))(nn.silu(gate) * up, tie)
 
 
 ACT_AXES = ("act_batch", "act_seq", "act_embed")

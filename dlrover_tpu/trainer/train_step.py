@@ -10,6 +10,7 @@ rules over the mesh, so DP/FSDP/TP are a table change, not a wrapper class.
 from __future__ import annotations
 
 import dataclasses
+import re
 from typing import Any, Callable, Optional, Sequence, Tuple
 
 import flax.linen as nn
@@ -37,6 +38,56 @@ def abstract_state_with_shardings(abstract: Any, shardings: Any) -> Any:
         lambda leaf, sharding: jax.ShapeDtypeStruct(
             leaf.shape, leaf.dtype, sharding=sharding),
         abstract, shardings)
+
+
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?%(?P<name>\S+) = (?P<result>.*?) (?P<opcode>[\w\-]+)\(.*"
+    r'op_name="(?P<op_name>[^"]*)"', re.M)
+
+
+def schedule_counts(compiled_text: str, rows_and_seq: Tuple[int, ...]) -> dict:
+    """What the compiled step's ENTRY computation, which is printed in
+    schedule order, says of two things XLA decides and nobody asks for:
+
+    `remat_instructions`: forward matmuls (`jvp(` outside `transpose(`,
+    `dot_general`) launched beyond the first of their `op_name` where XLA
+    marks one of them `.remat`, i.e. recomputed to fit the memory. Neither
+    sign alone will do: XLA may call the only copy `.remat2`, and a mesh
+    may split one product into several of one `op_name`.
+
+    `late_weight_grads`: weight-gradient matmuls of the projections
+    (`transpose(jvp(` + `_proj/`, with a result that is no activation: it
+    does not start with a device's `rows_and_seq`) that stand after the
+    backward pass's last activation-gradient matmul, with everything they
+    read alive until then; that last projection's own does not count, it
+    may stand on either side of its sibling."""
+    entry = compiled_text[compiled_text.rfind("\nENTRY "):]
+    activation = "[" + ",".join(str(d) for d in rows_and_seq) + ","
+    forward, weight_grads, last_dx = {}, [], (-1, "")
+    for at, found in enumerate(_INSTRUCTION.finditer(entry)):
+        opcode, op_name = found["opcode"], found["op_name"]
+        matmul = opcode == "convolution" or (
+            opcode == "fusion" and "kind=kOutput" in found[0])
+        if not matmul or not op_name.endswith("dot_general"):
+            continue
+        if "transpose(jvp(" in op_name:
+            if "_proj/" not in op_name:
+                continue
+            if activation in found["result"]:
+                last_dx = (at, op_name)
+            else:
+                weight_grads.append((at, op_name))
+        elif "jvp(" in op_name:
+            launches, marked = forward.get(op_name, (0, False))
+            forward[op_name] = (launches + 1,
+                                marked or ".remat" in found["name"])
+    return {
+        "remat_instructions": sum(
+            launches - 1 for launches, marked in forward.values() if marked),
+        "late_weight_grads": sum(
+            at > last_dx[0] and op_name != last_dx[1]
+            for at, op_name in weight_grads),
+    }
 
 
 @flax.struct.dataclass
@@ -122,6 +173,18 @@ class ShardedTrainer:
             aot_span.set_attr(
                 "compile_or_cache_load_s",
                 self.precompile_timings["compile_or_cache_load_s"])
+            # a device's (rows, sequence) of one micro-batch: how the
+            # compiled text shapes an activation
+            counts = schedule_counts(
+                compiled.as_text(), self.batch_sharding.shard_shape(
+                    self.batch_abstract.shape)[1:])
+            counts["schedule_read_s"] = round(_time.monotonic() - t2, 2)
+            for name, value in counts.items():
+                aot_span.set_attr(name, value)
+        default_logger.info(
+            "step program: remat_instructions=%d late_weight_grads=%d "
+            "(read in %.2f s)", counts["remat_instructions"],
+            counts["late_weight_grads"], counts["schedule_read_s"])
         self._compiled_step = compiled
 
     def step(self, state: TrainState, tokens, targets):

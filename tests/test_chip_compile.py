@@ -39,7 +39,7 @@ from dlrover_tpu.ops.flash_attention import (
 from dlrover_tpu.ops.norms import fused_rms_norm, mesh_rms_norm
 from dlrover_tpu.ops.quantization import dequantize, quantize
 from dlrover_tpu.parallel.mesh import MeshSpec, create_mesh, use_mesh
-from dlrover_tpu.trainer.train_step import build_trainer
+from dlrover_tpu.trainer.train_step import build_trainer, schedule_counts
 
 
 @pytest.fixture(scope="module")
@@ -145,26 +145,36 @@ def test_shard_mapped_norm_on_four_devices(topo, chip_path):
     assert psums and all("{{0,1,2,3}}" in line for line in psums), psums
 
 
+_STEP_TEXTS: dict = {}
+
+
+def _full_width_step_text(topo, n_devices: int) -> str:
+    """The compiled text of one whole step program of the 1.47B Llama at
+    full width (hidden 2048, MLP 8192, 16 heads, seq 2048, bf16, flash +
+    fused norm, factored-RMS), depth cut to 2 layers, for one described
+    chip or for a four-chip mesh with the state sharded fsdp=4. Compiled
+    once a mesh, under the caller's `chip_path`."""
+    if n_devices not in _STEP_TEXTS:
+        cfg = dataclasses.replace(
+            LlamaConfig.llama_wide_1b(
+                max_seq_len=2048, attn_impl="flash", norm_impl="fused",
+                embed_impl="gather", dtype=jnp.bfloat16),
+            num_layers=2)
+        tx = optax.chain(optax.scale_by_factored_rms(), optax.scale(-3e-4))
+        spec = MeshSpec(fsdp=4) if n_devices == 4 else MeshSpec()
+        mesh = create_mesh(spec, topo.devices[:n_devices])
+        micro = 2 * n_devices
+        trainer = build_trainer(
+            Llama(cfg), tx, mesh, jnp.zeros((micro, 2048), jnp.int32),
+            cross_entropy_loss, accum_steps=1, micro_batch=micro)
+        trainer.precompile()
+        _STEP_TEXTS[n_devices] = trainer._compiled_step.as_text()
+    return _STEP_TEXTS[n_devices]
+
+
 @pytest.mark.parametrize("n_devices", [1, 4])
 def test_full_width_train_step(topo, chip_path, n_devices):
-    """One whole step program of the 1.47B Llama at full width (hidden
-    2048, MLP 8192, 16 heads, seq 2048, bf16, flash + fused norm,
-    factored-RMS), depth cut to 2 layers, for one described chip and for
-    a four-chip mesh with the state sharded fsdp=4."""
-    cfg = dataclasses.replace(
-        LlamaConfig.llama_wide_1b(
-            max_seq_len=2048, attn_impl="flash", norm_impl="fused",
-            embed_impl="gather", dtype=jnp.bfloat16),
-        num_layers=2)
-    tx = optax.chain(optax.scale_by_factored_rms(), optax.scale(-3e-4))
-    spec = MeshSpec(fsdp=4) if n_devices == 4 else MeshSpec()
-    mesh = create_mesh(spec, topo.devices[:n_devices])
-    micro = 2 * n_devices
-    trainer = build_trainer(
-        Llama(cfg), tx, mesh, jnp.zeros((micro, 2048), jnp.int32),
-        cross_entropy_loss, accum_steps=1, micro_batch=micro)
-    trainer.precompile()
-    text = trainer._compiled_step.as_text()
+    text = _full_width_step_text(topo, n_devices)
     # per layer: 2 norms + attention, forward and backward; final norm
     assert text.count("tpu_custom_call") >= 2 * (2 * 2 + 1)
     if n_devices == 4:
@@ -183,3 +193,25 @@ def test_full_width_train_step(topo, chip_path, n_devices):
         assert any(re.search(rf"(^|[/(]){scope}([/)]|$)", name)
                    for name in op_names), scope
     assert any("transpose(jvp(" in name for name in op_names)
+
+
+@pytest.mark.parametrize("n_devices", [1, 4])
+def test_weight_gradients_stand_inside_their_layers_backward(
+        topo, chip_path, n_devices):
+    """The mechanism of `models/llama.py:tied_dot`, read from the schedule
+    (ENTRY is printed in its order): no projection's weight-gradient
+    matmul stands after the backward pass's last activation-gradient
+    matmul, where it would hold what it reads (gate, up, d h, the normed
+    inputs) alive through every earlier layer's backward, and no forward
+    matmul is launched a second time. Left to itself XLA puts every
+    weight gradient behind the whole backward pass, in the order of the
+    optimizer's parameter tree; at 24 layers it then recomputes 18 MLP
+    matmuls a step to fit the chip (PERF.md section 6, PR 36)."""
+    text = _full_width_step_text(topo, n_devices)
+    # 14 projections, each with an activation gradient and a weight
+    # gradient the count can tell apart: a device's rows x sequence
+    assert schedule_counts(text, (2, 2048)) == {
+        "remat_instructions": 0, "late_weight_grads": 0}
+    assert len(re.findall(
+        r'kind=kOutput[^\n]*transpose\(jvp\([^"\n]*_proj/dot_general"',
+        text[text.rfind("\nENTRY "):])) >= 2 * 14

@@ -26,7 +26,7 @@ from dlrover_tpu.models.llama import (
 from dlrover_tpu.models.llama_moe import LlamaMoE, LlamaMoEConfig
 from dlrover_tpu.parallel.mesh import MeshSpec, create_mesh
 from dlrover_tpu.trainer.elastic_loop import ElasticTrainLoop, TrainLoopConfig
-from dlrover_tpu.trainer.train_step import build_trainer
+from dlrover_tpu.trainer.train_step import build_trainer, schedule_counts
 
 BATCH, SEQ, HIDDEN, VOCAB = 2, 16, 8, 40
 
@@ -370,6 +370,62 @@ def test_precompiles_span_carries_path_and_slices(one_device, spans):
     trainer.precompile()
     (aot,) = _recompiles(spans, "aot")
     assert (aot["head_loss_path"], aot["head_loss_slices"]) == ("fused", 1)
+
+
+def test_precompiles_span_carries_the_schedules_counts(one_device, spans):
+    """Read from the compiled step's text inside the span (PR 36); on the
+    CPU nothing is recomputed and the backend fuses no matmul, so both
+    read 0: that they are there, and what reading them cost."""
+    trainer = _trainer(one_device, Llama(_config()), cross_entropy_loss,
+                       micro=2)
+    trainer.precompile()
+    (aot,) = _recompiles(spans, "aot")
+    assert (aot["remat_instructions"], aot["late_weight_grads"]) == (0, 0)
+    assert 0 <= aot["schedule_read_s"] < 5
+
+
+def _entry(*instructions) -> str:
+    """A compiled step's text cut to what `schedule_counts` reads: some
+    computation before ENTRY, then ENTRY's instructions in order."""
+    lines = ["%fused_computation.1 (p: bf16[8]) -> bf16[8] {",
+             '  ROOT %x.remat = bf16[8]{0} fusion(%p), kind=kOutput, '
+             'metadata={op_name="jit(s)/jvp(M)/layer_0/mlp/up_proj/'
+             'dot_general"}', "}", "", "ENTRY %main (a: s32[]) -> s32[] {"]
+    for name, result, op_name in instructions:
+        lines.append(f"  %{name} = {result} fusion(%a), kind=kOutput, "
+                     f'calls=%c, metadata={{op_name="jit(s)/{op_name}"}}')
+    return "\n".join(lines + ["}"])
+
+
+_ACT, _KERNEL = "bf16[2,64,128]{2,1,0}", "bf16[128,256,1]{1,0,2}"
+_FWD = "jvp(M)/layer_{}/mlp/{}_proj/dot_general"
+_BWD = "transpose(jvp(M))/layer_{}/mlp/{}_proj/dot_general"
+
+
+@pytest.mark.parametrize("order, want", [
+    # left alone: both layers' backward, then the weight gradients, and a
+    # forward matmul launched again in front of the one that reads it
+    ([("f.1", _ACT, _FWD.format(0, "up")), ("f.2", _ACT, _FWD.format(1, "up")),
+      ("dx.2", _ACT, _BWD.format(1, "up")), ("dx.1", _ACT, _BWD.format(0, "up")),
+      ("f.1.remat", _ACT, _FWD.format(0, "up")),
+      ("dw.1", f"(f32[], {_KERNEL})", _BWD.format(0, "up")),
+      ("dw.2", _KERNEL, _BWD.format(1, "up"))], (1, 1)),
+    # tied: each weight gradient beside its layer's activation gradient;
+    # the last projection's own may stand behind its sibling
+    ([("f.1", _ACT, _FWD.format(0, "up")), ("f.2", _ACT, _FWD.format(1, "up")),
+      ("dw.2", _KERNEL, _BWD.format(1, "up")), ("dx.2", _ACT, _BWD.format(1, "up")),
+      ("dx.1", _ACT, _BWD.format(0, "up")),
+      ("dw.1", _KERNEL, _BWD.format(0, "up"))], (0, 0)),
+    # neither sign alone is a recomputation: the only copy under XLA's
+    # mark, and one product a mesh split in two
+    ([("f.1.remat2", _ACT, _FWD.format(0, "up")),
+      ("f.2", _ACT, _FWD.format(1, "up")), ("f.3", _ACT, _FWD.format(1, "up")),
+      ("dx.1", _ACT, _BWD.format(0, "up"))], (0, 0))],
+    ids=["left_alone", "tied", "one_sign"])
+def test_schedule_counts_on_a_hand_made_entry(order, want):
+    counts = schedule_counts(_entry(*order), (2, 64))
+    assert (counts["remat_instructions"],
+            counts["late_weight_grads"]) == want
 
 
 @pytest.mark.parametrize("loss_fn, want", [

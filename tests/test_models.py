@@ -1,6 +1,7 @@
 """Model family tests: shapes, determinism, loss decreases with training,
 flash == reference attention inside the full model."""
 
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -8,10 +9,13 @@ import optax
 import pytest
 
 from dlrover_tpu.models.gpt import GPT, GPTConfig
+from dlrover_tpu.models import llama
 from dlrover_tpu.models.llama import (
     Llama,
     LlamaConfig,
     cross_entropy_loss,
+    tie_weight_grads,
+    tied_dot,
 )
 
 
@@ -79,6 +83,138 @@ class TestLlama:
         assert LlamaConfig.llama_7b().param_count() > 6.5e9
         assert 0.9e9 < LlamaConfig.llama_1b().param_count() < 1.6e9
         assert 3e8 < LlamaConfig.llama_410m().param_count() < 6e8
+
+
+def _same_bits(got, want):
+    assert got.dtype == want.dtype and got.shape == want.shape
+    np.testing.assert_array_equal(
+        np.asarray(got.astype(jnp.float32)),
+        np.asarray(want.astype(jnp.float32)))
+
+
+def _plain_dot(x, w, tie):
+    """The formula before PR 36, kept as the reference."""
+    return jnp.dot(x, w)
+
+
+class TestTiedDot:
+    """`tied_dot` is `jnp.dot` with its weight gradient tied into the
+    module's backward: nothing of the arithmetic may differ from the
+    formula it stands for."""
+
+    @pytest.mark.parametrize("x_shape", [(24, 16), (3, 8, 16)],
+                             ids=["2d", "3d"])
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["f32", "bf16"])
+    def test_value_and_both_gradients_are_jnp_dots_to_the_bit(
+            self, dtype, x_shape):
+        keys = jax.random.split(jax.random.PRNGKey(0), 3)
+        x = jax.random.normal(keys[0], x_shape, dtype)
+        w = jax.random.normal(keys[1], (16, 40), dtype)
+        g = jax.random.normal(keys[2], x_shape[:-1] + (40,), dtype)
+
+        def value_and_grads(dot):
+            def run(x, w, g):
+                def through_the_tie(x, w):
+                    x, tie = tie_weight_grads(x)
+                    return dot(x, w, tie)
+                out, vjp = jax.vjp(through_the_tie, x, w)
+                return out, vjp(g)
+            return run
+
+        # like with like: a jitted product against a jitted `jnp.dot`
+        for wrap in (lambda f: f, jax.jit):
+            got, grads = wrap(value_and_grads(tied_dot))(x, w, g)
+            want, want_grads = wrap(value_and_grads(_plain_dot))(x, w, g)
+            _same_bits(got, want)
+            for a, b in zip(grads, want_grads):
+                _same_bits(a, b)
+
+    def test_the_inputs_cotangent_waits_for_the_weight_gradient(self):
+        """The mechanism: the cotangent `tie` carries is read off dW, and
+        the module input's cotangent is made from it."""
+        x, w = jnp.ones((4, 8)), jnp.ones((8, 2))
+
+        def through_the_tie(x, w):
+            x, tie = tie_weight_grads(x)
+            return tied_dot(x, w, tie)
+
+        # x enters dW = x^T g and not dx = g w^T: an x that is not finite
+        # shows in the tied dx (0 x inf is NaN) and not in the plain one
+        x = x.at[0, 0].set(jnp.inf)
+        dx, dw = jax.vjp(through_the_tie, x, w)[1](jnp.ones((4, 2)))
+        plain_dx, plain_dw = jax.vjp(jnp.dot, x, w)[1](jnp.ones((4, 2)))
+        assert np.isnan(np.asarray(dx)).all()
+        assert np.isfinite(np.asarray(plain_dx)).all()
+        np.testing.assert_array_equal(np.asarray(dw), np.asarray(plain_dw))
+
+    @staticmethod
+    def _loss_and_grads(cfg, params, tokens, targets, jit=False):
+        def loss(p):
+            return cross_entropy_loss(Llama(cfg).apply(p, tokens), targets)
+
+        fn = jax.value_and_grad(loss)
+        if jit:
+            # the state donated, as the trainer's step donates it
+            fn = jax.jit(fn, donate_argnums=0)
+            params = jax.tree.map(jnp.copy, params)
+        return fn(params)
+
+    @pytest.mark.parametrize("how", ["plain", "remat", "jit_donated"])
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["f32", "bf16"])
+    def test_two_layer_llama_equals_the_plain_formula(
+            self, monkeypatch, dtype, how):
+        """Loss and the whole gradient tree against the same model built
+        on `jnp.dot` (the formula before PR 36, kept here)."""
+        cfg = LlamaConfig.tiny(attn_impl="reference", dtype=dtype,
+                               remat=how == "remat")
+        tokens = _data(2, 16, cfg.vocab_size)
+        targets = jnp.roll(tokens, -1, axis=-1)
+        params = Llama(cfg).init(jax.random.PRNGKey(0), tokens)
+        jit = how == "jit_donated"
+        loss, grads = self._loss_and_grads(cfg, params, tokens, targets, jit)
+        monkeypatch.setattr(llama, "tied_dot", _plain_dot)
+        want_loss, want = self._loss_and_grads(
+            cfg, params, tokens, targets, jit)
+        assert jax.tree.structure(grads) == jax.tree.structure(want)
+        if jit and dtype == jnp.bfloat16:
+            # under jit XLA fuses the tie's x 1 into its neighbours and
+            # keeps float32 between them where the plain program rounded
+            # to bf16 (8 bits): a few roundings at each leaf's scale
+            def close(got, want):
+                want = np.asarray(want.astype(jnp.float32))
+                np.testing.assert_allclose(
+                    np.asarray(got.astype(jnp.float32)), want, rtol=0,
+                    atol=4 * 2.0 ** -8 * np.abs(want).max())
+            close(loss, want_loss)
+            jax.tree.map(close, grads, want)
+        else:
+            _same_bits(loss, want_loss)
+            jax.tree.map(_same_bits, grads, want)
+
+    def test_llama_moe_still_traces(self):
+        """`LlamaMoE` takes `Attention`, and with it the tied product."""
+        from dlrover_tpu.models.llama_moe import LlamaMoE, LlamaMoEConfig
+
+        cfg = LlamaMoEConfig.mixtral_tiny(attn_impl="reference")
+        tokens = _data(2, 16, cfg.vocab_size)
+        model = LlamaMoE(cfg)
+        variables = jax.eval_shape(
+            lambda: model.init(jax.random.PRNGKey(0), tokens))
+
+        def loss(params):
+            logits, _ = model.apply(
+                {"params": params}, tokens, mutable=["losses"],
+                rngs={"gating": jax.random.PRNGKey(1)})
+            return jnp.sum(logits.astype(jnp.float32))
+
+        grads = jax.eval_shape(jax.grad(loss), variables["params"])
+        assert (jax.tree.structure(grads)
+                == jax.tree.structure(variables["params"]))
+        assert nn.unbox(grads)["layer_0"]["attn"]["q_proj"][
+            "kernel"].shape == (cfg.hidden_size,
+                                cfg.num_heads * cfg.head_dim)
 
 
 class TestGPT:
