@@ -258,6 +258,10 @@ class TraceScope:
     HEAD_LOSS = "head_loss"      # final norm + head matmul + loss
     OPTIMIZER = "optimizer"      # tx.update + apply_updates
     GRAD_ACCUM = "grad_accum"    # the scan over micro-batches
+    # models/keye.py and what it runs of ops/ and parallel/moe.py
+    INDEXER = "indexer"          # index scores, selection, KL, backward
+    SPARSE_ATTN = "sparse_attn"  # attention over the selected keys
+    MOE = "moe"                  # router, sort, grouped products, combine
 
 
 class DefaultValues:

@@ -62,10 +62,15 @@ class LlamaConfig:
     # "full"/"nothing_saveable" | "dots"/"dots_saveable" | "dots_with_no_batch_dims"
     remat_policy: str = "nothing_saveable"
     tie_embeddings: bool = False
+    # a head's width where the model declares one of its own (q is then
+    # num_heads x attn_head_dim wide, whatever the hidden size); 0: the
+    # usual hidden_size // num_heads
+    attn_head_dim: int = 0
+    qk_norm: bool = False            # RMSNorm over each head of q and k
 
     @property
     def head_dim(self) -> int:
-        return self.hidden_size // self.num_heads
+        return self.attn_head_dim or self.hidden_size // self.num_heads
 
     # ---- stock sizes -----------------------------------------------------
     @classmethod
@@ -100,11 +105,13 @@ class LlamaConfig:
     def param_count(self) -> int:
         h, i, v, L = (self.hidden_size, self.intermediate_size,
                       self.vocab_size, self.num_layers)
+        q = self.num_heads * self.head_dim
         kv = self.num_kv_heads * self.head_dim
         per_layer = (
-            h * h + 2 * h * kv + h * h      # q, k, v, o projections
+            h * q + 2 * h * kv + q * h      # q, k, v, o projections
             + 3 * h * i                      # gate, up, down
             + 2 * h                          # 2 rmsnorm scales
+            + (2 * self.head_dim if self.qk_norm else 0)
         )
         emb = v * h * (1 if self.tie_embeddings else 2)
         return L * per_layer + emb + h
@@ -215,6 +222,30 @@ def dispatch_attention(impl: str, q, k, v, causal: bool = True):
     return out.transpose(0, 2, 1, 3)
 
 
+def project_qkv(cfg: LlamaConfig, x, tie, positions):
+    """q, k, v as (batch, seq, heads, head_dim), q and k normed by head
+    where the model says so (`qk_norm`) and rotated; called inside an
+    attention module's compact `__call__`, whose submodules these are."""
+    batch, seq, _ = x.shape
+    dense = functools_partial_dense(cfg)
+    q = dense("q_proj", (cfg.hidden_size, cfg.num_heads * cfg.head_dim),
+              ("embed", "heads"))(x, tie)
+    k = dense("k_proj", (cfg.hidden_size, cfg.num_kv_heads * cfg.head_dim),
+              ("embed", "kv"))(x, tie)
+    v = dense("v_proj", (cfg.hidden_size, cfg.num_kv_heads * cfg.head_dim),
+              ("embed", "kv"))(x, tie)
+    q = q.reshape(batch, seq, cfg.num_heads, cfg.head_dim)
+    k = k.reshape(batch, seq, cfg.num_kv_heads, cfg.head_dim)
+    v = v.reshape(batch, seq, cfg.num_kv_heads, cfg.head_dim)
+    if cfg.qk_norm:
+        q = RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.norm_impl,
+                    name="q_norm")(q)
+        k = RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.norm_impl,
+                    name="k_norm")(k)
+    return (apply_rope(q, positions, cfg.rope_theta),
+            apply_rope(k, positions, cfg.rope_theta), v)
+
+
 class Attention(nn.Module):
     config: LlamaConfig
 
@@ -225,20 +256,7 @@ class Attention(nn.Module):
         dense = functools_partial_dense(cfg)
         # the four weight gradients inside this module's backward
         x, tie = tie_weight_grads(x)
-        q = dense("q_proj", (cfg.hidden_size,
-                             cfg.num_heads * cfg.head_dim),
-                  ("embed", "heads"))(x, tie)
-        k = dense("k_proj", (cfg.hidden_size,
-                             cfg.num_kv_heads * cfg.head_dim),
-                  ("embed", "kv"))(x, tie)
-        v = dense("v_proj", (cfg.hidden_size,
-                             cfg.num_kv_heads * cfg.head_dim),
-                  ("embed", "kv"))(x, tie)
-        q = q.reshape(batch, seq, cfg.num_heads, cfg.head_dim)
-        k = k.reshape(batch, seq, cfg.num_kv_heads, cfg.head_dim)
-        v = v.reshape(batch, seq, cfg.num_kv_heads, cfg.head_dim)
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
+        q, k, v = project_qkv(cfg, x, tie, positions)
         impl = cfg.attn_impl
         sp_mesh = None
         if impl in ("ring", "ulysses"):
@@ -412,6 +430,7 @@ class Llama(nn.Module):
     whole (`head_cross_entropy`)."""
 
     config: LlamaConfig
+    _BLOCK = DecoderBlock       # a model of other blocks names its own
 
     @nn.compact
     def hidden_and_head(self, tokens: jax.Array):
@@ -428,10 +447,10 @@ class Llama(nn.Module):
             x = embed_lookup(embed, tokens, cfg)
         positions = jnp.broadcast_to(
             jnp.arange(tokens.shape[-1]), tokens.shape)
-        block_cls = DecoderBlock
+        block_cls = self._BLOCK
         if cfg.remat:
             block_cls = nn.remat(
-                DecoderBlock, static_argnums=(),
+                self._BLOCK, static_argnums=(),
                 policy=resolve_remat_policy(cfg.remat_policy),
             )
         for layer in range(cfg.num_layers):

@@ -69,6 +69,35 @@ def flops_per_token(param_count: float, num_layers: int = 0,
     return 6.0 * counted + attention
 
 
+def model_flops_per_token(config, param_count: float,
+                          seq_len: int) -> float:
+    """Model FLOPs per trained token of the model whose config this is.
+
+    A model that says which of its parameters a token multiplies and
+    which pairs its attention scores answers itself: a config with a
+    ``flops_per_token(seq_len)`` (``models/keye.py``: of the routed
+    experts the ones a token reaches, the selected pairs and not the
+    causal ones) is asked, and nothing is guessed. For any other config
+    the shape is read off attribute names (``num_layers``,
+    ``hidden_size``, ``embed_impl``, ``tie_embeddings``; none of them:
+    the bare 6 x params floor), every parameter counted as active."""
+    own = getattr(config, "flops_per_token", None)
+    if callable(own):
+        return float(own(seq_len))
+    # a gather-lookup embedding table with an untied head does no matmul:
+    # crediting it would report a higher MFU than the benchmark's own
+    # count gives the identical model
+    uncounted = 0.0
+    if (getattr(config, "embed_impl", "") == "gather"
+            and not getattr(config, "tie_embeddings", True)):
+        uncounted = (getattr(config, "vocab_size", 0)
+                     * getattr(config, "hidden_size", 0))
+    return flops_per_token(
+        param_count, num_layers=getattr(config, "num_layers", 0),
+        hidden_size=getattr(config, "hidden_size", 0), seq_len=seq_len,
+        uncounted_embed_params=uncounted)
+
+
 def achieved_mfu(tokens_per_second: float, flops_per_token_: float,
                  peak_flops_total: float) -> float:
     """Achieved / peak model-FLOPs utilization; -1.0 when the FLOPs

@@ -117,29 +117,43 @@ class StepsInFlight:
         # the first dispatch (the device starts working then)
         self._mark: Optional[Tuple[float, int]] = None
         self._last_done_at = 0.0
+        # what finished steps counted of themselves (`take_counted`)
+        self._counted: list = []
 
     def __len__(self) -> int:
         return len(self._pending)
 
-    def dispatched(self, handle: Any) -> None:
+    def dispatched(self, handle: Any,
+                   counted: Optional[Dict[str, Any]] = None) -> None:
+        """``counted``: further scalar outputs of the same step (a model's
+        counters among the step's metrics), read once the step is seen
+        done: never a wait, their program has finished."""
         if self._mark is None:
             self._mark = (self._clock(), 0)
-        self._pending.append(handle)
+        self._pending.append((handle, counted))
 
     def poll(self) -> int:
         """Pop every leading step that is done; returns how many."""
         done = 0
         pending = self._pending
         while pending:
-            is_ready = getattr(pending[0], "is_ready", None)
+            is_ready = getattr(pending[0][0], "is_ready", None)
             if is_ready is not None and not is_ready():
                 break
-            pending.popleft()
+            counted = pending.popleft()[1]
+            if counted:
+                self._counted.append(
+                    {name: float(value) for name, value in counted.items()})
             done += 1
         if done:
             self.completed += done
             self._last_done_at = self._clock()
         return done
+
+    def take_counted(self) -> list:
+        """The counters of the steps seen done since the last call."""
+        counted, self._counted = self._counted, []
+        return counted
 
     def drain_step_time(self) -> float:
         """Mean seconds per step over the completions seen since the
@@ -170,11 +184,19 @@ class LoopWindow:
         self._in_flight_sum = 0
         self._in_flight_max = 0
         self._samples = 0
+        # name -> (sum, steps) of what finished steps counted of themselves
+        self._counted: Dict[str, Tuple[float, int]] = {}
 
     def add(self, marks: StepMarks, completed: int, in_flight: int,
-            took_step: bool = True) -> None:
+            took_step: bool = True, counted=()) -> None:
         """One closed iteration. ``took_step`` False: the iteration
-        that found the data exhausted; its time counts, it is no step."""
+        that found the data exhausted; its time counts, it is no step.
+        ``counted``: `StepsInFlight.take_counted()`, one dict a step seen
+        done in this iteration."""
+        for step_counted in counted:
+            for name, value in step_counted.items():
+                total, steps = self._counted.get(name, (0.0, 0))
+                self._counted[name] = (total + value, steps + 1)
         self.steps += 1 if took_step else 0
         self.wall_s += marks.wall
         for name, value in marks.seconds.items():
@@ -197,4 +219,8 @@ class LoopWindow:
         out["in_flight_mean"] = (self._in_flight_sum / self._samples
                                  if self._samples else 0.0)
         out["in_flight_max"] = self._in_flight_max
+        # a model's counters: the mean over the steps seen done here
+        for name, (total, steps) in self._counted.items():
+            out[f"{name}_mean"] = total / steps
+            out[f"{name}_steps"] = steps
         return out

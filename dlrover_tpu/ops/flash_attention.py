@@ -85,6 +85,11 @@ _DIM_SEMANTICS = pltpu.CompilerParams(
 KERNEL_FWD = "flash_attn_fwd"
 KERNEL_DQ = "flash_attn_dq"
 KERNEL_DKV = "flash_attn_dkv"
+# the same three kernels given a selection mask (`mask=`): attention over
+# the keys an indexer selected (ops/sparse_attention_kernels.py)
+KERNEL_SPARSE_FWD = "sparse_attn_fwd"
+KERNEL_SPARSE_DQ = "sparse_attn_dq"
+KERNEL_SPARSE_DKV = "sparse_attn_dkv"
 
 
 def _vma(*arrays) -> frozenset:
@@ -286,15 +291,34 @@ def _masked(s, tile: _Tile, q_start, k_start):
     return jnp.where(q_idx >= k_idx, s, NEG_INF)
 
 
+def _selected(s, tile: _Tile, mask_ref):
+    """A tile's scores with the keys the mask leaves out at NEG_INF; the
+    mask operand is (1, block_q, block_k) int8, nonzero = attended. It
+    applies in every computed tile, beside the causal one. No mask: `s`."""
+    if mask_ref is None:
+        return s
+    return jnp.where(mask_ref[0, tile.rows, tile.cols] != 0, s, NEG_INF)
+
+
 # ===========================================================================
 # Forward kernel
 # ===========================================================================
 
 
+def _with_mask(kernel, operands: int):
+    """`kernel` for a call whose operand after the first `operands` is the
+    selection mask: the kernels take it by keyword."""
+    def masked(*refs, **static):
+        return kernel(*refs[:operands], *refs[operands + 1:],
+                      mask_ref=refs[operands], **static)
+    return masked
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                 acc_ref, m_ref, l_ref,
                 *, sm_scale: float, causal: bool,
-                block_q: int, block_k: int, num_k_blocks: int):
+                block_q: int, block_k: int, num_k_blocks: int,
+                mask_ref=None):
     ki = pl.program_id(3)
 
     @pl.when(ki == 0)
@@ -315,7 +339,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         k = k_ref[0, 0, tile.cols]
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * (
             sm_scale * LOG2E)
-        return _masked(s, tile, q_start, k_start)
+        return _selected(_masked(s, tile, q_start, k_start), tile, mask_ref)
 
     def _update(tile, s):
         # one online-softmax update of the tile's rows
@@ -325,6 +349,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         m_cur = jnp.max(s, axis=1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
         p = jnp.exp2(s - m_new)
+        if mask_ref is not None:
+            # a row may have no selected key yet (under the causal mask
+            # alone key 0 serves every row): NEG_INF - NEG_INF is 0, not
+            # -inf, so its masked scores must not count as exp2(0)
+            p = jnp.where(s > 0.5 * NEG_INF, p, 0.0)
         alpha = jnp.exp2(m_prev - m_new)
         l_ref[rows] = l_ref[rows] * alpha + jnp.sum(p, axis=1, keepdims=True)
         acc_ref[rows] = acc_ref[rows] * alpha + jnp.dot(
@@ -345,11 +374,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 
 def _flash_fwd(q, k, v, sm_scale: float, causal: bool,
-               block_q: int, block_k: int):
+               block_q: int, block_k: int, mask=None):
     """(out, lse) of one forward launch; blocks are fitted here, so that
-    every request for the same fitted blocks shares one traced kernel."""
+    every request for the same fitted blocks shares one traced kernel.
+    `mask` (batch, seq_q, seq_k) int8, nonzero = attended: the softmax
+    runs over those keys alone (every head the same), under the kernels'
+    `sparse_attn_*` names."""
     return _flash_fwd_call(
-        q, k, v, sm_scale=sm_scale, causal=causal,
+        q, k, v, mask, sm_scale=sm_scale, causal=causal,
         block_q=fit_block(q.shape[2], block_q),
         block_k=fit_block(k.shape[2], block_k),
         interpret=not on_tpu())
@@ -362,7 +394,7 @@ def _flash_fwd(q, k, v, sm_scale: float, causal: bool,
 # read inside, because the trace is cached across backends.
 @functools.partial(jax.jit, static_argnames=(
     "sm_scale", "causal", "block_q", "block_k", "interpret"))
-def _flash_fwd_call(q, k, v, *, sm_scale: float, causal: bool,
+def _flash_fwd_call(q, k, v, mask=None, *, sm_scale: float, causal: bool,
                     block_q: int, block_k: int, interpret: bool):
     batch, num_heads, seq_q, head_dim = q.shape
     _, num_kv_heads, seq_k, _ = k.shape
@@ -387,9 +419,16 @@ def _flash_fwd_call(q, k, v, *, sm_scale: float, causal: bool,
         return (b, h, qi, 0)
 
     kernel = functools.partial(
-        _fwd_kernel, sm_scale=sm_scale, causal=causal,
+        _fwd_kernel if mask is None else _with_mask(_fwd_kernel, 3),
+        sm_scale=sm_scale, causal=causal,
         block_q=block_q, block_k=block_k, num_k_blocks=num_k_blocks,
     )
+    operands, masked = (q, k, v), []
+    if mask is not None:
+        operands += (mask,)
+        masked = [pl.BlockSpec(
+            (1, block_q, block_k),
+            lambda b, h, qi, ki: (b, qi, kv_map(b, h, qi, ki)[2]))]
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
@@ -397,7 +436,7 @@ def _flash_fwd_call(q, k, v, *, sm_scale: float, causal: bool,
             pl.BlockSpec((1, 1, block_q, head_dim), q_map),
             pl.BlockSpec((1, 1, block_k, head_dim), kv_map),
             pl.BlockSpec((1, 1, block_k, head_dim), kv_map),
-        ],
+        ] + masked,
         out_specs=[
             pl.BlockSpec((1, 1, block_q, head_dim), o_map),
             pl.BlockSpec((1, 1, block_q, 1),
@@ -414,8 +453,8 @@ def _flash_fwd_call(q, k, v, *, sm_scale: float, causal: bool,
         ],
         compiler_params=_DIM_SEMANTICS,
         interpret=interpret,
-        name=KERNEL_FWD,
-    )(q, k, v)
+        name=KERNEL_FWD if mask is None else KERNEL_SPARSE_FWD,
+    )(*operands)
     return out, lse
 
 
@@ -425,7 +464,7 @@ def _flash_fwd_call(q, k, v, *, sm_scale: float, causal: bool,
 
 
 def _bwd_scores(tile, *, q_ref, k_ref, v_ref, do_ref, sm_scale: float,
-                q_start, k_start):
+                q_start, k_start, mask_ref=None):
     """Stage one of both backward kernels: a tile's scores (exp2 domain,
     masked) and dP = dO V^T, the matmuls that wait on nothing."""
     q = q_ref[0, 0, tile.rows]
@@ -435,13 +474,14 @@ def _bwd_scores(tile, *, q_ref, k_ref, v_ref, do_ref, sm_scale: float,
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * (
         sm_scale * LOG2E)
     dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-    return _masked(s, tile, q_start, k_start), dp
+    return _selected(_masked(s, tile, q_start, k_start), tile, mask_ref), dp
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                    dq_ref, dq_acc_ref,
                    *, sm_scale: float, causal: bool,
-                   block_q: int, block_k: int, num_k_blocks: int):
+                   block_q: int, block_k: int, num_k_blocks: int,
+                   mask_ref=None):
     ki = pl.program_id(3)
 
     @pl.when(ki == 0)
@@ -454,7 +494,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     _scores = functools.partial(
         _bwd_scores, q_ref=q_ref, k_ref=k_ref, v_ref=v_ref, do_ref=do_ref,
-        sm_scale=sm_scale, q_start=q_start, k_start=k_start)
+        sm_scale=sm_scale, q_start=q_start, k_start=k_start,
+        mask_ref=mask_ref)
 
     def _update(tile, scores):
         rows = tile.rows
@@ -476,7 +517,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_acc_ref, dv_acc_ref,
                     *, sm_scale: float, causal: bool,
-                    block_q: int, block_k: int, num_q_blocks: int):
+                    block_q: int, block_k: int, num_q_blocks: int,
+                    mask_ref=None):
     qi = pl.program_id(3)
 
     @pl.when(qi == 0)
@@ -490,7 +532,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     _scores = functools.partial(
         _bwd_scores, q_ref=q_ref, k_ref=k_ref, v_ref=v_ref, do_ref=do_ref,
-        sm_scale=sm_scale, q_start=q_start, k_start=k_start)
+        sm_scale=sm_scale, q_start=q_start, k_start=k_start,
+        mask_ref=mask_ref)
 
     def _update(tile, scores):
         rows, cols = tile.rows, tile.cols
@@ -517,13 +560,13 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _flash_bwd(res, g, *, sm_scale: float, causal: bool,
-               block_q: int, block_k: int, delta=None):
+               block_q: int, block_k: int, delta=None, mask=None):
     """(dq, dk, dv) of one backward launch pair. delta = rowsum(dO·O) may
     be passed precomputed — ring callers invoke this once per visiting KV
-    block with step-invariant dO/O."""
+    block with step-invariant dO/O. `mask`: the forward's (`_flash_fwd`)."""
     q, k = res[0], res[1]
     return _flash_bwd_call(
-        res, g, delta, sm_scale=sm_scale, causal=causal,
+        res, g, delta, mask, sm_scale=sm_scale, causal=causal,
         block_q=fit_block(q.shape[2], block_q),
         block_k=fit_block(k.shape[2], block_k),
         interpret=not on_tpu())
@@ -531,8 +574,9 @@ def _flash_bwd(res, g, *, sm_scale: float, causal: bool,
 
 @functools.partial(jax.jit, static_argnames=(
     "sm_scale", "causal", "block_q", "block_k", "interpret"))
-def _flash_bwd_call(res, do, delta, *, sm_scale: float, causal: bool,
-                    block_q: int, block_k: int, interpret: bool):
+def _flash_bwd_call(res, do, delta, mask=None, *, sm_scale: float,
+                    causal: bool, block_q: int, block_k: int,
+                    interpret: bool):
     q, k, v, out, lse = res
     batch, num_heads, seq_q, head_dim = q.shape
     _, num_kv_heads, seq_k, _ = k.shape
@@ -559,9 +603,16 @@ def _flash_bwd_call(res, do, delta, *, sm_scale: float, causal: bool,
 
     # ---- dq: iterate kv blocks innermost -----------------------------
     dq_kernel = functools.partial(
-        _bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
+        _bwd_dq_kernel if mask is None else _with_mask(_bwd_dq_kernel, 6),
+        sm_scale=sm_scale, causal=causal,
         block_q=block_q, block_k=block_k, num_k_blocks=num_k_blocks,
     )
+    operands, masked = (q, k, v, do, lse, delta), []
+    if mask is not None:
+        operands += (mask,)
+        masked = [pl.BlockSpec(
+            (1, block_q, block_k),
+            lambda b, h, qi, ki: (b, qi, kv_map(b, h, qi, ki)[2]))]
     dq = pl.pallas_call(
         dq_kernel,
         grid=(batch, num_heads, num_q_blocks, num_k_blocks),
@@ -572,14 +623,14 @@ def _flash_bwd_call(res, do, delta, *, sm_scale: float, causal: bool,
             pl.BlockSpec((1, 1, block_q, head_dim), q_map),
             pl.BlockSpec((1, 1, block_q, 1), row_map),
             pl.BlockSpec((1, 1, block_q, 1), row_map),
-        ],
+        ] + masked,
         out_specs=pl.BlockSpec((1, 1, block_q, head_dim), q_map),
         out_shape=_sds(q.shape, q.dtype, _vma(q, k, v, do)),
         scratch_shapes=[pltpu.VMEM((block_q, head_dim), jnp.float32)],
         compiler_params=_DIM_SEMANTICS,
         interpret=interpret,
-        name=KERNEL_DQ,
-    )(q, k, v, do, lse, delta)
+        name=KERNEL_DQ if mask is None else KERNEL_SPARSE_DQ,
+    )(*operands)
 
     # ---- dk/dv: per q-head contributions, iterate q blocks innermost --
     # Grid runs over *query* heads so GQA contributions are disjoint per
@@ -613,9 +664,14 @@ def _flash_bwd_call(res, do, delta, *, sm_scale: float, causal: bool,
         return (b, h, _qi_eff(ki, qi), 0)
 
     dkv_kernel = functools.partial(
-        _bwd_dkv_kernel, sm_scale=sm_scale, causal=causal,
+        _bwd_dkv_kernel if mask is None else _with_mask(_bwd_dkv_kernel, 6),
+        sm_scale=sm_scale, causal=causal,
         block_q=block_q, block_k=block_k, num_q_blocks=num_q_blocks,
     )
+    if mask is not None:
+        masked = [pl.BlockSpec(
+            (1, block_q, block_k),
+            lambda b, h, ki, qi: (b, _qi_eff(ki, qi), ki))]
     dk_per_qh, dv_per_qh = pl.pallas_call(
         dkv_kernel,
         grid=(batch, num_heads, num_k_blocks, num_q_blocks),
@@ -626,7 +682,7 @@ def _flash_bwd_call(res, do, delta, *, sm_scale: float, causal: bool,
             pl.BlockSpec((1, 1, block_q, head_dim), q_map2),
             pl.BlockSpec((1, 1, block_q, 1), row_map2),
             pl.BlockSpec((1, 1, block_q, 1), row_map2),
-        ],
+        ] + masked,
         out_specs=[
             pl.BlockSpec((1, 1, block_k, head_dim), kv_out_map),
             pl.BlockSpec((1, 1, block_k, head_dim), kv_out_map),
@@ -643,8 +699,8 @@ def _flash_bwd_call(res, do, delta, *, sm_scale: float, causal: bool,
         ],
         compiler_params=_DIM_SEMANTICS,
         interpret=interpret,
-        name=KERNEL_DKV,
-    )(q, k, v, do, lse, delta)
+        name=KERNEL_DKV if mask is None else KERNEL_SPARSE_DKV,
+    )(*operands)
 
     if group > 1:
         dk = dk_per_qh.reshape(

@@ -193,3 +193,159 @@ def moe_aux_loss(variables) -> jax.Array:
     for leaf in jax.tree.leaves(losses):
         total = total + jnp.sum(leaf)
     return total
+
+
+def sown_counters(variables) -> dict:
+    """name -> mean over whoever sowed it, of a model's `counters`
+    collection: what a step counts of itself (an expert layer's load) and
+    hands to the step's metrics. Empty for a model that sows none."""
+    by_name: dict = {}
+    for path, leaf in jax.tree_util.tree_flatten_with_path(
+            variables.get("counters", {}))[0]:
+        names = [str(getattr(k, "key", k)) for k in path
+                 if isinstance(getattr(k, "key", None), str)]
+        by_name.setdefault(names[-1], []).append(jnp.mean(leaf))
+    return {name: jnp.mean(jnp.stack(values))
+            for name, values in by_name.items()}
+
+
+# ===========================================================================
+# Experts held by share: nothing dropped, no capacity
+# ===========================================================================
+# The layer one chip of an expert-parallel group runs: it is told how many
+# experts the model has, how many it holds and which is its first, routes
+# every token over ALL of them, sorts the token-expert assignments by
+# expert, multiplies the rows of the experts it holds by grouped matmuls
+# (`jax.lax.ragged_dot`; rows of absent experts are not multiplied) and adds
+# its own experts' weighted outputs. What the absent experts would add is
+# left out: on a mesh that is the other chips' part, and no code here stands
+# in for them or for their exchange. The capacity-based `MoELayer` above
+# stays for `models/llama_moe.py` until that model runs on this layer
+# (ROADMAP R8).
+
+@dataclasses.dataclass(frozen=True)
+class HeldExpertsConfig:
+    num_experts: int = 8            # the model's, the router's width
+    experts_held: int = 8           # held here: first_expert ... + held
+    first_expert: int = 0
+    top_k: int = 2
+    hidden_size: int = 512
+    expert_intermediate: int = 1024
+    norm_topk_prob: bool = True     # the k gates renormalised to sum 1
+    dtype: Any = jnp.float32
+    param_dtype: Any = jnp.float32
+
+
+def route_top_k(router_logits: jax.Array, top_k: int,
+                norm_topk_prob: bool) -> Tuple[jax.Array, jax.Array]:
+    """(gates (T, k) float32, experts (T, k) int32): softmax over every
+    expert in float32, the k largest, renormalised where the model says."""
+    probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
+    gates, experts = jax.lax.top_k(probs, top_k)
+    if norm_topk_prob:
+        gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+    return gates, experts.astype(jnp.int32)
+
+
+@jax.custom_vjp
+def permute_rows(x: jax.Array, perm: jax.Array, inverse: jax.Array):
+    """`x[perm]` for a permutation and its inverse: the cotangent goes
+    back by a gather through the inverse, not by a scatter that cannot
+    know its indices are distinct."""
+    return x[perm]
+
+
+def _permute_rows_fwd(x, perm, inverse):
+    return x[perm], (perm, inverse)
+
+
+def _permute_rows_bwd(res, g):
+    return g[res[1]], None, None
+
+
+permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
+
+
+def held_assignments(experts: jax.Array, first_expert: int,
+                     experts_held: int):
+    """Sort the (T, k) assignments by expert, held experts first in their
+    order and every absent expert after them. Returns (held (T k,) bool,
+    which assignments are of a held expert; order (T k,), the flat
+    assignment at each sorted row; sizes (held,), the rows of each held
+    expert)."""
+    local = experts.reshape(-1) - first_expert
+    held = (local >= 0) & (local < experts_held)
+    group = jnp.where(held, local, experts_held)
+    order = jnp.argsort(group, stable=True)
+    sizes = jnp.sum(group[:, None] == jnp.arange(experts_held)[None, :],
+                    axis=0, dtype=jnp.int32)
+    return held, order, sizes
+
+
+class HeldExpertsLayer(nn.Module):
+    """(..., S, H) -> (..., S, H): the held experts' part of a top-k
+    mixture of SwiGLU experts, `w2(silu(w1 x) * w3 x)`. Leaves: `router`
+    (H, E) float32 and three stacks of three axes, `w1`, `w3` (held, H, I)
+    and `w2` (held, I, H). Sows two counters into `counters`:
+    `moe_load_max_over_mean` (the fullest held expert's rows over the mean)
+    and `moe_held_rows_share` (the rows of the held experts over every
+    assignment, tokens x k: `experts_held / num_experts` under an even
+    routing, and what the grouped products' needed work follows)."""
+
+    cfg: HeldExpertsConfig
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        cfg = self.cfg
+        hidden, held_n, k = cfg.hidden_size, cfg.experts_held, cfg.top_k
+        normal = nn.initializers.normal(0.02)
+        router = self.param(
+            "router", nn.with_logical_partitioning(normal, ("embed", None)),
+            (hidden, cfg.num_experts), jnp.float32)
+        stacks = {}
+        for name, shape, axes in (
+                ("w1", (held_n, hidden, cfg.expert_intermediate),
+                 ("expert", "embed", "mlp")),
+                ("w3", (held_n, hidden, cfg.expert_intermediate),
+                 ("expert", "embed", "mlp")),
+                ("w2", (held_n, cfg.expert_intermediate, hidden),
+                 ("expert", "mlp", "embed"))):
+            stacks[name] = self.param(
+                name, nn.with_logical_partitioning(normal, axes), shape,
+                cfg.param_dtype).astype(cfg.dtype)
+        tokens = x.reshape(-1, hidden)
+        # the router in float32 whatever the backend's default for it
+        logits = jnp.dot(tokens.astype(jnp.float32), router,
+                         precision=jax.lax.Precision.HIGHEST)
+        gates, experts = route_top_k(logits, k, cfg.norm_topk_prob)
+        held, order, sizes = held_assignments(experts, cfg.first_expert,
+                                              held_n)
+        self.sow("counters", "moe_load_max_over_mean",
+                 jnp.max(sizes) * held_n / jnp.maximum(jnp.sum(sizes), 1))
+        self.sow("counters", "moe_held_rows_share",
+                 jnp.sum(sizes) / held.shape[0])
+        # a buffer for the worst case, every assignment on a held expert;
+        # the grouped products multiply the held experts' rows only (the
+        # first `sum(sizes)`), whatever the routing made of them
+        back = jnp.argsort(order)
+        rows = permute_rows(jnp.repeat(tokens.astype(cfg.dtype), k, axis=0),
+                            order, back)
+        written = (jnp.arange(order.shape[0]) < jnp.sum(sizes))[:, None]
+
+        def grouped(lhs, stack):
+            # a row past the held ones is never written, forward or
+            # backward (the chip leaves what was there: not a zero, maybe
+            # not a number), so it is selected out on both sides and its
+            # cotangent is a zero in either direction
+            return jnp.where(written, jax.lax.ragged_dot(
+                jnp.where(written, lhs, 0), stack, sizes), 0)
+
+        act = nn.silu(grouped(rows, stacks["w1"])) * grouped(rows,
+                                                             stacks["w3"])
+        out = grouped(act, stacks["w2"])
+        # each token's k rows back beside each other, weighted and summed;
+        # a row of an absent expert takes no weight
+        out = permute_rows(out, back, order).astype(jnp.float32)
+        out = out * jnp.where(held, gates.reshape(-1), 0.0)[:, None]
+        mixed = jnp.sum(out.reshape(-1, k, hidden), axis=1)
+        return mixed.reshape(x.shape).astype(x.dtype)

@@ -641,9 +641,10 @@ class ElasticTrainLoop:
     def _report_model_info(self, model=None) -> None:
         """One-shot static stats to the master's resource optimizer
         (reference: profile_extractor → ModelInfo) plus the FLOPs model
-        behind every MFU number (obs/mfu.py): analytic 6·params with
-        the causal attention term when the model config exposes its
-        shape, cross-checked later against the compiled step's XLA cost
+        behind every MFU number (obs/mfu.py:model_flops_per_token): the
+        model's own count where its config gives one, else analytic
+        6·params with the causal attention term when the config exposes
+        its shape; cross-checked later against the compiled step's XLA cost
         analysis (_maybe_cross_check_flops)."""
         try:
             abstract = self.trainer.abstract_state(jax.random.PRNGKey(0))
@@ -655,21 +656,14 @@ class ElasticTrainLoop:
             cfg = getattr(model, "config", None)
             self._param_count = param_count
             self._param_bytes = param_bytes
-            # a gather-lookup embedding table with an untied head does
-            # no matmul — crediting it would report a higher MFU than
-            # the benchmark's own count gives the identical model
-            uncounted = 0.0
-            if (getattr(cfg, "embed_impl", "") == "gather"
-                    and not getattr(cfg, "tie_embeddings", True)):
-                uncounted = (getattr(cfg, "vocab_size", 0)
-                             * getattr(cfg, "hidden_size", 0))
-            self._flops_per_token = obs.mfu.flops_per_token(
-                param_count,
-                num_layers=getattr(cfg, "num_layers", 0),
-                hidden_size=getattr(cfg, "hidden_size", 0),
-                seq_len=self.config.seq_len,
-                uncounted_embed_params=uncounted,
-            )
+            # the model is asked where it can answer (active experts,
+            # selected pairs); else its shape is read off attribute names
+            self._flops_per_token = obs.mfu.model_flops_per_token(
+                cfg, param_count, self.config.seq_len)
+            # a model's own count stands: XLA's cost analysis sees no
+            # kernel's FLOPs and counts a recomputed block twice
+            self._flops_from_model = callable(
+                getattr(cfg, "flops_per_token", None))
             # the chips THIS loop trains on: the mesh, which is every
             # device unless the caller handed the loop a subset
             device = self.mesh.devices.flat[0]
@@ -746,7 +740,7 @@ class ElasticTrainLoop:
         tokens_per_step = self.global_batch * self.config.seq_len
         adopted = obs.mfu.cross_check(self._flops_per_token, measured,
                                       tokens_per_step)
-        if adopted is None:
+        if adopted is None or getattr(self, "_flops_from_model", False):
             return
         logger.warning(
             "FLOPs model cross-check: analytic %.3e/token vs XLA cost "
@@ -1051,7 +1045,10 @@ class ElasticTrainLoop:
                 # completion accounting: one scalar of this step's
                 # outputs joins the queue, and whatever the device has
                 # finished meanwhile leaves it (is_ready, never a wait)
-                flight.dispatched(next(iter(raw_metrics.values()), None))
+                flight.dispatched(
+                    next(iter(raw_metrics.values()), None),
+                    {name: value for name, value in raw_metrics.items()
+                     if name not in ("loss", "grad_norm")})
                 completed = flight.poll()
                 # scripted fault injection (no-op unless
                 # DLROVER_TPU_CHAOS)
@@ -1098,7 +1095,8 @@ class ElasticTrainLoop:
                         self._report_progress(step)
                         self._flush_telemetry()
                 boundary = marks.close()
-                window.add(marks, completed, len(flight))
+                window.add(marks, completed, len(flight),
+                           counted=flight.take_counted())
                 if due:
                     self._emit_train_window(window)
                     window = obs.LoopWindow(first_step=step + 1)

@@ -23,7 +23,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from dlrover_tpu.common.constants import MeshAxis, TraceScope
 from dlrover_tpu.common.log import default_logger
 from dlrover_tpu.parallel.mesh import data_axes, dp_size, use_mesh
-from dlrover_tpu.parallel.moe import moe_aux_loss
+from dlrover_tpu.parallel.moe import moe_aux_loss, sown_counters
 from dlrover_tpu.parallel.sharding import (
     DEFAULT_RULES,
     mesh_shardings,
@@ -388,35 +388,40 @@ def build_trainer(
                 # (MoE router balancing, parallel/moe.py:172); for models
                 # that never sow, the collection is empty and the sum is
                 # 0 — one generic path covers both
+                # `counters`: what a model counts of a step (an expert
+                # layer's load) and wants among the step's metrics, each
+                # name averaged over whoever sowed it; empty likewise
                 if fused:
                     (hidden, head), mutables = model.apply(
-                        {"params": p}, tok, mutable=["losses"], rngs=rngs,
-                        method="hidden_and_head")
+                        {"params": p}, tok, mutable=["losses", "counters"],
+                        rngs=rngs, method="hidden_and_head")
                     loss = fused_loss(hidden, head, tgt, head_slices)
                 else:
                     logits, mutables = model.apply(
-                        {"params": p}, tok, mutable=["losses"], rngs=rngs)
+                        {"params": p}, tok, mutable=["losses", "counters"],
+                        rngs=rngs)
                     # the model's final norm and head open the same scope
                     # (models/llama.py): head + loss read as one in a trace
                     with jax.named_scope(TraceScope.HEAD_LOSS):
                         loss = loss_fn(logits, tgt)
-                return loss + moe_aux_loss(mutables)
+                return loss + moe_aux_loss(mutables), sown_counters(mutables)
 
-            loss, grads = jax.value_and_grad(compute_loss)(params)
+            (loss, counted), grads = jax.value_and_grad(
+                compute_loss, has_aux=True)(params)
             grad_acc = jax.tree.map(
                 lambda a, g: a + g.astype(jnp.float32), grad_acc, grads
             )
-            return (loss_acc + loss, grad_acc), None
+            return (loss_acc + loss, grad_acc), counted
 
         zero_grads = jax.tree.map(
             lambda p: jnp.zeros(p.shape, jnp.float32), params
         )
         with jax.named_scope(TraceScope.GRAD_ACCUM):
-            (loss_sum, grad_sum), _ = jax.lax.scan(
+            (loss_sum, grad_sum), counted = jax.lax.scan(
                 micro_step, (jnp.zeros((), jnp.float32), zero_grads),
                 (tokens, targets, jnp.arange(accum_steps)),
             )
-        return loss_sum, grad_sum
+        return loss_sum, grad_sum, jax.tree.map(jnp.mean, counted)
 
     def _apply_body(state: TrainState, grads):
         """Optimizer update from already-reduced grads (param dtype):
@@ -431,7 +436,7 @@ def build_trainer(
 
     def _train_step_body(state: TrainState, tokens, targets,
                          grad_reduce=None):
-        loss_sum, grad_sum = _accumulate(state, tokens, targets)
+        loss_sum, grad_sum, counted = _accumulate(state, tokens, targets)
         if grad_reduce is not None:
             # explicit (possibly quantized) mean over the manual reduce
             # axis — the cross-slice half of the hierarchical sync; the
@@ -448,6 +453,7 @@ def build_trainer(
         metrics = {
             "loss": loss_sum / accum_steps,
             "grad_norm": grad_norm,
+            **counted,
         }
         return new_state, metrics
 
@@ -523,7 +529,8 @@ def build_trainer(
         # grad_fn must NOT donate the state: apply_fn still reads it.
         def _grad_only(state, tokens, targets):
             with use_mesh(mesh), nn.logical_axis_rules(rules):
-                loss_sum, grad_sum = _accumulate(state, tokens, targets)
+                loss_sum, grad_sum, _ = _accumulate(state, tokens,
+                                                    targets)
                 grads = jax.tree.map(
                     lambda g, p: (g / accum_steps).astype(p.dtype),
                     grad_sum, state.params)

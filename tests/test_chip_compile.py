@@ -97,6 +97,65 @@ def test_flash_attention_fwd_bwd(topo, one_chip, chip_path, kv_heads):
     assert text.count("tpu_custom_call") >= 3
 
 
+SPARSE = dict(heads=32, kv_heads=4, seq=16384, d=128, index_heads=16,
+              index_dim=64, topk=2048)      # Keye-VL-2.0's, batch 1
+
+
+@pytest.mark.parametrize("kernel", ["indexer_select", "sparse_attn",
+                                    "indexer_kl", "indexer_dq_dk"])
+def test_sparse_attention_kernels_at_the_published_widths(
+        topo, one_chip, chip_path, kernel):
+    """Selection, masked attention (forward and both backward kernels), the
+    KL kernel and its two gradient kernels at 16,384 x 16,384 with 32 heads
+    of 128 and 16 index heads of 64: the VMEM each asks for (a row block's
+    scores for the bisection, a 1024 x 1024 float32 sum over the heads) and
+    every slice of them is the chip compiler's to refuse."""
+    import importlib
+
+    kernels = importlib.import_module(
+        "dlrover_tpu.ops.sparse_attention_kernels")
+
+    def of(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    s = SPARSE
+    seq = s["seq"]
+    q = of((1, s["heads"], seq, s["d"]))
+    kv = of((1, s["kv_heads"], seq, s["d"]))
+    qi = of((1, s["index_heads"], seq, s["index_dim"]))
+    ki = of((1, seq, s["index_dim"]))
+    w = of((1, seq, s["index_heads"]), jnp.float32)
+    mask = of((1, seq, seq), jnp.int8)
+    rows = of((1, seq, 1), jnp.float32)
+    if kernel == "indexer_select":
+        text = _compiled_text(
+            lambda qi, ki, w: kernels._select_call(
+                qi, ki, w, topk=s["topk"], interpret=False), qi, ki, w)
+        names = (kernels.KERNEL_SELECT,)
+    elif kernel == "sparse_attn":
+        def loss(q, k, v, mask):
+            return jnp.sum(kernels.masked_attention(
+                q, k, v, mask, 0.088)[0].astype(jnp.float32))
+        text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv,
+                              mask)
+        names = ("sparse_attn_fwd", "sparse_attn_dq", "sparse_attn_dkv")
+        assert "flash_attn_" not in text
+    elif kernel == "indexer_kl":
+        text = _compiled_text(
+            lambda *a: kernels._kl_call(*a, sm_scale=0.088, interpret=False),
+            q, kv, of((1, s["heads"], seq, 1), jnp.float32), mask, qi, w, ki,
+            rows)
+        names = (kernels.KERNEL_KL,)
+    else:
+        text = _compiled_text(
+            lambda *a: kernels._grad_call(*a, interpret=False),
+            of((1, seq, seq)), qi, w, ki)
+        names = (kernels.KERNEL_DQ, kernels.KERNEL_DK)
+    for name in names:
+        assert re.search(rf"%{name}[.\d]* = ", text), name
+    assert text.count("tpu_custom_call") >= len(names)
+
+
 @pytest.mark.parametrize("hidden", [2048, 4096])
 def test_fused_rms_norm_fwd_bwd(topo, one_chip, chip_path, hidden):
     x = jax.ShapeDtypeStruct((2, 2048, hidden), jnp.bfloat16,

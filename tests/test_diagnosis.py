@@ -386,7 +386,13 @@ class TestDiagnosisManager:
         for step in range(1, 6):
             monitor.collect_worker_step(0, step, step_time_s=0.1)
             monitor.collect_worker_step(1, step, step_time_s=0.5)
-        return DiagnosisManager(monitor)
+        manager = DiagnosisManager(monitor)
+        # the monitor's steps/s is wall-clock between collect calls made
+        # microseconds apart: the throughput rule fires on that noise once
+        # in some thirty runs, and is not what these tests are about
+        manager._rules = [r for r in manager._rules
+                          if r.name != "throughput_collapse"]
+        return manager
 
     def test_action_queue_cooldown_and_single_delivery(self, diag_ctx):
         manager = self._manager_with_straggler(diag_ctx)

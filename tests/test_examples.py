@@ -18,6 +18,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TRAIN = os.path.join(REPO, "examples", "nanogpt", "train.py")
 TRAIN_LONGCTX = os.path.join(REPO, "examples", "longcontext", "train.py")
 TRAIN_MOE = os.path.join(REPO, "examples", "moe", "train.py")
+TRAIN_SPARSE_MOE = os.path.join(REPO, "examples", "sparse_moe", "train.py")
 
 
 def run_cli(tmp_path, extra, timeout=240, script=TRAIN):
@@ -220,3 +221,19 @@ def test_streaming_standalone_trains_and_resumes(tmp_path):
     lines = open(log2).read()
     assert "start_step=4" in lines
     assert "done step=6" in lines
+
+
+def test_sparse_moe_tiny_preset_standalone(tmp_path):
+    """`models/keye.py`'s tiny preset through the real CLI: attention over
+    the keys an indexer selects, its KL term through the standard trainer's
+    `losses`, experts held by share with none dropped; the step's metrics
+    carry the expert layer's load counter."""
+    log = str(tmp_path / "run.log")
+    proc = run_cli(tmp_path, ["--steps", "4", "--log-file", log],
+                   script=TRAIN_SPARSE_MOE, timeout=360)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    lines = open(log).read()
+    assert "start_step=0" in lines and "held=4/8" in lines
+    assert "done step=4" in lines
+    load = float(lines.split("load=")[1].split()[0])
+    assert 1.0 <= load <= 4.0       # max over mean of four held experts

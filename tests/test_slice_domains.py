@@ -989,6 +989,9 @@ def test_slice_loss_acceptance_in_process(tmp_path):
                     30.0, "both slice worlds to form")
         mgr = master.rdzv_managers[RendezvousName.TRAINING]
         assert mgr.slice_status()["slices"]["1"]["generation"] == 1
+        # the world forms a moment before the agent spawns its worker
+        _wait_until(lambda: agents[2]._proc is not None, 10.0,
+                    "the survivor's worker to spawn")
         survivor_pid = agents[2]._proc.pid
         kill_ts = time.time()
 
