@@ -50,6 +50,9 @@ from dlrover_tpu.parallel.moe import HeldExpertsConfig, HeldExpertsLayer
 @dataclasses.dataclass(frozen=True)
 class KeyeConfig(LlamaConfig):
     qk_norm: bool = True
+    # with `remat`: the block's recomputation keeps what the attention's
+    # kernels made (`ops/remat.py:Kept`) and recomputes the rest
+    remat_policy: str = "kernel_outputs"
     # the mixture: `intermediate_size` is not used by this model's blocks
     num_experts: int = 128
     experts_held: int = 128          # this chip's, from first_expert on

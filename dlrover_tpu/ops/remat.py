@@ -1,12 +1,29 @@
 """Named rematerialization policies (consumed by model configs and
 the checkpoint/remat optimization; reference analog: atorch
-activation_checkpointing.py policy selection)."""
+activation_checkpointing.py policy selection), and the names under which
+a block tags what its recomputation keeps."""
+
+
+class Kept:
+    """`jax.ad_checkpoint.checkpoint_name`s of arrays that a kernel made and
+    that policy ``kernel_outputs`` keeps across a block's recomputation, so
+    the kernel is not launched a second time in the backward pass. The tag
+    sits where the array is made, inside a `custom_vjp`'s forward rule where
+    it is a residual too (docs/observability.md)."""
+
+    SELECTION = "indexer_selection"     # the int8 mask and its row log-sum-exp
+    ATTENTION = "sparse_attn_out"       # the attention's output and log-sum-exp
+    KL_GRADS = "indexer_kl_grads"       # what the KL term's backward reads
+
+    ALL = (SELECTION, ATTENTION, KL_GRADS)
 
 
 def resolve_remat_policy(name: str):
     """Named rematerialization policy → jax.checkpoint_policies member.
     "full"/"nothing_saveable" recomputes everything; "dots"/"dots_saveable"
-    keeps matmul outputs (cheaper backward, more memory)."""
+    keeps matmul outputs (cheaper backward, more memory); "kernel_outputs"
+    keeps what the block tagged with a name of `Kept` and recomputes the
+    rest: `nothing_saveable` for a block that tags nothing."""
     import jax
 
     policies = {
@@ -17,10 +34,10 @@ def resolve_remat_policy(name: str):
         "dots_saveable": jax.checkpoint_policies.dots_saveable,
         "dots_with_no_batch_dims":
             jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+        "kernel_outputs":
+            jax.checkpoint_policies.save_only_these_names(*Kept.ALL),
     }
     if name not in policies:
         raise ValueError(f"unknown remat policy {name!r}; "
                          f"choose from {sorted(policies)}")
     return policies[name]
-
-

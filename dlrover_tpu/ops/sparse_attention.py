@@ -40,9 +40,11 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from dlrover_tpu.common.constants import TraceScope
 from dlrover_tpu.ops.backend import on_tpu
+from dlrover_tpu.ops.remat import Kept
 
 NEG_INF = -1e30
 
@@ -103,15 +105,20 @@ def _xla_attention(q, k, v, mask, sm_scale):
 
 
 def _xla_forward(q, k, v, qi, ki, w, topk, sm_scale):
+    # tagged where the kernel form tags (`ops/remat.py:Kept`): a block's
+    # recomputation keeps the same three things in either form
     detach = jax.lax.stop_gradient
     with jax.named_scope(TraceScope.INDEXER):
         scores = index_scores(qi, ki, w)
-        mask = select_keys(detach(scores), topk)
+        mask = checkpoint_name(select_keys(detach(scores), topk),
+                               Kept.SELECTION)
     with jax.named_scope(TraceScope.SPARSE_ATTN):
         out, p_sum = _xla_attention(q, k, v, mask, sm_scale)
+        out = checkpoint_name(out, Kept.ATTENTION)
     with jax.named_scope(TraceScope.INDEXER):
-        target = detach(p_sum) / q.shape[1]
-        log_q = jax.nn.log_softmax(jnp.where(mask, scores, NEG_INF), axis=-1)
+        target = checkpoint_name(detach(p_sum) / q.shape[1], Kept.KL_GRADS)
+        log_q = checkpoint_name(jax.nn.log_softmax(
+            jnp.where(mask, scores, NEG_INF), axis=-1), Kept.KL_GRADS)
         kl = jnp.sum(jnp.where(target > 0, target * (
             jnp.log(jnp.where(target > 0, target, 1.0)) - log_q), 0.0),
             axis=-1)

@@ -21,6 +21,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -37,6 +38,7 @@ from dlrover_tpu.ops.flash_attention import (
     _vma,
     fit_block,
 )
+from dlrover_tpu.ops.remat import Kept
 
 KERNEL_SELECT = "indexer_select"
 KERNEL_KL = "indexer_kl"
@@ -232,6 +234,15 @@ def indexer_select(qi, ki, w, topk: int):
 # ===========================================================================
 
 
+def _keep_rows(rows, name: str):
+    """`checkpoint_name` on a (..., S, 1) row statistic without its last
+    axis: on the chip a float32 array whose minor dimension is 1 is padded
+    to 128 lanes, and what a block's recomputation keeps (`ops/remat.py`)
+    it keeps through the whole forward pass (268 MB a layer for the
+    attention's log-sum-exp at 32 heads x 16,384 rows, 2 MB so)."""
+    return checkpoint_name(rows[..., 0], name)[..., None]
+
+
 def _masked_forward(q, k, v, mask, sm_scale: float):
     return _flash_fwd(q, k, v, sm_scale, True, DEFAULT_BLOCK_Q,
                       DEFAULT_BLOCK_K, mask=mask)
@@ -244,7 +255,11 @@ def masked_attention(q, k, v, mask, sm_scale: float):
 
 
 def _masked_attention_fwd(q, k, v, mask, sm_scale):
+    # tagged here, where they are outputs and residuals at once: a block's
+    # recomputation that keeps them (`ops/remat.py`) drops the kernel
     out, lse = _masked_forward(q, k, v, mask, sm_scale)
+    out = checkpoint_name(out, Kept.ATTENTION)
+    lse = _keep_rows(lse, Kept.ATTENTION)
     return (out, lse), (q, k, v, out, lse, mask)
 
 
@@ -495,9 +510,12 @@ def _indexer_kl_fwd(qi, ki, w, mask, lse_i, q, k, lse, sm_scale):
                           sm_scale=sm_scale, interpret=interpret)
     dqi, dw, dki = _grad_call(grad, qi, w, ki, interpret=interpret)
     scale = 1.0 / rows.size
-    # kept in the dtypes their cotangents go back in
-    return jnp.mean(rows), ((dqi * scale).astype(qi.dtype),
-                            (dki * scale).astype(ki.dtype), dw * scale)
+    # kept in the dtypes their cotangents go back in; the rule's only
+    # residuals: a block's recomputation that keeps them runs neither call
+    return jnp.mean(rows), tuple(
+        checkpoint_name(g, Kept.KL_GRADS)
+        for g in ((dqi * scale).astype(qi.dtype),
+                  (dki * scale).astype(ki.dtype), dw * scale))
 
 
 def _indexer_kl_bwd(sm_scale, res, cotangent):
@@ -520,6 +538,8 @@ def sparse_attention(q, k, v, qi, ki, w, topk: int, sm_scale: float):
     w = w.astype(jnp.float32)
     with jax.named_scope(TraceScope.INDEXER):
         mask, lse_i = indexer_select(detach(qi), detach(ki), detach(w), topk)
+        mask = checkpoint_name(mask, Kept.SELECTION)
+        lse_i = _keep_rows(lse_i, Kept.SELECTION)
     qi = qi.transpose(0, 2, 1, 3)
     with jax.named_scope(TraceScope.SPARSE_ATTN):
         out, lse = masked_attention(q, k, v, mask, sm_scale)

@@ -47,7 +47,8 @@ _INSTRUCTION = re.compile(
 
 def schedule_counts(compiled_text: str, rows_and_seq: Tuple[int, ...]) -> dict:
     """What the compiled step's ENTRY computation, which is printed in
-    schedule order, says of two things XLA decides and nobody asks for:
+    schedule order, says of two things XLA decides and nobody asks for,
+    and of one the block's recomputation policy decides:
 
     `remat_instructions`: forward matmuls (`jvp(` outside `transpose(`,
     `dot_general`) launched beyond the first of their `op_name` where XLA
@@ -60,12 +61,21 @@ def schedule_counts(compiled_text: str, rows_and_seq: Tuple[int, ...]) -> dict:
     does not start with a device's `rows_and_seq`) that stand after the
     backward pass's last activation-gradient matmul, with everything they
     read alive until then; that last projection's own does not count, it
-    may stand on either side of its sibling."""
+    may stand on either side of its sibling.
+
+    `recomputed_kernels`: kernel name -> launches (`custom-call`s, named
+    after their `pl.pallas_call`) under `rematted_computation`, a block's
+    forward run again in the backward pass (`remat`). What
+    `ops/remat.py:Kept` names is kept and its kernel is not among them."""
     entry = compiled_text[compiled_text.rfind("\nENTRY "):]
     activation = "[" + ",".join(str(d) for d in rows_and_seq) + ","
     forward, weight_grads, last_dx = {}, [], (-1, "")
+    recomputed: dict = {}
     for at, found in enumerate(_INSTRUCTION.finditer(entry)):
         opcode, op_name = found["opcode"], found["op_name"]
+        if opcode == "custom-call" and "rematted_computation" in op_name:
+            kernel = found["name"].rstrip(".0123456789")
+            recomputed[kernel] = recomputed.get(kernel, 0) + 1
         matmul = opcode == "convolution" or (
             opcode == "fusion" and "kind=kOutput" in found[0])
         if not matmul or not op_name.endswith("dot_general"):
@@ -87,6 +97,7 @@ def schedule_counts(compiled_text: str, rows_and_seq: Tuple[int, ...]) -> dict:
         "late_weight_grads": sum(
             at > last_dx[0] and op_name != last_dx[1]
             for at, op_name in weight_grads),
+        "recomputed_kernels": recomputed,
     }
 
 
@@ -178,13 +189,17 @@ class ShardedTrainer:
             counts = schedule_counts(
                 compiled.as_text(), self.batch_sharding.shard_shape(
                     self.batch_abstract.shape)[1:])
+            by_kernel = counts["recomputed_kernels"]    # the span: the total
+            counts["recomputed_kernels"] = sum(by_kernel.values())
             counts["schedule_read_s"] = round(_time.monotonic() - t2, 2)
             for name, value in counts.items():
                 aot_span.set_attr(name, value)
         default_logger.info(
             "step program: remat_instructions=%d late_weight_grads=%d "
-            "(read in %.2f s)", counts["remat_instructions"],
-            counts["late_weight_grads"], counts["schedule_read_s"])
+            "recomputed_kernels=%d %s (read in %.2f s)",
+            counts["remat_instructions"], counts["late_weight_grads"],
+            counts["recomputed_kernels"], dict(sorted(by_kernel.items())),
+            counts["schedule_read_s"])
         self._compiled_step = compiled
 
     def step(self, state: TrainState, tokens, targets):

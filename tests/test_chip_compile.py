@@ -156,6 +156,42 @@ def test_sparse_attention_kernels_at_the_published_widths(
     assert text.count("tpu_custom_call") >= len(names)
 
 
+def test_a_recomputed_block_launches_none_of_its_attentions_kernels_again(
+        topo, chip_path):
+    """One layer of `models/keye.py` at the published widths and 16,384
+    tokens, recomputed by block under `KeyeConfig`'s default policy
+    (`ops/remat.py`: `kernel_outputs`), as the chip's compiler schedules
+    it: every kernel of the attention stands once in the step, only the
+    norms' forward kernels are launched again in the backward pass, and
+    XLA rematerialises nothing of its own."""
+    from dlrover_tpu.models.keye import Keye, KeyeConfig
+
+    s = SPARSE
+    cfg = KeyeConfig(
+        vocab_size=18992, hidden_size=2048, num_layers=1,
+        num_heads=s["heads"], num_kv_heads=s["kv_heads"],
+        attn_head_dim=s["d"], max_seq_len=s["seq"], rope_theta=1e7,
+        rms_norm_eps=1e-6, dtype=jnp.bfloat16, norm_impl="fused",
+        embed_impl="gather", remat=True, experts_held=16,
+        index_heads=s["index_heads"], index_head_dim=s["index_dim"],
+        index_topk=s["topk"])
+    assert cfg.remat_policy == "kernel_outputs"
+    tx = optax.chain(optax.scale_by_factored_rms(), optax.scale(-3e-4))
+    trainer = build_trainer(
+        Keye(cfg), tx, create_mesh(MeshSpec(), topo.devices[:1]),
+        jnp.zeros((1, s["seq"]), jnp.int32), cross_entropy_loss,
+        accum_steps=1, micro_batch=1)
+    trainer.precompile()
+    text = trainer._compiled_step.as_text()
+    counts = schedule_counts(text, (1, s["seq"]))
+    assert set(counts["recomputed_kernels"]) == {norms.KERNEL_FWD}
+    assert counts["remat_instructions"] == 0
+    for kernel in ("indexer_select", "sparse_attn_fwd", "indexer_kl",
+                   "indexer_dq", "indexer_dk", "sparse_attn_dq",
+                   "sparse_attn_dkv"):
+        assert len(re.findall(rf"%{kernel}[.\d]* = ", text)) == 1, kernel
+
+
 @pytest.mark.parametrize("hidden", [2048, 4096])
 def test_fused_rms_norm_fwd_bwd(topo, one_chip, chip_path, hidden):
     x = jax.ShapeDtypeStruct((2, 2048, hidden), jnp.bfloat16,
@@ -270,7 +306,8 @@ def test_weight_gradients_stand_inside_their_layers_backward(
     # 14 projections, each with an activation gradient and a weight
     # gradient the count can tell apart: a device's rows x sequence
     assert schedule_counts(text, (2, 2048)) == {
-        "remat_instructions": 0, "late_weight_grads": 0}
+        "remat_instructions": 0, "late_weight_grads": 0,
+        "recomputed_kernels": {}}
     assert len(re.findall(
         r'kind=kOutput[^\n]*transpose\(jvp\([^"\n]*_proj/dot_general"',
         text[text.rfind("\nENTRY "):])) >= 2 * 14

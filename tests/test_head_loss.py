@@ -380,7 +380,8 @@ def test_precompiles_span_carries_the_schedules_counts(one_device, spans):
                        micro=2)
     trainer.precompile()
     (aot,) = _recompiles(spans, "aot")
-    assert (aot["remat_instructions"], aot["late_weight_grads"]) == (0, 0)
+    assert (aot["remat_instructions"], aot["late_weight_grads"],
+            aot["recomputed_kernels"]) == (0, 0, 0)
     assert 0 <= aot["schedule_read_s"] < 5
 
 
@@ -426,6 +427,44 @@ def test_schedule_counts_on_a_hand_made_entry(order, want):
     counts = schedule_counts(_entry(*order), (2, 64))
     assert (counts["remat_instructions"],
             counts["late_weight_grads"]) == want
+    assert counts["recomputed_kernels"] == {}
+
+
+_BLOCK = "transpose(jvp(M))/layer_{}/checkpoint/rematted_computation/{}"
+
+
+def _kernel(name, result, op_name) -> str:
+    return (f"  %{name} = {result} custom-call(%a), "
+            'custom_call_target="tpu_custom_call", '
+            f'metadata={{op_name="jit(s)/{op_name}/pallas_call"}}')
+
+
+@pytest.mark.parametrize("again, want", [
+    # recomputed whole: the block's forward kernels stand a second time
+    # under `rematted_computation`, by name whatever XLA numbers them
+    ([("sparse_attn_fwd.9", "sparse_attn"), ("sparse_attn_fwd.10",
+      "sparse_attn"), ("indexer_select.4", "indexer"),
+      ("rms_norm_fwd.31", "attn_norm")],
+     {"sparse_attn_fwd": 2, "indexer_select": 1, "rms_norm_fwd": 1}),
+    # their outputs kept (`ops/remat.py`): only the norm's is left
+    ([("rms_norm_fwd.31", "attn_norm")], {"rms_norm_fwd": 1}),
+    ([], {})], ids=["whole", "kept", "no_remat"])
+def test_schedule_counts_names_the_kernels_a_block_recomputes(again, want):
+    """Kernels of the forward pass, of the backward pass and a fusion
+    under `rematted_computation` are not counted; a `custom-call` there is,
+    under its `pl.pallas_call`'s name."""
+    tuple_result = "(bf16[1,4,64,32]{3,2,1,0}, f32[1,4,64,1]{3,2,1,0})"
+    lines = _entry(("f.1", _ACT, _BLOCK.format(0, "mlp/up_proj/dot_general"))
+                   ).splitlines()
+    lines[-1:-1] = [
+        _kernel("sparse_attn_fwd.1", tuple_result, "jvp(M)/layer_0/sparse_attn"),
+        _kernel("sparse_attn_dq.2", _ACT,
+                "transpose(jvp(M))/layer_0/sparse_attn")] + [
+        _kernel(name, tuple_result, _BLOCK.format(0, scope))
+        for name, scope in again]
+    counts = schedule_counts("\n".join(lines), (2, 64))
+    assert counts["recomputed_kernels"] == want
+    assert (counts["remat_instructions"], counts["late_weight_grads"]) == (0, 0)
 
 
 @pytest.mark.parametrize("loss_fn, want", [

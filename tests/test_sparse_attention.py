@@ -3,11 +3,14 @@ ops/sparse_attention_kernels.py), the experts held by share
 (parallel/moe.py:HeldExpertsLayer) and the model they make
 (models/keye.py): the selection's exactness and tie rule, the kernels
 against the plain XLA form (interpret mode on the CPU), nothing dropped
-whatever the routing, and the same selection in a recomputed forward."""
+whatever the routing, the same selection in a recomputed forward, and
+what a block's recomputation keeps of its kernels' work (ops/remat.py)."""
 
 from __future__ import annotations
 
+import collections
 import importlib
+import re
 
 import flax.linen as nn
 import jax
@@ -16,6 +19,8 @@ import numpy as np
 import pytest
 
 from dlrover_tpu.models.keye import Keye, KeyeConfig
+from dlrover_tpu.models.llama import Llama, LlamaConfig
+from dlrover_tpu.ops.remat import Kept, resolve_remat_policy
 from dlrover_tpu.parallel.moe import (
     HeldExpertsConfig,
     HeldExpertsLayer,
@@ -253,15 +258,18 @@ def _objective(model, tokens):
     return objective
 
 
-def test_the_recomputed_forward_selects_the_same_keys():
+POLICIES = ["kernel_outputs", "nothing_saveable"]
+
+
+@pytest.mark.parametrize("policy", POLICIES)
+def test_the_recomputed_forward_selects_the_same_keys(policy):
     """Recomputation by block: the selection is a function of the saved
-    block input alone, so the recomputed forward attends the same keys and
-    the gradients are the same to the last digit."""
+    block input alone (or, under `kernel_outputs`, kept), so the backward
+    pass attends the same keys and the gradients are the same to the last
+    digit, whatever the policy keeps."""
     cfg, model, tokens, params = _model(remat=False, num_layers=1)
     loss, grads = jax.value_and_grad(_objective(model, tokens))(params)
-    again = Keye(KeyeConfig.tiny(dtype=jnp.float32, norm_impl="reference",
-                                 embed_impl="gather", remat=True,
-                                 num_layers=1))
+    _, again, _, _ = _model(remat=True, remat_policy=policy, num_layers=1)
     loss_again, grads_again = jax.value_and_grad(
         _objective(again, tokens))(params)
     assert float(loss) == float(loss_again)
@@ -270,6 +278,76 @@ def test_the_recomputed_forward_selects_the_same_keys():
             jax.tree.leaves(grads_again)):
         np.testing.assert_array_equal(mine, theirs, err_msg=str(path))
     assert cfg.index_topk < 64
+
+
+@pytest.mark.parametrize("policy, selections", [
+    (None, 1), ("nothing_saveable", 2)], ids=["default", "nothing_saveable"])
+def test_a_recomputed_block_keeps_its_selection(policy, selections):
+    """`KeyeConfig`'s default policy keeps what the attention tagged
+    (`ops/remat.py:Kept`): the gradient's program selects once a layer,
+    where `nothing_saveable` selects again in the backward pass. The
+    router's own `top_k` (2 of 8 experts) is recomputed under either."""
+    kw = {} if policy is None else {"remat_policy": policy}
+    cfg, model, tokens, params = _model(remat=True, num_layers=1, **kw)
+    assert cfg.remat_policy == (policy or "kernel_outputs")
+    text = str(jax.make_jaxpr(jax.grad(_objective(model, tokens)))(params))
+    by_k = collections.Counter(re.findall(r"\btop_k\[[^\]]*?\bk=(\d+)", text))
+    assert by_k == {str(cfg.index_topk): selections,
+                    str(cfg.experts_per_token): 2}
+
+
+def test_a_block_that_tags_nothing_is_recomputed_whole():
+    """The policy follows what the block contains, not a model's name: a
+    `Llama` tags nothing, so `kernel_outputs` gives the gradient's program
+    `nothing_saveable` gives, to the letter."""
+    texts = []
+    for policy in POLICIES:
+        model = Llama(LlamaConfig.tiny(
+            dtype=jnp.float32, norm_impl="reference", attn_impl="reference",
+            remat=True, remat_policy=policy))
+        tokens = jax.random.randint(jax.random.PRNGKey(0), (2, 64), 0, 256)
+        params = nn.unbox(model.init(jax.random.PRNGKey(1), tokens))["params"]
+        text = str(jax.make_jaxpr(jax.grad(
+            lambda p: jnp.mean(model.apply({"params": p}, tokens) ** 2)))(
+                params))
+        # the policy is a function and prints as its address
+        texts.append(re.sub(r"policy=<[^\n]*", "policy=_", text))
+    assert "policy=_" in texts[0]
+    assert texts[0] == texts[1]
+    assert LlamaConfig().remat_policy == "nothing_saveable"
+
+
+@pytest.mark.parametrize("policy, forward_launches", [
+    ("kernel_outputs", 1), ("nothing_saveable", 2)])
+def test_the_kernels_run_once_under_a_policy_that_keeps_their_outputs(
+        policy, forward_launches, small_blocks):
+    """The Pallas form under `jax.checkpoint`: with `Kept`'s names kept,
+    the selection, the masked forward and the KL kernel each stand once in
+    the gradient's program (the tags sit inside the `custom_vjp`s' forward
+    rules, where outputs and residuals are the same arrays), and the KL
+    term's two gradient kernels once under either policy; the gradients
+    are the plain ones to the last digit."""
+    operands = _operands(256)
+
+    def objective(*operands):
+        out, kl = sparse.sparse_attention(*operands, 48, impl="kernel")
+        return jnp.sum(out ** 2) * 1e-3 + kl
+
+    every = tuple(range(6))
+    kept = jax.grad(jax.checkpoint(
+        objective, policy=resolve_remat_policy(policy)), argnums=every)
+    launches = collections.Counter(re.findall(
+        r"\bname=(\w+)", str(jax.make_jaxpr(kept)(*operands))))
+    for kernel in (kernels.KERNEL_SELECT, "sparse_attn_fwd",
+                   kernels.KERNEL_KL):
+        assert launches[kernel] == forward_launches, kernel
+    for kernel in (kernels.KERNEL_DQ, kernels.KERNEL_DK, "sparse_attn_dq",
+                   "sparse_attn_dkv"):
+        assert launches[kernel] == 1, kernel
+    assert all(launches[name] for name in Kept.ALL)     # every tag is there
+    for mine, plain in zip(kept(*operands),
+                           jax.grad(objective, argnums=every)(*operands)):
+        np.testing.assert_array_equal(mine, plain)
 
 
 def test_the_two_objectives_train_apart():
