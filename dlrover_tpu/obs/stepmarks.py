@@ -219,8 +219,13 @@ class LoopWindow:
         out["in_flight_mean"] = (self._in_flight_sum / self._samples
                                  if self._samples else 0.0)
         out["in_flight_max"] = self._in_flight_max
-        # a model's counters: the mean over the steps seen done here
-        for name, (total, steps) in self._counted.items():
-            out[f"{name}_mean"] = total / steps
+        for name, (mean, steps) in self.counters().items():
+            out[f"{name}_mean"] = mean
             out[f"{name}_steps"] = steps
         return out
+
+    def counters(self) -> Dict[str, Tuple[float, int]]:
+        """A model's counters: name -> (the mean over the steps seen done
+        here, how many they were)."""
+        return {name: (total / steps, steps)
+                for name, (total, steps) in self._counted.items()}

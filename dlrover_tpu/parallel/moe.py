@@ -17,6 +17,7 @@ function needed (its transpose falls out of autodiff).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Tuple
 
 import flax.linen as nn
@@ -214,14 +215,18 @@ def sown_counters(variables) -> dict:
 # ===========================================================================
 # The layer one chip of an expert-parallel group runs: it is told how many
 # experts the model has, how many it holds and which is its first, routes
-# every token over ALL of them, sorts the token-expert assignments by
-# expert, multiplies the rows of the experts it holds by grouped matmuls
-# (`jax.lax.ragged_dot`; rows of absent experts are not multiplied) and adds
-# its own experts' weighted outputs. What the absent experts would add is
-# left out: on a mesh that is the other chips' part, and no code here stands
-# in for them or for their exchange. The capacity-based `MoELayer` above
-# stays for `models/llama_moe.py` until that model runs on this layer
-# (ROADMAP R8).
+# every token over ALL of them and sorts the token-expert assignments by
+# expert, the held experts' first. It then works through the sorted order a
+# chunk of rows at a time (`chunk_rows`: half the even routing's share of
+# the assignments): a chunk gathers its token rows, multiplies them by grouped
+# matmuls (`jax.lax.ragged_dot`) with its own group sizes and is skipped, by
+# a real branch, when it starts past the last held row. So every held row is
+# computed under every routing, and the cost follows the rows held to within
+# one chunk, not the worst case's tokens x top_k. The layer adds its own
+# experts' weighted outputs; what the absent experts would add is left out:
+# on a mesh that is the other chips' part, and no code here stands in for
+# them or for their exchange. The capacity-based `MoELayer` above stays for
+# `models/llama_moe.py` until that model runs on this layer (ROADMAP R8).
 
 @dataclasses.dataclass(frozen=True)
 class HeldExpertsConfig:
@@ -247,25 +252,6 @@ def route_top_k(router_logits: jax.Array, top_k: int,
     return gates, experts.astype(jnp.int32)
 
 
-@jax.custom_vjp
-def permute_rows(x: jax.Array, perm: jax.Array, inverse: jax.Array):
-    """`x[perm]` for a permutation and its inverse: the cotangent goes
-    back by a gather through the inverse, not by a scatter that cannot
-    know its indices are distinct."""
-    return x[perm]
-
-
-def _permute_rows_fwd(x, perm, inverse):
-    return x[perm], (perm, inverse)
-
-
-def _permute_rows_bwd(res, g):
-    return g[res[1]], None, None
-
-
-permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
-
-
 def held_assignments(experts: jax.Array, first_expert: int,
                      experts_held: int):
     """Sort the (T, k) assignments by expert, held experts first in their
@@ -282,15 +268,141 @@ def held_assignments(experts: jax.Array, first_expert: int,
     return held, order, sizes
 
 
+def chunk_rows(assignments: int, experts_held: int, num_experts: int) -> int:
+    """Rows of one chunk of the sorted order: half of the held experts'
+    share of the assignments under an even routing, that share first taken
+    up to a multiple of 512 (8,192 of 131,072 where an eighth is held: the
+    chip multiplies what a routing leaves under half a share at half the
+    price, and an even routing's 16,384 and a few more rows in three chunks
+    and not in two of 16,384); every assignment, one chunk, where every
+    expert is held."""
+    even = -(-assignments * experts_held // num_experts)
+    if even >= assignments:
+        return assignments
+    return min(-(-even // 512) * 256, assignments)
+
+
+def _chunk_experts(rows, gate, stacks, sizes, live):
+    """(R, H) -> (R, H) float32: `gate * w2(silu(w1 x) * w3 x)` on one
+    chunk's sorted rows, `sizes` rows to each held expert from the first
+    row on."""
+    def grouped(lhs, stack):
+        # a row past the held ones is never written, forward or backward
+        # (the chip leaves what was there: not a zero, maybe not a number),
+        # so it is selected out on both sides and its cotangent is a zero
+        # in either direction
+        return jnp.where(live, jax.lax.ragged_dot(
+            jnp.where(live, lhs, 0), stack, sizes), 0)
+
+    w1, w3, w2 = stacks
+    out = grouped(nn.silu(grouped(rows, w1)) * grouped(rows, w3), w2)
+    return out.astype(jnp.float32) * gate[:, None]
+
+
+def _over_chunks(per_chunk, tokens, weights, order, sizes, run, carry):
+    """`carry` through `run(carry, (token of each row, gate of each row,
+    group sizes, which rows are held ones))` for every chunk of `per_chunk`
+    sorted rows that holds a held expert's row, in order; a chunk that
+    starts past the last held row costs a branch. Also returns what each
+    chunk's `run` gave beside the carry, zeros for a chunk skipped."""
+    k = order.shape[0] // tokens.shape[0]
+    count = -(-order.shape[0] // per_chunk)
+    spare = count * per_chunk - order.shape[0]
+    order = jnp.pad(order, (0, spare))
+    # a row of an absent expert has no weight: `weights` is 0 there
+    gate = jnp.pad(weights[order], (0, spare))
+    cum = jnp.concatenate([jnp.zeros((1,), sizes.dtype), jnp.cumsum(sizes)])
+
+    def chunk(carry, c):
+        lo = c * per_chunk
+        at = jax.lax.dynamic_slice_in_dim(order, lo, per_chunk)
+        # an expert whose rows straddle two chunks is in both
+        edges = jnp.clip(cum, lo, lo + per_chunk)
+        live = (jnp.arange(per_chunk) < cum[-1] - lo)[:, None]
+        return run(carry, (at // k,
+                           jax.lax.dynamic_slice_in_dim(gate, lo, per_chunk),
+                           edges[1:] - edges[:-1], live))
+
+    skipped = jax.tree.map(
+        lambda leaf: jnp.zeros(leaf.shape, leaf.dtype),
+        jax.eval_shape(chunk, carry, 0)[1])
+
+    def body(carry, c):
+        return jax.lax.cond(c * per_chunk < cum[-1], chunk,
+                            lambda carry, c: (carry, skipped), carry, c)
+
+    return jax.lax.scan(body, carry, jnp.arange(count))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def held_rows(per_chunk: int, tokens, weights, order, sizes, stacks):
+    """(T, H) float32: each token's held experts' outputs, weighted and
+    summed. `tokens` (T, H); `weights` (T k,) float32, an assignment's gate
+    or 0 for an absent expert's; `order`, `sizes` as `held_assignments`
+    gives them; `stacks` (w1, w3, w2).
+
+    Forward and backward each work through the sorted rows a chunk at a
+    time: gather the chunk's token rows, multiply, and add its weighted
+    rows into the tokens' (the cotangent's rows likewise): at most
+    `per_chunk` rows added a chunk, where a gather the other way round
+    would read all T k, and on the chip the scatter-add is the faster of
+    the two (`PERF.md` section 6, PR 39). The rule keeps its inputs only:
+    the backward forms a chunk's forward anew, and a recomputed forward
+    pass (`remat`) whose output nobody reads costs the router and the sort
+    alone."""
+    return _held_rows_fwd(per_chunk, tokens, weights, order, sizes,
+                          stacks)[0]
+
+
+def _held_rows_fwd(per_chunk, tokens, weights, order, sizes, stacks):
+    def run(mixed, chunk):
+        tok, gate, groups, live = chunk
+        return mixed.at[tok].add(_chunk_experts(
+            tokens[tok], gate, stacks, groups, live)), None
+
+    mixed, _ = _over_chunks(per_chunk, tokens, weights, order, sizes, run,
+                            jnp.zeros(tokens.shape, jnp.float32))
+    return mixed, (tokens, weights, order, sizes, stacks)
+
+
+def _held_rows_bwd(per_chunk, res, g):
+    tokens, weights, order, sizes, stacks = res
+
+    def run(carry, chunk):
+        tok, gate, groups, live = chunk
+        d_rows, d_gate, d_stacks = jax.vjp(
+            lambda rows, gate, stacks: _chunk_experts(
+                rows, gate, stacks, groups, live),
+            tokens[tok], gate, stacks)[1](g[tok])
+        d_tokens, acc = carry
+        # the stacks' cotangents add up in the stacks' own dtype, as the
+        # grouped product's transpose gives them
+        return (d_tokens.at[tok].add(d_rows.astype(jnp.float32)),
+                jax.tree.map(jnp.add, acc, d_stacks)), d_gate
+
+    (d_tokens, d_stacks), d_gate = _over_chunks(
+        per_chunk, tokens, weights, order, sizes, run,
+        (jnp.zeros(tokens.shape, jnp.float32),
+         jax.tree.map(jnp.zeros_like, stacks)))
+    # each assignment's gate cotangent, from its sorted row
+    d_weights = d_gate.reshape(-1)[jnp.argsort(order)]
+    return d_tokens.astype(tokens.dtype), d_weights, None, None, d_stacks
+
+
+held_rows.defvjp(_held_rows_fwd, _held_rows_bwd)
+
+
 class HeldExpertsLayer(nn.Module):
     """(..., S, H) -> (..., S, H): the held experts' part of a top-k
     mixture of SwiGLU experts, `w2(silu(w1 x) * w3 x)`. Leaves: `router`
     (H, E) float32 and three stacks of three axes, `w1`, `w3` (held, H, I)
-    and `w2` (held, I, H). Sows two counters into `counters`:
-    `moe_load_max_over_mean` (the fullest held expert's rows over the mean)
-    and `moe_held_rows_share` (the rows of the held experts over every
+    and `w2` (held, I, H). Sows three counters into `counters`:
+    `moe_load_max_over_mean` (the fullest held expert's rows over the mean),
+    `moe_held_rows_share` (the rows of the held experts over every
     assignment, tokens x k: `experts_held / num_experts` under an even
-    routing, and what the grouped products' needed work follows)."""
+    routing) and `moe_chunks_run` (how many chunks of `chunk_rows` sorted
+    rows the layer worked through, of `ceil(tokens x k / chunk_rows)`: what
+    the grouped products' work follows)."""
 
     cfg: HeldExpertsConfig
 
@@ -320,32 +432,16 @@ class HeldExpertsLayer(nn.Module):
         gates, experts = route_top_k(logits, k, cfg.norm_topk_prob)
         held, order, sizes = held_assignments(experts, cfg.first_expert,
                                               held_n)
+        per_chunk = chunk_rows(held.shape[0], held_n, cfg.num_experts)
+        held_rows_n = jnp.sum(sizes)
         self.sow("counters", "moe_load_max_over_mean",
-                 jnp.max(sizes) * held_n / jnp.maximum(jnp.sum(sizes), 1))
+                 jnp.max(sizes) * held_n / jnp.maximum(held_rows_n, 1))
         self.sow("counters", "moe_held_rows_share",
-                 jnp.sum(sizes) / held.shape[0])
-        # a buffer for the worst case, every assignment on a held expert;
-        # the grouped products multiply the held experts' rows only (the
-        # first `sum(sizes)`), whatever the routing made of them
-        back = jnp.argsort(order)
-        rows = permute_rows(jnp.repeat(tokens.astype(cfg.dtype), k, axis=0),
-                            order, back)
-        written = (jnp.arange(order.shape[0]) < jnp.sum(sizes))[:, None]
-
-        def grouped(lhs, stack):
-            # a row past the held ones is never written, forward or
-            # backward (the chip leaves what was there: not a zero, maybe
-            # not a number), so it is selected out on both sides and its
-            # cotangent is a zero in either direction
-            return jnp.where(written, jax.lax.ragged_dot(
-                jnp.where(written, lhs, 0), stack, sizes), 0)
-
-        act = nn.silu(grouped(rows, stacks["w1"])) * grouped(rows,
-                                                             stacks["w3"])
-        out = grouped(act, stacks["w2"])
-        # each token's k rows back beside each other, weighted and summed;
-        # a row of an absent expert takes no weight
-        out = permute_rows(out, back, order).astype(jnp.float32)
-        out = out * jnp.where(held, gates.reshape(-1), 0.0)[:, None]
-        mixed = jnp.sum(out.reshape(-1, k, hidden), axis=1)
+                 held_rows_n / held.shape[0])
+        self.sow("counters", "moe_chunks_run",
+                 (-(-held_rows_n // per_chunk)).astype(jnp.float32))
+        mixed = held_rows(
+            per_chunk, tokens.astype(cfg.dtype),
+            jnp.where(held, gates.reshape(-1), 0.0), order, sizes,
+            (stacks["w1"], stacks["w3"], stacks["w2"]))
         return mixed.reshape(x.shape).astype(x.dtype)

@@ -1143,6 +1143,13 @@ class ElasticTrainLoop:
         and the SpanExporter hold lifecycle spans a postmortem needs."""
         if window.wall_s > 0.0:
             obs.record_span("train_window", window.wall_s, window.attrs())
+            counters = window.counters()
+            if counters:
+                logger.info(
+                    "train window from step %d, the model's counters "
+                    "(mean over the steps seen done): %s", window.first_step,
+                    ", ".join(f"{name}={mean:.4g} ({steps})" for name,
+                              (mean, steps) in sorted(counters.items())))
 
     # -- multi-slice hierarchical DP ---------------------------------------
     def _slice_step(self, state, tok, tgt, step: int):

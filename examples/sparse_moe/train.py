@@ -5,7 +5,10 @@ preset through the product's path.
 learned indexer selects (`ops/sparse_attention.py`), the indexer learns
 from the attention by a KL term the block sows into `losses`, and the MLP
 is a top-k mixture of SwiGLU experts of which this process holds a share
-and drops none (`parallel/moe.py:HeldExpertsLayer`). The trainer adds the
+and drops none (`parallel/moe.py:HeldExpertsLayer`: the assignments sorted
+by expert, the held experts' first, and worked through a chunk of the even
+routing's share at a time; a chunk no held expert reaches is skipped, so the
+cost follows the rows held and not tokens x top_k). The trainer adds the
 sown term to the loss it reports; nothing here is bespoke.
 
     python -m dlrover_tpu.run --standalone examples/sparse_moe/train.py \
@@ -100,7 +103,8 @@ def main(argv=None) -> int:
                               sampler=sampler)
     log(f"sparse_moe: done step={int(metrics['step'])} "
         f"loss={metrics['loss']:.4f} "
-        f"load={metrics.get('moe_load_max_over_mean', -1):.2f}")
+        f"load={metrics.get('moe_load_max_over_mean', -1):.2f} "
+        f"chunks_run={metrics.get('moe_chunks_run', -1):.2f}")
     loop.close()
     return 0
 

@@ -156,6 +156,44 @@ def test_sparse_attention_kernels_at_the_published_widths(
     assert text.count("tpu_custom_call") >= len(names)
 
 
+def test_the_expert_layer_multiplies_a_chunk_of_the_sorted_rows(topo,
+                                                                one_chip):
+    """`parallel/moe.py:HeldExpertsLayer` at the published widths (16 of
+    128 experts of width 768 held, 8 a token, 16,384 tokens), forward and
+    backward, as the chip's compiler takes it: every grouped product is
+    over one chunk's 8,192 rows, none over the 131,072 assignments, under
+    real branches (a chunk no held expert reaches is skipped, not masked),
+    and nothing of the layer is tokens x top_k rows of `hidden` columns."""
+    from dlrover_tpu.parallel.moe import HeldExpertsConfig, HeldExpertsLayer
+
+    layer = HeldExpertsLayer(HeldExpertsConfig(
+        num_experts=128, experts_held=16, first_expert=0, top_k=8,
+        hidden_size=2048, expert_intermediate=768, dtype=jnp.bfloat16))
+    x = jax.ShapeDtypeStruct((1, SPARSE["seq"], 2048), jnp.bfloat16,
+                             sharding=one_chip)
+    params = {
+        name: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+        for name, shape in (("router", (2048, 128)), ("w1", (16, 2048, 768)),
+                            ("w3", (16, 2048, 768)), ("w2", (16, 768, 2048)))}
+
+    def loss(p, x):
+        out = layer.apply({"params": p}, x, mutable=["counters"])[0]
+        return jnp.sum(out.astype(jnp.float32))
+
+    # the value too: the rule keeps only its inputs, so a gradient alone
+    # would leave the forward's chunks dead code
+    text = _compiled_text(jax.value_and_grad(loss, argnums=(0, 1)), params,
+                          x)
+    products = re.findall(r"%ragged-dot[-\w]*(?:\.\d+)? = (\w+\[[\d,]*\])",
+                          text)
+    products = [shape for shape in products if not shape.startswith("(")]
+    assert len(products) >= 9, products     # 3 forward, 3 + 2 x 3 backward
+    assert not [shape for shape in products if "[131072," in shape]
+    assert [shape for shape in products if "[8192," in shape]
+    assert len(re.findall(r" conditional\(", text)) == 2
+    assert "[131072,2048]" not in text
+
+
 def test_a_recomputed_block_launches_none_of_its_attentions_kernels_again(
         topo, chip_path):
     """One layer of `models/keye.py` at the published widths and 16,384
