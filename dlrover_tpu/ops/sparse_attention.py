@@ -28,10 +28,10 @@ Two forms of the same mathematics (`impl`):
   (`ops/flash_attention.py`) with that mask as an operand, applied in every
   computed block, the causal block skipping kept; `indexer_kl` recomputes
   the main attention's scores a head at a time, sums the probabilities over
-  the heads in VMEM and forms the KL term's rows and d KL / d I in one pass;
-  `indexer_dq` and `indexer_dk` take that gradient back to qi, w and ki.
-  No (seq, seq) float32 array is ever written: the mask is int8 and
-  d KL / d I bfloat16.
+  the heads in VMEM and forms the KL term's rows and, under
+  differentiation, d KL / d I, which it takes back to qi, w and ki in the
+  same pass. The int8 mask is the only (seq, seq) array written: d KL / d I
+  stays in VMEM, float32 until the products' operands are cast.
 """
 
 from __future__ import annotations

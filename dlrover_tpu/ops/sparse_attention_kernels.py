@@ -42,8 +42,6 @@ from dlrover_tpu.ops.remat import Kept
 
 KERNEL_SELECT = "indexer_select"
 KERNEL_KL = "indexer_kl"
-KERNEL_DQ = "indexer_dq"
-KERNEL_DK = "indexer_dk"
 
 INT_MIN = -(2 ** 31)
 FLIP = 0x7FFFFFFF
@@ -51,7 +49,7 @@ FLIP = 0x7FFFFFFF
 SELECT_BLOCK_Q = 128      # rows whose scores sit in VMEM at once (x seq x 4 B)
 SELECT_BLOCK_K = 2048     # keys a pass takes at a time
 KL_BLOCK = 1024
-GRAD_BLOCK = 512
+KL_ROWS = 128             # a block's rows whose index scores VMEM keeps at once
 VMEM_LIMIT = 100 * 1024 * 1024
 
 
@@ -86,12 +84,15 @@ def _scores_of(q, k):
     return _dot(q, k, ((1,), (1,)))
 
 
-def _index_block(q_of, w, k, heads: int):
+def _index_block(q_of, w, k, heads: int, scores=None):
     """I[rows, cols] = sum_j w[:, j] relu(qi_j k^T): `q_of(j)` (rows, D) and
-    k (cols, D) in the indexer's dtype, float32 accumulation; w (rows, J)."""
+    k (cols, D) in the indexer's dtype, float32 accumulation; w (rows, J).
+    `scores`, a (J, rows, cols) float32 ref, keeps each head's qi_j k^T."""
     acc = None
     for j in range(heads):
         s = _scores_of(q_of(j), k)
+        if scores is not None:
+            scores[j] = s
         term = w[:, j:j + 1] * jnp.maximum(s, 0.0)
         acc = term if acc is None else acc + term
     return acc
@@ -277,14 +278,25 @@ masked_attention.defvjp(_masked_attention_fwd, _masked_attention_bwd)
 
 
 # ===========================================================================
-# indexer_kl: head-summed probabilities, the KL rows and d KL / d I
+# indexer_kl: head-summed probabilities, the KL rows, and d KL / d I taken
+# back to qi, w and ki in the same pass
 # ===========================================================================
 
 
+def _weights_of(grad, w, s, j: int):
+    """One head's part of d I: (d w[:, j] rows, the matmuls' left operand
+    `grad x w[:, j]` where the head's score is positive)."""
+    live = jnp.where(s > 0.0, grad, 0.0)
+    return jnp.sum(live * s, axis=1, keepdims=True), live * w[:, j:j + 1]
+
+
 def _kl_kernel(q_ref, k_ref, lse_ref, mask_ref, qi_ref, w_ref, ki_ref,
-               lsei_ref, grad_ref, kl_ref, sum_ref,
-               *, sm_scale: float, block: int, heads: int,
-               index_heads: int):
+               lsei_ref, kl_ref, *rest, sm_scale: float, block: int,
+               heads: int, index_heads: int, grads: bool, rows: int):
+    if grads:
+        dqi_ref, dw_ref, dki_ref, sum_ref, scores_ref = rest
+    else:
+        (sum_ref,), scores_ref = rest, None
     qb, kb, h = pl.program_id(1), pl.program_id(2), pl.program_id(3)
     needed = kb <= qb       # equal square blocks: at or below the diagonal
     last_head = h == heads - 1
@@ -295,7 +307,14 @@ def _kl_kernel(q_ref, k_ref, lse_ref, mask_ref, qi_ref, w_ref, ki_ref,
 
     @pl.when(jnp.logical_and(last_head, kb == 0))
     def _init_rows():
-        kl_ref[0] = jnp.zeros((block, 1), jnp.float32)
+        kl_ref[0] = jnp.zeros(kl_ref.shape[1:], jnp.float32)
+        if grads:
+            dqi_ref[0] = jnp.zeros(dqi_ref.shape[1:], jnp.float32)
+            dw_ref[0] = jnp.zeros(dw_ref.shape[1:], jnp.float32)
+
+            @pl.when(qb == 0)
+            def _init_keys():
+                dki_ref[0] = jnp.zeros(dki_ref.shape[1:], jnp.float32)
 
     @pl.when(needed)
     def _head():
@@ -307,39 +326,82 @@ def _kl_kernel(q_ref, k_ref, lse_ref, mask_ref, qi_ref, w_ref, ki_ref,
 
     @pl.when(jnp.logical_and(last_head, needed))
     def _rows():
-        chosen = mask_ref[0] != 0
-        index = _index_block(lambda j: qi_ref[0, j], w_ref[0], ki_ref[0],
-                             index_heads)
-        log_q = index - lsei_ref[0]
-        target = sum_ref[:] * (1.0 / heads)
-        there = jnp.logical_and(chosen, target > 0)
-        gap = jnp.where(there, target * (
-            jnp.log(jnp.where(there, target, 1.0)) - log_q), 0.0)
-        kl_ref[0] += jnp.sum(gap, axis=1, keepdims=True)
-        grad_ref[0] = (jnp.where(chosen, jnp.exp(log_q), 0.0)
-                       - target).astype(grad_ref.dtype)
+        k = ki_ref[0]
+        lane = jax.lax.broadcasted_iota(jnp.int32, (rows, index_heads), 1)
+        dk = None
+        for r0 in range(0, block, rows):
+            at = pl.ds(r0, rows)
+            chosen = mask_ref[0, at, :] != 0
+            w = w_ref[0, at, :]
+            index = _index_block(lambda j: qi_ref[0, j, at, :], w, k,
+                                 index_heads, scores_ref)
+            log_q = index - lsei_ref[0, at, :]
+            target = sum_ref[at, :] * (1.0 / heads)
+            there = jnp.logical_and(chosen, target > 0)
+            gap = jnp.where(there, target * (
+                jnp.log(jnp.where(there, target, 1.0)) - log_q), 0.0)
+            kl_ref[0, at, :] += jnp.sum(gap, axis=1, keepdims=True)
+            if not grads:
+                continue
+            # d KL / d I, float32 until the matmuls' operands are cast
+            grad = jnp.where(chosen, jnp.exp(log_q), 0.0) - target
+            dw = jnp.zeros((rows, index_heads), jnp.float32)
+            for j in range(index_heads):
+                q = qi_ref[0, j, at, :]
+                dw_j, left = _weights_of(grad, w, scores_ref[j], j)
+                dw = dw + jnp.where(lane == j, dw_j, 0.0)
+                left = left.astype(k.dtype)
+                dqi_ref[0, j, at, :] += _dot(left, k, ((1,), (0,)))
+                part = _dot(q, left, ((0,), (0,)))
+                dk = part if dk is None else dk + part
+            dw_ref[0, at, :] += dw
+        if grads:
+            keys = pl.ds(pl.multiple_of(kb * block, block), block)
+            dki_ref[0, :, keys] += dk
 
-    @pl.when(jnp.logical_and(last_head, jnp.logical_not(needed)))
-    def _nothing():
-        grad_ref[0] = jnp.zeros(grad_ref.shape[1:], grad_ref.dtype)
 
-
-@functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("sm_scale", "grads", "interpret"))
 def _kl_call(q, k, lse, mask, qi, w, ki, lse_i, *, sm_scale: float,
-             interpret: bool):
+             grads: bool, interpret: bool):
+    """The KL term's rows (b, S, 1) and, with `grads`, its unscaled
+    gradients with respect to qi, w and ki, float32."""
     batch, heads, seq, d = q.shape
     group = heads // k.shape[1]
     index_heads, dim = qi.shape[1], qi.shape[3]
     block = fit_block(seq, KL_BLOCK)
     blocks = seq // block
-    kernel = functools.partial(_kl_kernel, sm_scale=sm_scale, block=block,
-                               heads=heads, index_heads=index_heads)
+    rows = fit_block(block, KL_ROWS)
+    kernel = functools.partial(
+        _kl_kernel, sm_scale=sm_scale, block=block, heads=heads,
+        index_heads=index_heads, grads=grads, rows=rows)
 
     def low(qb, kb):        # a block above the diagonal repeats the last
         return jnp.minimum(kb, qb)
 
+    def of_rows(b, qb, kb, h):
+        return b, qb, 0
+
     vma = _vma(q, k, qi, ki, w)
-    grad, rows = pl.pallas_call(
+    out_specs = [pl.BlockSpec((1, block, 1), of_rows)]
+    out_shape = [_sds((batch, seq, 1), jnp.float32, vma)]
+    scratch = [pltpu.VMEM((block, block), jnp.float32)]
+    if grads:
+        # dqi and dw a row block's, written when its last key block is
+        # done; dki the whole sequence's, written once at the end, so the
+        # row blocks run in order; each index head's scores of a slice of
+        # rows, formed for I and read again for d I
+        out_specs += [
+            pl.BlockSpec((1, index_heads, block, dim),
+                         lambda b, qb, kb, h: (b, 0, qb, 0)),
+            pl.BlockSpec((1, block, index_heads), of_rows),
+            pl.BlockSpec((1, dim, seq), lambda b, qb, kb, h: (b, 0, 0)),
+        ]
+        out_shape += [_sds(qi.shape, jnp.float32, vma),
+                      _sds(w.shape, jnp.float32, vma),
+                      _sds((batch, dim, seq), jnp.float32, vma)]
+        scratch.append(pltpu.VMEM((index_heads, rows, block), jnp.float32))
+    return pl.pallas_call(
         kernel,
         grid=(batch, blocks, blocks, heads),
         in_specs=[
@@ -351,147 +413,20 @@ def _kl_call(q, k, lse, mask, qi, w, ki, lse_i, *, sm_scale: float,
                          lambda b, qb, kb, h: (b, qb, low(qb, kb))),
             pl.BlockSpec((1, index_heads, block, dim),
                          lambda b, qb, kb, h: (b, 0, qb, 0)),
-            pl.BlockSpec((1, block, index_heads),
-                         lambda b, qb, kb, h: (b, qb, 0)),
+            pl.BlockSpec((1, block, index_heads), of_rows),
             pl.BlockSpec((1, block, dim),
                          lambda b, qb, kb, h: (b, low(qb, kb), 0)),
-            pl.BlockSpec((1, block, 1), lambda b, qb, kb, h: (b, qb, 0)),
+            pl.BlockSpec((1, block, 1), of_rows),
         ],
-        out_specs=[
-            pl.BlockSpec((1, block, block), lambda b, qb, kb, h: (b, qb, kb)),
-            pl.BlockSpec((1, block, 1), lambda b, qb, kb, h: (b, qb, 0)),
-        ],
-        out_shape=[_sds((batch, seq, seq), jnp.bfloat16, vma),
-                   _sds((batch, seq, 1), jnp.float32, vma)],
-        scratch_shapes=[pltpu.VMEM((block, block), jnp.float32)],
-        compiler_params=_params("parallel", "parallel", "arbitrary",
-                                "arbitrary"),
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=scratch,
+        compiler_params=_params("parallel",
+                                "arbitrary" if grads else "parallel",
+                                "arbitrary", "arbitrary"),
         interpret=interpret,
         name=KERNEL_KL,
     )(q, k, lse, mask, qi, w, ki, lse_i)
-    return grad, rows
-
-
-# ===========================================================================
-# indexer_dq / indexer_dk: d KL / d I back to qi, w and ki
-# ===========================================================================
-
-
-def _weights_of(grad, w, s, j: int):
-    """One head's part of d I: (d w[:, j] rows, the matmuls' left operand
-    `grad x w[:, j]` where the head's score is positive)."""
-    dw = jnp.sum(grad * jnp.maximum(s, 0.0), axis=1, keepdims=True)
-    return dw, jnp.where(s > 0.0, grad * w[:, j:j + 1], 0.0)
-
-
-def _dq_kernel(grad_ref, qi_ref, w_ref, ki_ref, dqi_ref, dw_ref,
-               dq_acc, dw_acc, *, blocks: int, index_heads: int):
-    qb, kb = pl.program_id(1), pl.program_id(2)
-
-    @pl.when(kb == 0)
-    def _init():
-        dq_acc[:] = jnp.zeros_like(dq_acc)
-        dw_acc[:] = jnp.zeros_like(dw_acc)
-
-    @pl.when(kb <= qb)
-    def _block():
-        grad = grad_ref[0].astype(jnp.float32)
-        w, k = w_ref[0], ki_ref[0]
-        lane = jax.lax.broadcasted_iota(jnp.int32, dw_acc.shape, 1)
-        dw = jnp.zeros(dw_acc.shape, jnp.float32)
-        for j in range(index_heads):
-            s = _scores_of(qi_ref[0, j], k)
-            dw_j, left = _weights_of(grad, w, s, j)
-            dw = dw + jnp.where(lane == j, dw_j, 0.0)
-            dq_acc[j] += _dot(left.astype(k.dtype), k, ((1,), (0,)))
-        dw_acc[:] += dw
-
-    @pl.when(kb == blocks - 1)
-    def _finalize():
-        dqi_ref[0] = dq_acc[:]
-        dw_ref[0] = dw_acc[:]
-
-
-def _dk_kernel(grad_ref, qi_ref, w_ref, ki_ref, dki_ref, dk_acc,
-               *, blocks: int, index_heads: int):
-    kb, qb = pl.program_id(1), pl.program_id(2)
-
-    @pl.when(qb == 0)
-    def _init():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
-
-    @pl.when(kb <= qb)
-    def _block():
-        grad = grad_ref[0].astype(jnp.float32)
-        w, k = w_ref[0], ki_ref[0]
-        total = jnp.zeros(dk_acc.shape, jnp.float32)
-        for j in range(index_heads):
-            q = qi_ref[0, j]
-            s = _scores_of(q, k)
-            _, left = _weights_of(grad, w, s, j)
-            total = total + _dot(left.astype(q.dtype), q, ((0,), (0,)))
-        dk_acc[:] += total
-
-    @pl.when(qb == blocks - 1)
-    def _finalize():
-        dki_ref[0] = dk_acc[:]
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _grad_call(grad, qi, w, ki, *, interpret: bool):
-    batch, index_heads, seq, dim = qi.shape
-    block = fit_block(seq, GRAD_BLOCK)
-    blocks = seq // block
-    vma = _vma(grad, qi, ki, w)
-    dqi, dw = pl.pallas_call(
-        functools.partial(_dq_kernel, blocks=blocks,
-                          index_heads=index_heads),
-        grid=(batch, blocks, blocks),
-        in_specs=[
-            pl.BlockSpec((1, block, block),
-                         lambda b, qb, kb: (b, qb, jnp.minimum(kb, qb))),
-            pl.BlockSpec((1, index_heads, block, dim),
-                         lambda b, qb, kb: (b, 0, qb, 0)),
-            pl.BlockSpec((1, block, index_heads),
-                         lambda b, qb, kb: (b, qb, 0)),
-            pl.BlockSpec((1, block, dim),
-                         lambda b, qb, kb: (b, jnp.minimum(kb, qb), 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, index_heads, block, dim),
-                         lambda b, qb, kb: (b, 0, qb, 0)),
-            pl.BlockSpec((1, block, index_heads),
-                         lambda b, qb, kb: (b, qb, 0)),
-        ],
-        out_shape=[_sds(qi.shape, jnp.float32, vma),
-                   _sds(w.shape, jnp.float32, vma)],
-        scratch_shapes=[pltpu.VMEM((index_heads, block, dim), jnp.float32),
-                        pltpu.VMEM((block, index_heads), jnp.float32)],
-        compiler_params=_params("parallel", "parallel", "arbitrary"),
-        interpret=interpret,
-        name=KERNEL_DQ,
-    )(grad, qi, w, ki)
-    dki = pl.pallas_call(
-        functools.partial(_dk_kernel, blocks=blocks,
-                          index_heads=index_heads),
-        grid=(batch, blocks, blocks),
-        in_specs=[
-            pl.BlockSpec((1, block, block),
-                         lambda b, kb, qb: (b, jnp.maximum(qb, kb), kb)),
-            pl.BlockSpec((1, index_heads, block, dim),
-                         lambda b, kb, qb: (b, 0, jnp.maximum(qb, kb), 0)),
-            pl.BlockSpec((1, block, index_heads),
-                         lambda b, kb, qb: (b, jnp.maximum(qb, kb), 0)),
-            pl.BlockSpec((1, block, dim), lambda b, kb, qb: (b, kb, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block, dim), lambda b, kb, qb: (b, kb, 0)),
-        out_shape=_sds(ki.shape, jnp.float32, vma),
-        scratch_shapes=[pltpu.VMEM((block, dim), jnp.float32)],
-        compiler_params=_params("parallel", "parallel", "arbitrary"),
-        interpret=interpret,
-        name=KERNEL_DK,
-    )(grad, qi, w, ki)
-    return dqi, dw, dki
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(8,))
@@ -499,19 +434,19 @@ def indexer_kl(qi, ki, w, mask, lse_i, q, k, lse, sm_scale: float):
     """mean over batch and queries of KL(p_t || softmax_{S_t} I[t, :]), a
     function of qi (b, J, S, D), ki and w alone: the mask, the main
     attention's q, k and log-sum-exp are constants of it."""
-    _, rows = _kl_call(q, k, lse, mask, qi, w, ki, lse_i, sm_scale=sm_scale,
-                       interpret=not on_tpu())
+    (rows,) = _kl_call(q, k, lse, mask, qi, w, ki, lse_i, sm_scale=sm_scale,
+                       grads=False, interpret=not on_tpu())
     return jnp.mean(rows)
 
 
 def _indexer_kl_fwd(qi, ki, w, mask, lse_i, q, k, lse, sm_scale):
-    interpret = not on_tpu()
-    grad, rows = _kl_call(q, k, lse, mask, qi, w, ki, lse_i,
-                          sm_scale=sm_scale, interpret=interpret)
-    dqi, dw, dki = _grad_call(grad, qi, w, ki, interpret=interpret)
+    rows, dqi, dw, dki = _kl_call(q, k, lse, mask, qi, w, ki, lse_i,
+                                  sm_scale=sm_scale, grads=True,
+                                  interpret=not on_tpu())
+    dki = dki.transpose(0, 2, 1)
     scale = 1.0 / rows.size
     # kept in the dtypes their cotangents go back in; the rule's only
-    # residuals: a block's recomputation that keeps them runs neither call
+    # residuals: a block's recomputation that keeps them runs no kernel
     return jnp.mean(rows), tuple(
         checkpoint_name(g, Kept.KL_GRADS)
         for g in ((dqi * scale).astype(qi.dtype),

@@ -102,14 +102,15 @@ SPARSE = dict(heads=32, kv_heads=4, seq=16384, d=128, index_heads=16,
 
 
 @pytest.mark.parametrize("kernel", ["indexer_select", "sparse_attn",
-                                    "indexer_kl", "indexer_dq_dk"])
+                                    "indexer_kl", "indexer_kl_grads"])
 def test_sparse_attention_kernels_at_the_published_widths(
         topo, one_chip, chip_path, kernel):
-    """Selection, masked attention (forward and both backward kernels), the
-    KL kernel and its two gradient kernels at 16,384 x 16,384 with 32 heads
-    of 128 and 16 index heads of 64: the VMEM each asks for (a row block's
-    scores for the bisection, a 1024 x 1024 float32 sum over the heads) and
-    every slice of them is the chip compiler's to refuse."""
+    """Selection, masked attention (forward and both backward kernels) and
+    the KL kernel, alone and with the gradient it takes back to qi, w and
+    ki, at 16,384 x 16,384 with 32 heads of 128 and 16 index heads of 64:
+    the VMEM each asks for (a row block's scores for the bisection, a
+    1024 x 1024 float32 sum over the heads, d KL / d ki over the whole
+    sequence) and every slice of them is the chip compiler's to refuse."""
     import importlib
 
     kernels = importlib.import_module(
@@ -140,17 +141,20 @@ def test_sparse_attention_kernels_at_the_published_widths(
                               mask)
         names = ("sparse_attn_fwd", "sparse_attn_dq", "sparse_attn_dkv")
         assert "flash_attn_" not in text
-    elif kernel == "indexer_kl":
+    else:
+        grads = kernel == "indexer_kl_grads"
         text = _compiled_text(
-            lambda *a: kernels._kl_call(*a, sm_scale=0.088, interpret=False),
+            lambda *a: kernels._kl_call(*a, sm_scale=0.088, grads=grads,
+                                        interpret=False),
             q, kv, of((1, s["heads"], seq, 1), jnp.float32), mask, qi, w, ki,
             rows)
         names = (kernels.KERNEL_KL,)
-    else:
-        text = _compiled_text(
-            lambda *a: kernels._grad_call(*a, interpret=False),
-            of((1, seq, seq)), qi, w, ki)
-        names = (kernels.KERNEL_DQ, kernels.KERNEL_DK)
+        # the rows, and with `grads` d qi, d w, d ki^T: nothing (seq, seq)
+        launch = re.search(rf"%{kernels.KERNEL_KL}[.\d]* = (.*?) custom-call\(",
+                           text).group(1)
+        assert re.findall(r"\w+\[[\d,]*\]", launch) == (
+            ["f32[1,16384,1]", "f32[1,16,16384,64]", "f32[1,16384,16]",
+             "f32[1,64,16384]"] if grads else ["f32[1,16384,1]"])
     for name in names:
         assert re.search(rf"%{name}[.\d]* = ", text), name
     assert text.count("tpu_custom_call") >= len(names)
@@ -225,9 +229,10 @@ def test_a_recomputed_block_launches_none_of_its_attentions_kernels_again(
     assert set(counts["recomputed_kernels"]) == {norms.KERNEL_FWD}
     assert counts["remat_instructions"] == 0
     for kernel in ("indexer_select", "sparse_attn_fwd", "indexer_kl",
-                   "indexer_dq", "indexer_dk", "sparse_attn_dq",
-                   "sparse_attn_dkv"):
+                   "sparse_attn_dq", "sparse_attn_dkv"):
         assert len(re.findall(rf"%{kernel}[.\d]* = ", text)) == 1, kernel
+    # the KL term's gradient is the KL kernel's: no kernel of its own
+    assert "%indexer_dq" not in text and "%indexer_dk" not in text
 
 
 @pytest.mark.parametrize("hidden", [2048, 4096])

@@ -35,12 +35,13 @@ kernels = importlib.import_module("dlrover_tpu.ops.sparse_attention_kernels")
 @pytest.fixture()
 def small_blocks(monkeypatch):
     """Block sizes at which a 256-long sequence takes several blocks of
-    every kernel (they are read when a call is traced)."""
+    every kernel, and the KL kernel two slices of rows a block (they are
+    read when a call is traced)."""
     jax.clear_caches()
     monkeypatch.setattr(kernels, "SELECT_BLOCK_Q", 64)
     monkeypatch.setattr(kernels, "SELECT_BLOCK_K", 128)
     monkeypatch.setattr(kernels, "KL_BLOCK", 128)
-    monkeypatch.setattr(kernels, "GRAD_BLOCK", 64)
+    monkeypatch.setattr(kernels, "KL_ROWS", 64)
     monkeypatch.setattr(kernels, "DEFAULT_BLOCK_Q", 128)
     monkeypatch.setattr(kernels, "DEFAULT_BLOCK_K", 128)
     yield
@@ -106,8 +107,8 @@ def test_the_kernels_select_what_the_plain_form_selects(small_blocks):
 
 def test_the_kernels_against_the_plain_form(small_blocks):
     """Output, KL term and every gradient, float32 operands: the Pallas
-    form (masked flash kernels, `indexer_kl`, `indexer_dq`, `indexer_dk`)
-    is the plain form's mathematics."""
+    form (masked flash kernels, `indexer_kl` with its gradient) is the
+    plain form's mathematics."""
     seq, topk = 256, 48
     operands = _operands(seq)
 
@@ -135,6 +136,68 @@ def test_the_kernels_against_the_plain_form(small_blocks):
                                        impl="kernel")[1]
     grads = jax.grad(kl_only, argnums=(0, 1, 2))(*operands)
     assert all(float(jnp.max(jnp.abs(g))) == 0.0 for g in grads)
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside its equations."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for param in eqn.params.values():
+            inner = getattr(param, "jaxpr", param)
+            if hasattr(inner, "eqns"):
+                yield from _equations(inner)
+
+
+@pytest.mark.parametrize("case", ["float32", "first_block_empty",
+                                  "bfloat16"])
+def test_the_kl_kernel_forms_the_objective_and_its_gradient(case,
+                                                            small_blocks):
+    """`indexer_kl` takes d KL / d I back to qi, w and ki in the launch that
+    forms the KL rows: its value and its three gradients are `jax.grad` of
+    the plain form's KL term, over three row blocks (d KL / d ki summed
+    across them), with a row block whose first key block holds no selected
+    key, and in bfloat16 (d KL / d I cast for the products only). Outside
+    differentiation the launch has the rows for its only output."""
+    seq, topk = 384, 48
+    dtype = jnp.bfloat16 if case == "bfloat16" else jnp.float32
+    q, k, v, qi, ki, w = _operands(seq, dtype, seed=3)
+    if case == "first_block_empty":
+        # keys 0..127 score 0 under every head; later keys positive or not
+        qi, w = jnp.abs(qi), jnp.abs(w)
+        ki = ki.at[:, :128].set(-jnp.abs(ki[:, :128]))
+        chosen = np.asarray(sparse.selection(qi, ki, w, topk, impl="xla"))
+        assert not chosen[:, 256:, :128].any()
+        assert chosen[:, 128:256, :128].any()
+
+    def kl(impl):
+        def of(qi, ki, w):
+            return sparse.sparse_attention(q, k, v, qi, ki, w, topk,
+                                           impl=impl)[1]
+        return jax.value_and_grad(of, argnums=(0, 1, 2))(qi, ki, w)
+
+    (kl_x, grads_x), (kl_k, grads_k) = kl("xla"), kl("kernel")
+    exact = dtype == jnp.float32
+    assert float(kl_k) == pytest.approx(float(kl_x), rel=1e-5 if exact
+                                        else 2e-2)
+    for name, mine, plain in zip(("qi", "ki", "w"), grads_k, grads_x):
+        assert mine.dtype == plain.dtype, name
+        mine, plain = (np.asarray(g, np.float32) for g in (mine, plain))
+        scale = float(np.max(np.abs(plain)))
+        assert scale > 0, name
+        np.testing.assert_allclose(mine, plain, err_msg=name,
+                                   atol=(1e-5 if exact else 3e-2) * scale)
+    # the primal alone: one output, no gradient formed and thrown away
+    qi_t = qi.transpose(0, 2, 1, 3)
+    mask, lse_i = kernels.indexer_select(qi, ki, w, topk)
+    _, lse = kernels.masked_attention(q, k, v, mask, 32 ** -0.5)
+    args = (qi_t, ki, w.astype(jnp.float32), mask, lse_i, q, k, lse)
+    launches = [eqn for eqn in _equations(jax.make_jaxpr(
+        lambda *a: kernels.indexer_kl(*a, 32 ** -0.5))(*args).jaxpr)
+        if eqn.primitive.name == "pallas_call"]
+    assert [(eqn.params["name"], len(eqn.outvars)) for eqn in launches] == [
+        (kernels.KERNEL_KL, 1)]
+    assert float(kernels.indexer_kl(*args, 32 ** -0.5)) == pytest.approx(
+        float(kl_k), rel=1e-6)
 
 
 def test_a_row_with_no_selected_key_in_its_first_block(small_blocks):
@@ -324,9 +387,10 @@ def test_the_kernels_run_once_under_a_policy_that_keeps_their_outputs(
     """The Pallas form under `jax.checkpoint`: with `Kept`'s names kept,
     the selection, the masked forward and the KL kernel each stand once in
     the gradient's program (the tags sit inside the `custom_vjp`s' forward
-    rules, where outputs and residuals are the same arrays), and the KL
-    term's two gradient kernels once under either policy; the gradients
-    are the plain ones to the last digit."""
+    rules, where outputs and residuals are the same arrays; the KL kernel
+    forms the term's gradient in the same launch), and the attention's two
+    gradient kernels once under either policy; the gradients are the plain
+    ones to the last digit."""
     operands = _operands(256)
 
     def objective(*operands):
@@ -341,9 +405,9 @@ def test_the_kernels_run_once_under_a_policy_that_keeps_their_outputs(
     for kernel in (kernels.KERNEL_SELECT, "sparse_attn_fwd",
                    kernels.KERNEL_KL):
         assert launches[kernel] == forward_launches, kernel
-    for kernel in (kernels.KERNEL_DQ, kernels.KERNEL_DK, "sparse_attn_dq",
-                   "sparse_attn_dkv"):
+    for kernel in ("sparse_attn_dq", "sparse_attn_dkv"):
         assert launches[kernel] == 1, kernel
+    assert not launches["indexer_dq"] and not launches["indexer_dk"]
     assert all(launches[name] for name in Kept.ALL)     # every tag is there
     for mine, plain in zip(kept(*operands),
                            jax.grad(objective, argnums=every)(*operands)):
