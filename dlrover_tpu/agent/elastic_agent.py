@@ -213,6 +213,10 @@ class ElasticAgent:
         self._proc: Optional[subprocess.Popen] = None
         self.last_world: Dict[int, int] = {}
         self.last_round = -1
+        # the last completed rendezvous span's context: the worker it
+        # spawns parents its root spans under it (one trace an
+        # incarnation, obs/spans.py TRACE_PARENT_ENV)
+        self._rdzv_context: Dict[str, str] = {}
         self._monitors: List = []
         self._hang_detector = None
         # set by the HangingDetector thread; consumed (and acted on) only
@@ -302,6 +306,7 @@ class ElasticAgent:
                 )
                 if world and self._client.node_rank in world:
                     self.last_world, self.last_round = world, rdzv_round
+                    self._rdzv_context = rdzv_span.context()
                     rdzv_span.set_attr("round", rdzv_round)
                     rdzv_span.set_attr("world_size", len(world))
                     return rdzv_round, world
@@ -371,6 +376,9 @@ class ElasticAgent:
             # gradient sync and slice-targeted chaos faults
             NodeEnv.SLICE_ID: str(slice_id),
         })
+        if self._rdzv_context:
+            env[obs.TRACE_PARENT_ENV] = obs.encode_context(
+                self._rdzv_context)
         # Persistent XLA compile cache shared across worker restarts AND
         # across launches: a respawned worker re-lowers the same programs
         # and loads them instead of compiling — the dominant cost of a
@@ -1126,22 +1134,28 @@ def apply_jax_platform_env() -> None:
 
 
 def init_distributed() -> None:
-    """Training-process entry: initialize jax.distributed from the agent's
-    env contract. No-op single-process (standalone runs)."""
-    apply_jax_platform_env()
-    world_size = int(os.getenv(NodeEnv.WORLD_SIZE, "1"))
-    if world_size <= 1:
-        return
-    import jax
+    """Training-process entry: start JAX's backend under the agent's env
+    contract, joining jax.distributed where the world has more than one
+    process, inside a ``backend_init`` span (``platform``, ``devices``)."""
+    with obs.span("backend_init") as backend_span:
+        apply_jax_platform_env()
+        import jax
 
-    # Default 300 s coordinator-registration deadline is too tight when
-    # several probe/worker processes cold-compile on a loaded shared host
-    # (observed: DEADLINE_EXCEEDED on CoordinationService/RegisterTask) —
-    # give registration the same generous budget the agent gives compiles.
-    init_timeout = int(os.getenv("DLROVER_TPU_DIST_INIT_TIMEOUT", "600"))
-    jax.distributed.initialize(
-        coordinator_address=os.environ[NodeEnv.COORDINATOR_ADDR],
-        num_processes=world_size,
-        process_id=int(os.environ[NodeEnv.PROCESS_ID]),
-        initialization_timeout=init_timeout,
-    )
+        world_size = int(os.getenv(NodeEnv.WORLD_SIZE, "1"))
+        if world_size > 1:
+            # Default 300 s coordinator-registration deadline is too
+            # tight when several probe/worker processes cold-compile on a
+            # loaded shared host (observed: DEADLINE_EXCEEDED on
+            # CoordinationService/RegisterTask) — give registration the
+            # same generous budget the agent gives compiles.
+            init_timeout = int(os.getenv("DLROVER_TPU_DIST_INIT_TIMEOUT",
+                                         "600"))
+            jax.distributed.initialize(
+                coordinator_address=os.environ[NodeEnv.COORDINATOR_ADDR],
+                num_processes=world_size,
+                process_id=int(os.environ[NodeEnv.PROCESS_ID]),
+                initialization_timeout=init_timeout,
+            )
+        devices = jax.devices()
+        backend_span.set_attr("platform", devices[0].platform)
+        backend_span.set_attr("devices", len(devices))

@@ -48,11 +48,14 @@ from dlrover_tpu.obs.profiler import (
     write_profile_request,
 )
 from dlrover_tpu.obs.spans import (
+    TRACE_PARENT_ENV,
     Span,
     SpanExporter,
     add_span_sink,
+    attach,
     current_context,
     current_span,
+    encode_context,
     record_span,
     remove_span_sink,
     span,
@@ -76,6 +79,7 @@ __all__ = [
     "BUCKETS",
     "DEFAULT_BUCKETS",
     "FLIGHT_DIR_ENV",
+    "TRACE_PARENT_ENV",
     "TRACE_PHASES",
     "ClockSync",
     "DeviceTelemetry",
@@ -96,8 +100,10 @@ __all__ = [
     "TsdbCollector",
     "device",
     "add_span_sink",
+    "attach",
     "current_context",
     "current_span",
+    "encode_context",
     "get_flight_recorder",
     "get_registry",
     "load_timeline",

@@ -17,9 +17,11 @@ nothing disables sampling after one probe — no forever-0 series, no
 per-step cost.
 
 Compile events: :func:`record_compile_event` stamps one flight event +
-gauges per AOT compile with the wall time and the compiled step's
-``cost_analysis`` FLOPs/bytes — the measured program cost the MFU
-cross-check and the planner calibration read, not the analytic guess.
+gauges per AOT compile with the compiled step's ``cost_analysis``
+FLOPs/bytes — the measured program cost the MFU cross-check and the
+planner calibration read, not the analytic guess. The compile's time is
+the AOT ``recompile`` span's; :func:`compile_cache_reads` says whether
+the persistent compile cache answered it.
 
 stdlib-only at import time (jax is imported lazily inside the sampler),
 so the master, tools and jax-free test workers import this bare.
@@ -28,6 +30,7 @@ so the master, tools and jax-free test workers import this bare.
 from __future__ import annotations
 
 import threading
+from contextlib import contextmanager
 from typing import Any, Callable, Dict, List, Optional
 
 # a watermark move smaller than this is allocator noise, not a rise
@@ -200,27 +203,21 @@ def cost_summary(compiled) -> Dict[str, float]:
     return out
 
 
-def record_compile_event(wall_s: float, compiled=None,
-                         kind: str = "aot",
+def record_compile_event(compiled=None, kind: str = "aot",
                          mesh: Optional[Dict[str, Any]] = None) -> Dict[
                              str, float]:
-    """One compile's device truth into the flight recorder + gauges:
-    wall time plus the compiled step's cost-analysis FLOPs/bytes. The
-    event is what ``tools/top.py --flight`` and the calibration table
-    read; returns the cost summary so callers reuse it."""
+    """One compile's device truth into the flight recorder + gauges: the
+    compiled step's cost-analysis FLOPs/bytes. Returns the cost summary
+    so callers reuse it."""
     from dlrover_tpu.obs.flight_recorder import get_flight_recorder
     from dlrover_tpu.obs.metrics import get_registry
 
     costs = cost_summary(compiled)
     get_flight_recorder().record_event(
-        "compile_event", kind=kind, wall_s=round(float(wall_s), 3),
+        "compile_event", kind=kind,
         flops=costs["flops"], bytes_accessed=costs["bytes_accessed"],
         mesh=dict(mesh) if mesh else None)
     registry = get_registry()
-    registry.gauge(
-        "dlrover_tpu_compile_wall_seconds",
-        "Wall-clock of the last train-step compile",
-        labelnames=("kind",)).labels(kind=kind).set(float(wall_s))
     if costs["flops"] > 0:
         registry.gauge(
             "dlrover_tpu_compiled_step_flops",
@@ -232,3 +229,57 @@ def record_compile_event(wall_s: float, compiled=None,
             "XLA cost-analysis bytes accessed of the last compiled "
             "train step").set(costs["bytes_accessed"])
     return costs
+
+
+# JAX's persistent-compile-cache events: a compile that asked the cache,
+# one it answered, one written after compiling anew
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/compile_requests_use_cache": "requests",
+    "/jax/compilation_cache/cache_hits": "hits",
+    "/jax/compilation_cache/cache_misses": "misses",
+}
+# per thread: the AOT compile runs on a thread of its own while the main
+# thread compiles the state's init
+_cache_counts = threading.local()
+_listener_lock = threading.Lock()
+_listening = False
+
+
+def _count_cache_event(event: str, **_: Any) -> None:
+    field = _CACHE_EVENTS.get(event)
+    if field is not None:
+        setattr(_cache_counts, field, getattr(_cache_counts, field, 0) + 1)
+
+
+def _listen_for_cache_events() -> None:
+    """One listener a process (JAX keeps its listeners for good)."""
+    global _listening
+    with _listener_lock:
+        if not _listening:
+            import jax
+
+            jax.monitoring.register_event_listener(_count_cache_event)
+            _listening = True
+
+
+@contextmanager
+def compile_cache_reads():
+    """Yields a dict that, once the block is left, says how this
+    thread's compiles inside it met the persistent compile cache:
+    ``cache`` is ``off`` where none asked it, ``hit`` where it answered
+    every one, else ``miss``; ``cache_hits`` and ``cache_misses`` count
+    JAX's own events (a miss counts where its entry was written)."""
+    _listen_for_cache_events()
+    before = {field: getattr(_cache_counts, field, 0)
+              for field in _CACHE_EVENTS.values()}
+    reads: Dict[str, Any] = {}
+    try:
+        yield reads
+    finally:
+        seen = {field: getattr(_cache_counts, field, 0) - count
+                for field, count in before.items()}
+        reads["cache"] = ("off" if not seen["requests"] else
+                          "hit" if seen["hits"] >= seen["requests"]
+                          else "miss")
+        reads["cache_hits"] = seen["hits"]
+        reads["cache_misses"] = seen["misses"]

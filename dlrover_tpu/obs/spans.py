@@ -11,7 +11,11 @@ master).
 Cross-process parenting: `current_context()` serializes the active
 span's identity into a small dict that travels inside a control-plane
 message; the receiving side passes it as ``parent=`` so the master's
-rendezvous span and the agent's join span share one trace.
+rendezvous span and the agent's join span share one trace. A process
+can inherit a parent as a whole: ``$DLROVER_TPU_TRACE_PARENT``
+(``trace_id:span_id``, what the agent hands the worker it spawns) parents
+every span that has no other, so one incarnation is one trace. A thread
+started on another's behalf takes the starter's context with `attach`.
 
 stdlib-only by design (imported by agent/worker/master alike).
 """
@@ -26,8 +30,24 @@ from contextlib import contextmanager
 from typing import Any, Callable, Dict, List, Optional
 
 
+TRACE_PARENT_ENV = "DLROVER_TPU_TRACE_PARENT"
+
+
 def _new_id() -> str:
     return uuid.uuid4().hex[:16]
+
+
+def encode_context(context: Dict[str, str]) -> str:
+    """A context as the value of ``TRACE_PARENT_ENV``."""
+    return f"{context['trace_id']}:{context['span_id']}"
+
+
+def _inherited_context() -> Optional[Dict[str, str]]:
+    trace_id, sep, span_id = os.environ.get(
+        TRACE_PARENT_ENV, "").partition(":")
+    if not (sep and trace_id):
+        return None
+    return {"trace_id": trace_id, "span_id": span_id}
 
 
 class Span:
@@ -132,13 +152,27 @@ def current_context() -> Optional[Dict[str, str]]:
 def _resolve_parent(parent: Optional[Dict[str, str]],
                     stack: List[Span]) -> tuple:
     """(trace_id, parent_id): explicit remote context wins, else the
-    thread's current span, else a fresh trace."""
+    thread's current span, else the context `attach` gave the thread,
+    else the one the process inherited, else a fresh trace."""
+    parent = (parent or (stack and stack[-1].context())
+              or getattr(_tls, "attached", None) or _inherited_context())
     if parent:
         return parent.get("trace_id") or _new_id(), parent.get(
             "span_id", "")
-    if stack:
-        return stack[-1].trace_id, stack[-1].span_id
     return _new_id(), ""
+
+
+@contextmanager
+def attach(context: Optional[Dict[str, str]]):
+    """Spans this thread opens with no parent of their own nest under
+    ``context`` (another thread's `current_context()`) inside the block:
+    a thread's span stack starts empty."""
+    previous = getattr(_tls, "attached", None)
+    _tls.attached = context
+    try:
+        yield
+    finally:
+        _tls.attached = previous
 
 
 @contextmanager

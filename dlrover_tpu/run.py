@@ -18,6 +18,7 @@ import os
 import sys
 from typing import List, Optional, Tuple
 
+from dlrover_tpu import obs
 from dlrover_tpu.agent.elastic_agent import ElasticAgent, WorkerSpec
 from dlrover_tpu.agent.master_client import MasterClient
 from dlrover_tpu.common.constants import DefaultValues, NodeEnv
@@ -72,9 +73,19 @@ def parse_args(argv: Optional[List[str]] = None) -> argparse.Namespace:
 
 
 def _detect_devices() -> int:
+    """The local chip count, inside a ``device_probe`` span (``devices``,
+    ``source``: ``env`` or ``probe``)."""
+    with obs.span("device_probe") as probe_span:
+        devices, source = _probe_devices()
+        probe_span.set_attr("devices", devices)
+        probe_span.set_attr("source", source)
+    return devices
+
+
+def _probe_devices() -> Tuple[int, str]:
     env = os.getenv(NodeEnv.DEVICES_PER_NODE)
     if env:
-        return int(env)
+        return int(env), "env"
     # Detect in a short-lived subprocess: importing jax here would
     # initialize the TPU runtime in the AGENT process and hold the chips,
     # so the spawned training process could never acquire them.
@@ -87,13 +98,13 @@ def _detect_devices() -> int:
             capture_output=True, text=True, timeout=120,
         )
         if out.returncode == 0:
-            return int(out.stdout.strip().splitlines()[-1])
+            return int(out.stdout.strip().splitlines()[-1]), "probe"
         reason = out.stderr.strip()[-400:]
     except (subprocess.TimeoutExpired, OSError, ValueError,
             IndexError) as e:
         reason = repr(e)
     if os.getenv("JAX_PLATFORMS", "") == "cpu":
-        return 1
+        return 1, "probe"
     # an accelerator that cannot be probed must not be read as "one
     # device": the worker would then train on whatever it finds
     raise RuntimeError(f"device probe failed: {reason}")
@@ -108,7 +119,8 @@ def run(args: argparse.Namespace) -> int:
 
         master = JobMaster(min_nodes=min_nodes, max_nodes=max_nodes,
                            node_unit=args.node_unit, host="127.0.0.1")
-        master.prepare()
+        with obs.span("master_prepare"):
+            master.prepare()
         master_addr = master.addr
         logger.info("standalone master at %s", master_addr)
     if not master_addr:

@@ -723,17 +723,14 @@ class ElasticTrainLoop:
         if compiled is None:
             return
         self._flops_cross_checked = True
-        # one compile event per AOT build: wall time + the compiled
-        # step's cost-analysis FLOPs/bytes into the flight record and
-        # gauges (obs/device.py) — the device truth behind the MFU
-        # cross-check below and the calibration table's predictions
+        # one compile event per AOT build: the compiled step's
+        # cost-analysis FLOPs/bytes into the flight record and gauges
+        # (obs/device.py) — the device truth behind the MFU cross-check
+        # below and the calibration table's predictions (the compile's
+        # time is the AOT `recompile` span's)
         try:
-            timings = getattr(self.trainer, "precompile_timings", {})
             obs.device.record_compile_event(
-                wall_s=float(timings.get("trace_lower_s", 0.0))
-                + float(timings.get("compile_or_cache_load_s", 0.0)),
-                compiled=compiled, kind="aot",
-                mesh=dict(self.mesh.shape))
+                compiled=compiled, kind="aot", mesh=dict(self.mesh.shape))
         except Exception:  # noqa: BLE001 — telemetry, never the loop
             logger.warning("compile event record failed", exc_info=True)
         measured = obs.mfu.cost_analysis_flops(compiled)
@@ -804,12 +801,14 @@ class ElasticTrainLoop:
             compile_thread = None
             if (self.config.overlap_restore_compile
                     and hasattr(self.trainer, "precompile")):
+                # the thread's AOT `recompile` span nests under this one
                 compile_thread = threading.Thread(
-                    target=self._precompile_quietly, daemon=True)
+                    target=self._precompile_quietly,
+                    args=(restore_span.context(),), daemon=True)
                 t_compile_start = _time.monotonic()
                 compile_thread.start()
             if self.checkpointer is None:
-                state, step = self.trainer.init(rng), 0
+                state, step = self._init_state(rng), 0
                 self.last_restore_source = "init"
             else:
                 t0 = _time.monotonic()
@@ -847,7 +846,7 @@ class ElasticTrainLoop:
                                               {}).items():
                         timings[f"restore_{key}"] = value
                 if restored is None:
-                    state, step = self.trainer.init(rng), 0
+                    state, step = self._init_state(rng), 0
                     self.last_restore_source = "init"
                 else:
                     self.last_restore_source = source
@@ -937,9 +936,22 @@ class ElasticTrainLoop:
         self._flush_telemetry()
         return state, step
 
-    def _precompile_quietly(self) -> None:
+    def _init_state(self, rng):
+        """Weights from the seed, ready on the device, inside a
+        ``state_init`` span (``bytes``: the state's). The wait costs
+        nothing: the first step would wait for them, and the AOT compile
+        keeps running on its thread meanwhile."""
+        with obs.span("state_init") as init_span:
+            state = self.trainer.init(rng)
+            jax.block_until_ready(state)
+            init_span.set_attr("bytes", sum(
+                leaf.nbytes for leaf in jax.tree_util.tree_leaves(state)))
+        return state
+
+    def _precompile_quietly(self, parent: Dict[str, str]) -> None:
         try:
-            self.trainer.precompile()
+            with obs.attach(parent):
+                self.trainer.precompile()
         except Exception:
             # AOT is an optimization: the jitted path compiles on first
             # step regardless

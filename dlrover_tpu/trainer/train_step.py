@@ -169,12 +169,15 @@ class ShardedTrainer:
         with obs.span("recompile", {
                 "phase": "aot", "head_loss_path": self.head_loss_path,
                 "head_loss_slices": self.head_loss_slices}) as aot_span:
-            t0 = _time.monotonic()
-            lowered = self.step_fn.lower(
-                abstract, self.batch_abstract, self.batch_abstract)
-            t1 = _time.monotonic()
-            compiled = lowered.compile()
-            t2 = _time.monotonic()
+            with obs.device.compile_cache_reads() as cache:
+                t0 = _time.monotonic()
+                lowered = self.step_fn.lower(
+                    abstract, self.batch_abstract, self.batch_abstract)
+                t1 = _time.monotonic()
+                compiled = lowered.compile()
+                t2 = _time.monotonic()
+            for name, value in cache.items():
+                aot_span.set_attr(name, value)
             self.precompile_timings = {
                 "trace_lower_s": round(t1 - t0, 2),
                 "compile_or_cache_load_s": round(t2 - t1, 2),

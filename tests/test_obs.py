@@ -167,6 +167,50 @@ def test_span_error_status_and_sink():
     assert finished and finished[0].status == "error"
 
 
+def test_a_process_inherits_its_trace_from_the_environment(monkeypatch):
+    """A worker's root spans parent under the agent's rendezvous span
+    that the env names; explicit and nested parents still win."""
+    monkeypatch.setenv(obs.TRACE_PARENT_ENV, obs.encode_context(
+        {"trace_id": "feedbeef", "span_id": "0123abcd"}))
+    with obs.span("backend_init") as root:
+        with obs.span("nested") as nested:
+            pass
+    recorded = obs.record_span("train_window", 0.5)
+    with obs.span("remote", parent={"trace_id": "t2", "span_id": "s2"}) \
+            as remote:
+        pass
+    assert (root.trace_id, root.parent_id) == ("feedbeef", "0123abcd")
+    assert (recorded.trace_id, recorded.parent_id) == ("feedbeef",
+                                                       "0123abcd")
+    assert (nested.trace_id, nested.parent_id) == ("feedbeef", root.span_id)
+    assert (remote.trace_id, remote.parent_id) == ("t2", "s2")
+    for garbage in ("", "no-colon", ":no-trace"):
+        monkeypatch.setenv(obs.TRACE_PARENT_ENV, garbage)
+        with obs.span("fresh") as fresh:
+            pass
+        assert fresh.parent_id == "" and fresh.trace_id != "feedbeef"
+
+
+def test_attach_hands_a_thread_its_starters_context():
+    seen = {}
+
+    def compile_thread(context):
+        with obs.attach(context):
+            with obs.span("recompile") as s:
+                seen["ids"] = (s.trace_id, s.parent_id)
+        with obs.span("after") as s:
+            seen["after"] = s.parent_id
+
+    with obs.span("restore_or_init") as restore:
+        t = threading.Thread(target=compile_thread,
+                             args=(restore.context(),))
+        t.start()
+        t.join(timeout=10)
+    assert not t.is_alive()
+    assert seen["ids"] == (restore.trace_id, restore.span_id)
+    assert seen["after"] == ""      # detached once the block is left
+
+
 def test_join_rendezvous_span_parents_under_agent_trace():
     from dlrover_tpu.master.servicer import MasterServicer
 
@@ -417,6 +461,149 @@ def test_recompile_span_recorded_after_simulated_resize(cpu_devices,
     assert relower[0].duration_s > 0
     restores = [s for s in captured if s.name == "checkpoint_restore"]
     assert restores and restores[0].attrs["step"] == 2
+
+
+def test_fresh_weights_and_the_aot_compile_nest_under_restore_or_init(
+        cpu_devices, tmp_path):
+    """``state_init`` on the main thread and the AOT ``recompile`` on the
+    compile thread both parent under ``restore_or_init``; the compile says
+    how the persistent cache met it."""
+    captured = []
+    obs.add_span_sink(captured.append)
+    try:
+        _, loop, jax_mod = _make_loop(cpu_devices, tmp_path, 2)
+        state, start = loop.restore_or_init(jax_mod.random.PRNGKey(0))
+        loop.close()
+    finally:
+        obs.remove_span_sink(captured.append)
+    assert start == 0
+    by_name = {}
+    for s in captured:
+        by_name.setdefault((s.name, s.attrs.get("phase")), s)
+    restore = by_name[("restore_or_init", None)]
+    init = by_name[("state_init", None)]
+    aot = by_name[("recompile", "aot")]
+    assert init.parent_id == restore.span_id
+    assert aot.parent_id == restore.span_id
+    assert init.trace_id == aot.trace_id == restore.trace_id
+    assert init.attrs["bytes"] == sum(
+        leaf.nbytes for leaf in jax_mod.tree_util.tree_leaves(state)) > 0
+    assert aot.attrs["cache"] in ("hit", "miss", "off")
+    assert {"cache_hits", "cache_misses"} <= set(aot.attrs)
+
+
+def _fresh_program():
+    import jax
+
+    def doubled_plus_one(x):        # one name: one cache key across calls
+        return x * 2.0 + 1.0
+
+    return jax.jit(doubled_plus_one)
+
+
+def test_compile_cache_reads_a_miss_then_a_hit(tmp_path):
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache
+
+    saved = {name: getattr(jax.config, name) for name in (
+        "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes")}
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    compilation_cache.reset_cache()
+    try:
+        x = jnp.arange(7.0)
+        reads = []
+        for _ in range(2):
+            with obs.device.compile_cache_reads() as cache:
+                _fresh_program().lower(x).compile()
+            reads.append(cache)
+    finally:
+        for name, value in saved.items():
+            jax.config.update(name, value)
+        compilation_cache.reset_cache()
+    assert reads == [{"cache": "miss", "cache_hits": 0, "cache_misses": 1},
+                     {"cache": "hit", "cache_hits": 1, "cache_misses": 0}]
+
+
+def test_compile_cache_reads_count_the_compiling_thread_alone():
+    """The AOT compile runs on its own thread while the main thread
+    compiles the state's init: only the block's own thread counts."""
+    import jax
+
+    prefix = "/jax/compilation_cache/"
+    go, done = threading.Event(), threading.Event()
+
+    def other_thread():
+        go.wait(timeout=10)
+        for name in ("compile_requests_use_cache", "cache_misses"):
+            jax.monitoring.record_event(prefix + name)
+        done.set()
+
+    t = threading.Thread(target=other_thread)
+    t.start()
+    with obs.device.compile_cache_reads() as off:
+        go.set()
+        assert done.wait(timeout=10)
+    t.join(timeout=10)
+    assert not t.is_alive()
+    with obs.device.compile_cache_reads() as hit:
+        for name in ("compile_requests_use_cache", "cache_hits") * 2:
+            jax.monitoring.record_event(prefix + name)
+    with obs.device.compile_cache_reads() as miss:
+        for name in ("compile_requests_use_cache", "cache_hits",
+                     "compile_requests_use_cache", "cache_misses"):
+            jax.monitoring.record_event(prefix + name)
+    assert off == {"cache": "off", "cache_hits": 0, "cache_misses": 0}
+    assert hit == {"cache": "hit", "cache_hits": 2, "cache_misses": 0}
+    assert miss == {"cache": "miss", "cache_hits": 1, "cache_misses": 1}
+
+
+_WORKER = """
+from dlrover_tpu import obs
+from dlrover_tpu.agent.elastic_agent import init_distributed
+
+init_distributed()
+obs.get_flight_recorder().dump(reason="worker-exit")
+"""
+
+
+@pytest.mark.parametrize("source", ["env", "probe"])
+def test_launcher_and_worker_dumps_hold_the_set_up_spans(tmp_path, source):
+    """A standalone launch: the launcher's dump holds ``device_probe`` (by
+    the env's count, or by the probe child's) and ``master_prepare``; the
+    worker's ``backend_init`` is in the agent's rendezvous trace."""
+    script = tmp_path / "worker.py"
+    script.write_text(_WORKER)
+    env = dict(os.environ, PYTHONPATH=str(REPO), JAX_PLATFORMS="cpu",
+               DLROVER_TPU_FLIGHT_DIR=str(tmp_path / "flight"))
+    env.pop(obs.TRACE_PARENT_ENV, None)
+    for name in ("DLROVER_TPU_DEVICES_PER_NODE", "XLA_FLAGS"):
+        env.pop(name, None)     # one CPU device, however it is counted
+    if source == "env":
+        env["DLROVER_TPU_DEVICES_PER_NODE"] = "1"
+    proc = subprocess.run(
+        [sys.executable, "-m", "dlrover_tpu.run", "--standalone",
+         "--max-restarts", "0", "--monitor-interval", "0.2", str(script)],
+        cwd=str(tmp_path), env=env, capture_output=True, text=True,
+        timeout=240)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    spans = {}
+    for path in (tmp_path / "flight").glob("flight-*.json"):
+        for record in json.loads(path.read_text())["events"]:
+            if record.get("kind") == "span":
+                spans.setdefault(record["name"], record)
+    probe = spans["device_probe"]
+    assert probe["attrs"] == {"devices": 1, "source": source}
+    assert probe["duration_s"] >= 0.0 and "master_prepare" in spans
+    rendezvous, backend = spans["rendezvous"], spans["backend_init"]
+    assert backend["pid"] != rendezvous["pid"]
+    assert backend["trace_id"] == rendezvous["trace_id"]
+    assert backend["parent_id"] == rendezvous["span_id"]
+    assert backend["attrs"] == {"platform": "cpu", "devices": 1}
 
 
 # -- acceptance: simulated failover ---------------------------------------
