@@ -21,6 +21,7 @@ import dataclasses
 import os
 import signal
 import subprocess
+import sys
 import tempfile
 import threading
 import time
@@ -400,7 +401,8 @@ class ElasticAgent:
         self._spawn_step = self._timeline_step()
         obs.get_flight_recorder().record_event(
             "worker_spawn", round=rdzv_round, world=sorted(world),
-            restart=self._restart_count, pid=self._proc.pid)
+            restart=self._restart_count, pid=self._proc.pid,
+            agent_jax_loaded=_accelerator_stack_loaded())
 
     def _stop_worker(self) -> None:
         if self._proc is None or self._proc.poll() is not None:
@@ -1119,6 +1121,13 @@ class ElasticAgent:
         obs.remove_span_sink(self._span_exporter)
 
 
+def _accelerator_stack_loaded() -> bool:
+    """Whether this process has imported JAX or Orbax: the agent must
+    not, so that its set-up before the worker's spawn stays short."""
+    return any(name.partition(".")[0] in ("jax", "orbax")
+               for name in sys.modules)
+
+
 def apply_jax_platform_env() -> None:
     """Honor ``JAX_PLATFORMS`` explicitly in worker processes.
 
@@ -1136,7 +1145,12 @@ def apply_jax_platform_env() -> None:
 def init_distributed() -> None:
     """Training-process entry: start JAX's backend under the agent's env
     contract, joining jax.distributed where the world has more than one
-    process, inside a ``backend_init`` span (``platform``, ``devices``)."""
+    process, inside a ``backend_init`` span (``platform``, ``devices``).
+
+    Off the CPU the local devices must be as many as the agent counted
+    (``$DLROVER_TPU_DEVICES_PER_NODE``, read off the PCI bus or a probe):
+    a worker that finds another number raises rather than train on a
+    world the master did not form."""
     with obs.span("backend_init") as backend_span:
         apply_jax_platform_env()
         import jax
@@ -1159,3 +1173,11 @@ def init_distributed() -> None:
         devices = jax.devices()
         backend_span.set_attr("platform", devices[0].platform)
         backend_span.set_attr("devices", len(devices))
+        counted = os.getenv(NodeEnv.DEVICES_PER_NODE)
+        local = len(jax.local_devices())
+        if (counted and devices[0].platform != "cpu"
+                and local != int(counted)):
+            raise RuntimeError(
+                f"this worker has {local} local {devices[0].platform} "
+                f"devices, its agent counted {counted} "
+                f"(${NodeEnv.DEVICES_PER_NODE})")

@@ -6,11 +6,9 @@ modules/distributed_modules/layers.py) — re-designed TPU-first: one
 `jax.sharding.Mesh` with named axes (data/fsdp/tensor/sequence/expert/pipe),
 logical-axis rules instead of parallel module classes, and XLA-inserted
 collectives over ICI/DCN.
-"""
 
-from dlrover_tpu.parallel.mesh import MeshSpec, create_mesh, use_mesh
-from dlrover_tpu.parallel.sharding import (
-    DEFAULT_RULES,
-    make_sharding_rules,
-    mesh_shardings,
-)
+The package imports nothing itself: the master imports its JAX-free
+``planner`` and ``calibration`` in the launcher's process, which must stay
+off JAX until the worker holds the chips. Import ``mesh``, ``sharding``
+and the rest by their own names.
+"""

@@ -309,3 +309,25 @@ def test_restore_falls_back_past_corrupt_latest(tiny_setup, cpu_devices,
                                                    np.asarray(b)),
         params_step1, restored.params,
     )
+
+
+def test_package_names_resolve_on_first_use():
+    """The package's nine names load their submodule when first asked
+    for, and are the submodules' own objects."""
+    import dlrover_tpu.checkpoint as ckpt_pkg
+    from dlrover_tpu.checkpoint import (
+        FlashCheckpointer as flash,
+        encode_tree,
+        flash_checkpoint,
+        peer_restore,
+        quantized,
+    )
+
+    assert flash is flash_checkpoint.FlashCheckpointer
+    assert encode_tree is quantized.encode_tree
+    assert ckpt_pkg.PeerDonorServer is peer_restore.PeerDonorServer
+    assert len(ckpt_pkg.__all__) == 9
+    for name in ckpt_pkg.__all__:
+        assert getattr(ckpt_pkg, name) is not None
+    with pytest.raises(AttributeError, match="no_such_name"):
+        ckpt_pkg.no_such_name

@@ -591,11 +591,15 @@ def test_launcher_and_worker_dumps_hold_the_set_up_spans(tmp_path, source):
         cwd=str(tmp_path), env=env, capture_output=True, text=True,
         timeout=240)
     assert proc.returncode == 0, proc.stderr[-3000:]
-    spans = {}
+    spans, events = {}, {}
     for path in (tmp_path / "flight").glob("flight-*.json"):
         for record in json.loads(path.read_text())["events"]:
             if record.get("kind") == "span":
                 spans.setdefault(record["name"], record)
+            elif record.get("kind") == "event":
+                events.setdefault(record["name"], record)
+    # the agent reached the spawn without the accelerator stack
+    assert events["worker_spawn"]["attrs"]["agent_jax_loaded"] is False
     probe = spans["device_probe"]
     assert probe["attrs"] == {"devices": 1, "source": source}
     assert probe["duration_s"] >= 0.0 and "master_prepare" in spans
