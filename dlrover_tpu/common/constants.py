@@ -262,6 +262,10 @@ class TraceScope:
     INDEXER = "indexer"          # index scores, selection, KL, backward
     SPARSE_ATTN = "sparse_attn"  # attention over the selected keys
     MOE = "moe"                  # router, sort, grouped products, combine
+    # models/minicpm_sala.py and what it runs of ops/
+    LIGHTNING_ATTN = "lightning_attn"        # the decayed linear recurrence
+    BLOCK_SELECT = "block_select"            # compressed keys, block top-k
+    BLOCK_SPARSE_ATTN = "block_sparse_attn"  # attention over chosen blocks
 
 
 class DefaultValues:

@@ -68,6 +68,7 @@ class LlamaConfig:
     # usual hidden_size // num_heads
     attn_head_dim: int = 0
     qk_norm: bool = False            # RMSNorm over each head of q and k
+    embed_scale: float = 1.0         # the embedding's output times this
 
     @property
     def head_dim(self) -> int:
@@ -223,10 +224,11 @@ def dispatch_attention(impl: str, q, k, v, causal: bool = True):
     return out.transpose(0, 2, 1, 3)
 
 
-def project_qkv(cfg: LlamaConfig, x, tie, positions):
+def project_qkv(cfg: LlamaConfig, x, tie, positions, rope: bool = True):
     """q, k, v as (batch, seq, heads, head_dim), q and k normed by head
-    where the model says so (`qk_norm`) and rotated; called inside an
-    attention module's compact `__call__`, whose submodules these are."""
+    where the model says so (`qk_norm`) and rotated unless `rope` is
+    False; called inside an attention module's compact `__call__`, whose
+    submodules these are."""
     batch, seq, _ = x.shape
     dense = functools_partial_dense(cfg)
     q = dense("q_proj", (cfg.hidden_size, cfg.num_heads * cfg.head_dim),
@@ -243,6 +245,8 @@ def project_qkv(cfg: LlamaConfig, x, tie, positions):
                     name="q_norm")(q)
         k = RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.norm_impl,
                     name="k_norm")(k)
+    if not rope:
+        return q, k, v
     return (apply_rope(q, positions, cfg.rope_theta),
             apply_rope(k, positions, cfg.rope_theta), v)
 
@@ -425,6 +429,14 @@ class DecoderBlock(nn.Module):
         return nn.with_logical_constraint(x, ACT_AXES)
 
 
+@functools.lru_cache(maxsize=None)
+def _recomputed(block_cls, policy: str):
+    """One recomputed class for each block class and policy, so that the
+    layers of one kind share it and trace as one."""
+    return nn.remat(block_cls, static_argnums=(),
+                    policy=resolve_remat_policy(policy))
+
+
 class Llama(nn.Module):
     """Decoder-only LM. `__call__(tokens) -> logits`; `hidden_and_head`
     gives what the logits are made of, for a loss that never forms them
@@ -432,6 +444,21 @@ class Llama(nn.Module):
 
     config: LlamaConfig
     _BLOCK = DecoderBlock       # a model of other blocks names its own
+
+    def block(self, layer: int) -> nn.Module:
+        """Layer `layer`'s block, made inside `hidden_and_head`: `_BLOCK`
+        for every layer. A model of layers of more than one kind picks by
+        the layer."""
+        return self.recomputed(self._BLOCK)(self.config,
+                                            name=f"layer_{layer}")
+
+    def recomputed(self, block_cls):
+        """`block_cls` recomputed by block in the backward pass where the
+        configuration says `remat`, under its `remat_policy`."""
+        cfg = self.config
+        if not cfg.remat:
+            return block_cls
+        return _recomputed(block_cls, cfg.remat_policy)
 
     @nn.compact
     def hidden_and_head(self, tokens: jax.Array):
@@ -446,16 +473,12 @@ class Llama(nn.Module):
         )
         with jax.named_scope(TraceScope.EMBED):
             x = embed_lookup(embed, tokens, cfg)
+            if cfg.embed_scale != 1.0:
+                x = x * cfg.embed_scale
         positions = jnp.broadcast_to(
             jnp.arange(tokens.shape[-1]), tokens.shape)
-        block_cls = self._BLOCK
-        if cfg.remat:
-            block_cls = nn.remat(
-                self._BLOCK, static_argnums=(),
-                policy=resolve_remat_policy(cfg.remat_policy),
-            )
         for layer in range(cfg.num_layers):
-            x = block_cls(cfg, name=f"layer_{layer}")(x, positions)
+            x = self.block(layer)(x, positions)
         # one scope with head and loss (`__call__` and the losses below
         # open it again): final norm + head matmul + loss are one item
         # in a trace's account of the step

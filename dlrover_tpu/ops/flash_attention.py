@@ -90,6 +90,11 @@ KERNEL_DKV = "flash_attn_dkv"
 KERNEL_SPARSE_FWD = "sparse_attn_fwd"
 KERNEL_SPARSE_DQ = "sparse_attn_dq"
 KERNEL_SPARSE_DKV = "sparse_attn_dkv"
+# ... and given a selection of key blocks for each row (`blocks=`):
+# attention over the blocks InfLLM-v2 chose (ops/block_sparse_attention.py)
+KERNEL_BLOCK_FWD = "block_sparse_attn_fwd"
+KERNEL_BLOCK_DQ = "block_sparse_attn_dq"
+KERNEL_BLOCK_DKV = "block_sparse_attn_dkv"
 
 
 def _vma(*arrays) -> frozenset:
@@ -242,7 +247,7 @@ def _needed_full(q_start, k_start, block_q: int, block_k: int):
 
 
 def _dispatch(scores, update, causal: bool, q_start, k_start,
-              block_q: int, block_k: int) -> None:
+              block_q: int, block_k: int, live=None) -> None:
     """Run a kernel's two stages (`_pipelined`) over one (q, k) block
     pair. Causal: skip blocks entirely above the diagonal, run blocks
     entirely below it whole and unmasked, and in a block that straddles
@@ -250,12 +255,16 @@ def _dispatch(scores, update, causal: bool, q_start, k_start,
     the diagonal beyond a strip's last g columns is never computed.
     Static per-block skip is impossible (q_start/k_start are dynamic over
     the grid), so dispatch with pl.when. Shared by the forward and both
-    backward kernels so the boundary conditions cannot drift apart."""
+    backward kernels so the boundary conditions cannot drift apart.
+    `live` (a traced bool, causal calls only): the block pair holds a
+    selected key of some row; where it is false the pair is skipped too."""
     whole = _whole(block_q, block_k, _UNMASKED)
     if not causal:
         _pipelined(whole, scores, update)
         return
     needed, full = _needed_full(q_start, k_start, block_q, block_k)
+    if live is not None:
+        needed = jnp.logical_and(needed, live)
     pl.when(jnp.logical_and(needed, full))(
         lambda: _pipelined(whole, scores, update))
     pl.when(jnp.logical_and(needed, jnp.logical_not(full)))(
@@ -291,13 +300,89 @@ def _masked(s, tile: _Tile, q_start, k_start):
     return jnp.where(q_idx >= k_idx, s, NEG_INF)
 
 
-def _selected(s, tile: _Tile, mask_ref):
-    """A tile's scores with the keys the mask leaves out at NEG_INF; the
-    mask operand is (1, block_q, block_k) int8, nonzero = attended. It
-    applies in every computed tile, beside the causal one. No mask: `s`."""
-    if mask_ref is None:
-        return s
-    return jnp.where(mask_ref[0, tile.rows, tile.cols] != 0, s, NEG_INF)
+# A selection applies in every computed tile, beside the causal mask: the
+# kernels take it as `select(s, tile)`, which sets the scores of the keys it
+# leaves out to NEG_INF, and a block pair's `live` for `_dispatch`.
+
+
+def _with_mask(kernel, operands: int):
+    """`kernel` for a call whose operand after the first `operands` is a
+    (1, block_q, block_k) int8 block of the selection mask, nonzero =
+    attended."""
+    def masked(*refs, **static):
+        mask_ref = refs[operands]
+
+        def select(s, tile: _Tile):
+            return jnp.where(mask_ref[0, tile.rows, tile.cols] != 0, s,
+                             NEG_INF)
+
+        return kernel(*refs[:operands], *refs[operands + 1:],
+                      select=select, **static)
+    return masked
+
+
+class Blocks(NamedTuple):
+    """A selection of key blocks for each row, as the kernels take it
+    (`ops/block_sparse_attention.py:kernel_operands` makes it): `bits`
+    (batch, groups, seq_q, seq_k // block_k) int32, bit j of word [b, g,
+    t, c] set where row t of kv group g selected the j-th `size`-key block
+    of kv tile c; `visit` (batch x groups x q tiles x kv tiles,) int32,
+    nonzero where some row of the q tile selected a block of the kv tile
+    (scalar-prefetched: the kernels skip the pair where it is 0)."""
+    bits: jax.Array
+    visit: jax.Array
+    size: int
+
+
+def _with_blocks(kernel, operands: int, *, groups: int, group: int,
+                 num_q_blocks: int, num_k_blocks: int, size: int,
+                 kv_major: bool):
+    """`kernel` for a call whose first operand is `Blocks.visit`
+    (scalar-prefetched) and whose operand after the next `operands` is the
+    (1, 1, block_q, kv tiles) block of `Blocks.bits`. `kv_major`: the
+    grid runs (b, h, kv tile, q tile), as dK/dV's does."""
+    def blocked(visit_ref, *refs, **static):
+        bits_ref = refs[operands]
+        b, h = pl.program_id(0), pl.program_id(1)
+        qi, ki = pl.program_id(2), pl.program_id(3)
+        if kv_major:
+            qi, ki = ki, qi
+        live = visit_ref[((b * groups + h // group) * num_q_blocks + qi)
+                         * num_k_blocks + ki] != 0
+
+        def select(s, tile: _Tile):
+            words = bits_ref[0, 0, tile.rows, :]
+            lane = jax.lax.broadcasted_iota(jnp.int32, words.shape, 1)
+            # the kv tile's word of each row; below 2^24, exact in float32
+            word = jnp.sum(jnp.where(lane == ki, words, 0).astype(
+                jnp.float32), axis=1, keepdims=True).astype(jnp.int32)
+            block = (tile.c0 + jax.lax.broadcasted_iota(
+                jnp.int32, (1, tile.cn), 1)) // size
+            chosen = jnp.right_shift(word, block) & 1
+            return jnp.where(chosen != 0, s, NEG_INF)
+
+        return kernel(*refs[:operands], *refs[operands + 1:],
+                      select=select, live=live, **static)
+    return blocked
+
+
+def _pallas(kernel, *, grid, in_specs, out_specs, out_shape, scratch_shapes,
+            name: str, interpret: bool, prefetch=None):
+    """`pl.pallas_call` with the flash kernels' grid semantics; with
+    `prefetch`, an int32 array scalar-prefetched ahead of the operands
+    (every index map then takes it after the grid's indices)."""
+    if prefetch is None:
+        return pl.pallas_call(
+            kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
+            out_shape=out_shape, scratch_shapes=scratch_shapes,
+            compiler_params=_DIM_SEMANTICS, interpret=interpret, name=name)
+    call = pl.pallas_call(
+        kernel, grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
+            out_specs=out_specs, scratch_shapes=scratch_shapes),
+        out_shape=out_shape, compiler_params=_DIM_SEMANTICS,
+        interpret=interpret, name=name)
+    return lambda *operands: call(prefetch, *operands)
 
 
 # ===========================================================================
@@ -305,20 +390,11 @@ def _selected(s, tile: _Tile, mask_ref):
 # ===========================================================================
 
 
-def _with_mask(kernel, operands: int):
-    """`kernel` for a call whose operand after the first `operands` is the
-    selection mask: the kernels take it by keyword."""
-    def masked(*refs, **static):
-        return kernel(*refs[:operands], *refs[operands + 1:],
-                      mask_ref=refs[operands], **static)
-    return masked
-
-
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                 acc_ref, m_ref, l_ref,
                 *, sm_scale: float, causal: bool,
                 block_q: int, block_k: int, num_k_blocks: int,
-                mask_ref=None):
+                select=None, live=None):
     ki = pl.program_id(3)
 
     @pl.when(ki == 0)
@@ -339,7 +415,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         k = k_ref[0, 0, tile.cols]
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * (
             sm_scale * LOG2E)
-        return _selected(_masked(s, tile, q_start, k_start), tile, mask_ref)
+        s = _masked(s, tile, q_start, k_start)
+        return s if select is None else select(s, tile)
 
     def _update(tile, s):
         # one online-softmax update of the tile's rows
@@ -349,7 +426,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         m_cur = jnp.max(s, axis=1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
         p = jnp.exp2(s - m_new)
-        if mask_ref is not None:
+        if select is not None:
             # a row may have no selected key yet (under the causal mask
             # alone key 0 serves every row): NEG_INF - NEG_INF is 0, not
             # -inf, so its masked scores must not count as exp2(0)
@@ -363,7 +440,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
     # Causal: blocks below the diagonal run whole and unmasked; a block on
     # it (2 of the 3 computed at seq 2048; 8 of 36 at 8k) computes only
     # the sub-tiles its strips meet, 36 of 64 at grain 128.
-    _dispatch(_scores, _update, causal, q_start, k_start, block_q, block_k)
+    _dispatch(_scores, _update, causal, q_start, k_start, block_q, block_k,
+              live)
 
     @pl.when(ki == num_k_blocks - 1)
     def _finalize():
@@ -374,17 +452,42 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 
 def _flash_fwd(q, k, v, sm_scale: float, causal: bool,
-               block_q: int, block_k: int, mask=None):
+               block_q: int, block_k: int, mask=None, blocks=None):
     """(out, lse) of one forward launch; blocks are fitted here, so that
     every request for the same fitted blocks shares one traced kernel.
     `mask` (batch, seq_q, seq_k) int8, nonzero = attended: the softmax
     runs over those keys alone (every head the same), under the kernels'
-    `sparse_attn_*` names."""
+    `sparse_attn_*` names. `blocks` (`Blocks`, made for the same fitted
+    blocks): over the key blocks each row of each kv group selected, under
+    the `block_sparse_attn_*` names; causal calls only."""
     return _flash_fwd_call(
-        q, k, v, mask, sm_scale=sm_scale, causal=causal,
+        q, k, v, mask, _arrays_of(blocks), sm_scale=sm_scale, causal=causal,
+        size=0 if blocks is None else blocks.size,
         block_q=fit_block(q.shape[2], block_q),
         block_k=fit_block(k.shape[2], block_k),
         interpret=not on_tpu())
+
+
+def _arrays_of(blocks):
+    """`Blocks` as a jitted call takes it: its arrays (its size goes
+    apart, static)."""
+    return None if blocks is None else (blocks.bits, blocks.visit)
+
+
+def _kernel_of(kernel, operands: int, mask, blocks, size: int,
+               names: tuple, *, kv_major: bool, q_shape, k_shape,
+               block_q: int, block_k: int):
+    """(kernel, its name, the prefetched operand) of a call with no
+    selection, a mask or blocks: `names` in that order."""
+    if mask is not None:
+        return _with_mask(kernel, operands), names[1], None
+    if blocks is None:
+        return kernel, names[0], None
+    return _with_blocks(
+        kernel, operands, groups=k_shape[1], group=q_shape[1] // k_shape[1],
+        num_q_blocks=_cdiv(q_shape[2], block_q),
+        num_k_blocks=_cdiv(k_shape[2], block_k), size=size,
+        kv_major=kv_major), names[2], blocks[1]
 
 
 # Under jit with everything but the arrays static: a model calls this once
@@ -393,9 +496,10 @@ def _flash_fwd(q, k, v, sm_scale: float, causal: bool,
 # per layer (the backward pair likewise). `interpret` is an argument, not
 # read inside, because the trace is cached across backends.
 @functools.partial(jax.jit, static_argnames=(
-    "sm_scale", "causal", "block_q", "block_k", "interpret"))
-def _flash_fwd_call(q, k, v, mask=None, *, sm_scale: float, causal: bool,
-                    block_q: int, block_k: int, interpret: bool):
+    "size", "sm_scale", "causal", "block_q", "block_k", "interpret"))
+def _flash_fwd_call(q, k, v, mask=None, blocks=None, *, sm_scale: float,
+                    causal: bool, block_q: int, block_k: int,
+                    interpret: bool, size: int = 0):
     batch, num_heads, seq_q, head_dim = q.shape
     _, num_kv_heads, seq_k, _ = k.shape
     group = num_heads // num_kv_heads
@@ -404,10 +508,11 @@ def _flash_fwd_call(q, k, v, mask=None, *, sm_scale: float, causal: bool,
 
     grid = (batch, num_heads, num_q_blocks, num_k_blocks)
 
-    def q_map(b, h, qi, ki):
+    # every index map takes `*_`: the prefetched operand, where there is one
+    def q_map(b, h, qi, ki, *_):
         return (b, h, qi, 0)
 
-    def kv_map(b, h, qi, ki):
+    def kv_map(b, h, qi, ki, *_):
         if causal:
             # Blocks above the diagonal are skipped by the kernel; map
             # their kv index to the last needed block so consecutive
@@ -415,12 +520,15 @@ def _flash_fwd_call(q, k, v, mask=None, *, sm_scale: float, causal: bool,
             ki = jnp.minimum(ki, ((qi + 1) * block_q - 1) // block_k)
         return (b, h // group, ki, 0)
 
-    def o_map(b, h, qi, ki):
+    def o_map(b, h, qi, ki, *_):
         return (b, h, qi, 0)
 
+    kernel, name, prefetch = _kernel_of(
+        _fwd_kernel, 3, mask, blocks, size,
+        (KERNEL_FWD, KERNEL_SPARSE_FWD, KERNEL_BLOCK_FWD), kv_major=False,
+        q_shape=q.shape, k_shape=k.shape, block_q=block_q, block_k=block_k)
     kernel = functools.partial(
-        _fwd_kernel if mask is None else _with_mask(_fwd_kernel, 3),
-        sm_scale=sm_scale, causal=causal,
+        kernel, sm_scale=sm_scale, causal=causal,
         block_q=block_q, block_k=block_k, num_k_blocks=num_k_blocks,
     )
     operands, masked = (q, k, v), []
@@ -428,8 +536,13 @@ def _flash_fwd_call(q, k, v, mask=None, *, sm_scale: float, causal: bool,
         operands += (mask,)
         masked = [pl.BlockSpec(
             (1, block_q, block_k),
-            lambda b, h, qi, ki: (b, qi, kv_map(b, h, qi, ki)[2]))]
-    out, lse = pl.pallas_call(
+            lambda b, h, qi, ki, *_: (b, qi, kv_map(b, h, qi, ki)[2]))]
+    if blocks is not None:
+        operands += (blocks[0],)
+        masked = [pl.BlockSpec(
+            (1, 1, block_q, blocks[0].shape[-1]),
+            lambda b, h, qi, ki, *_: (b, h // group, qi, 0))]
+    out, lse = _pallas(
         kernel,
         grid=grid,
         in_specs=[
@@ -439,8 +552,7 @@ def _flash_fwd_call(q, k, v, mask=None, *, sm_scale: float, causal: bool,
         ] + masked,
         out_specs=[
             pl.BlockSpec((1, 1, block_q, head_dim), o_map),
-            pl.BlockSpec((1, 1, block_q, 1),
-                         lambda b, h, qi, ki: (b, h, qi, 0)),
+            pl.BlockSpec((1, 1, block_q, 1), o_map),
         ],
         out_shape=[
             _sds(q.shape, q.dtype, _vma(q, k, v)),
@@ -451,9 +563,7 @@ def _flash_fwd_call(q, k, v, mask=None, *, sm_scale: float, causal: bool,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
-        compiler_params=_DIM_SEMANTICS,
-        interpret=interpret,
-        name=KERNEL_FWD if mask is None else KERNEL_SPARSE_FWD,
+        name=name, interpret=interpret, prefetch=prefetch,
     )(*operands)
     return out, lse
 
@@ -464,7 +574,7 @@ def _flash_fwd_call(q, k, v, mask=None, *, sm_scale: float, causal: bool,
 
 
 def _bwd_scores(tile, *, q_ref, k_ref, v_ref, do_ref, sm_scale: float,
-                q_start, k_start, mask_ref=None):
+                q_start, k_start, select=None):
     """Stage one of both backward kernels: a tile's scores (exp2 domain,
     masked) and dP = dO V^T, the matmuls that wait on nothing."""
     q = q_ref[0, 0, tile.rows]
@@ -474,14 +584,15 @@ def _bwd_scores(tile, *, q_ref, k_ref, v_ref, do_ref, sm_scale: float,
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * (
         sm_scale * LOG2E)
     dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-    return _selected(_masked(s, tile, q_start, k_start), tile, mask_ref), dp
+    s = _masked(s, tile, q_start, k_start)
+    return (s if select is None else select(s, tile)), dp
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                    dq_ref, dq_acc_ref,
                    *, sm_scale: float, causal: bool,
                    block_q: int, block_k: int, num_k_blocks: int,
-                   mask_ref=None):
+                   select=None, live=None):
     ki = pl.program_id(3)
 
     @pl.when(ki == 0)
@@ -495,7 +606,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     _scores = functools.partial(
         _bwd_scores, q_ref=q_ref, k_ref=k_ref, v_ref=v_ref, do_ref=do_ref,
         sm_scale=sm_scale, q_start=q_start, k_start=k_start,
-        mask_ref=mask_ref)
+        select=select)
 
     def _update(tile, scores):
         rows = tile.rows
@@ -507,7 +618,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         ds = (p * (dp - delta) * sm_scale).astype(k.dtype)
         dq_acc_ref[rows] += jnp.dot(ds, k, preferred_element_type=jnp.float32)
 
-    _dispatch(_scores, _update, causal, q_start, k_start, block_q, block_k)
+    _dispatch(_scores, _update, causal, q_start, k_start, block_q, block_k,
+              live)
 
     @pl.when(ki == num_k_blocks - 1)
     def _finalize():
@@ -518,7 +630,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_acc_ref, dv_acc_ref,
                     *, sm_scale: float, causal: bool,
                     block_q: int, block_k: int, num_q_blocks: int,
-                    mask_ref=None):
+                    select=None, live=None):
     qi = pl.program_id(3)
 
     @pl.when(qi == 0)
@@ -533,7 +645,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     _scores = functools.partial(
         _bwd_scores, q_ref=q_ref, k_ref=k_ref, v_ref=v_ref, do_ref=do_ref,
         sm_scale=sm_scale, q_start=q_start, k_start=k_start,
-        mask_ref=mask_ref)
+        select=select)
 
     def _update(tile, scores):
         rows, cols = tile.rows, tile.cols
@@ -551,7 +663,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     # Causal: for a kv block, only q blocks at or below the diagonal
     # contribute; blocks strictly below it need no mask.
-    _dispatch(_scores, _update, causal, q_start, k_start, block_q, block_k)
+    _dispatch(_scores, _update, causal, q_start, k_start, block_q, block_k,
+              live)
 
     @pl.when(qi == num_q_blocks - 1)
     def _finalize():
@@ -560,23 +673,26 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _flash_bwd(res, g, *, sm_scale: float, causal: bool,
-               block_q: int, block_k: int, delta=None, mask=None):
+               block_q: int, block_k: int, delta=None, mask=None,
+               blocks=None):
     """(dq, dk, dv) of one backward launch pair. delta = rowsum(dO·O) may
     be passed precomputed — ring callers invoke this once per visiting KV
-    block with step-invariant dO/O. `mask`: the forward's (`_flash_fwd`)."""
+    block with step-invariant dO/O. `mask`, `blocks`: the forward's
+    (`_flash_fwd`)."""
     q, k = res[0], res[1]
     return _flash_bwd_call(
-        res, g, delta, mask, sm_scale=sm_scale, causal=causal,
+        res, g, delta, mask, _arrays_of(blocks), sm_scale=sm_scale,
+        causal=causal, size=0 if blocks is None else blocks.size,
         block_q=fit_block(q.shape[2], block_q),
         block_k=fit_block(k.shape[2], block_k),
         interpret=not on_tpu())
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "sm_scale", "causal", "block_q", "block_k", "interpret"))
-def _flash_bwd_call(res, do, delta, mask=None, *, sm_scale: float,
-                    causal: bool, block_q: int, block_k: int,
-                    interpret: bool):
+    "size", "sm_scale", "causal", "block_q", "block_k", "interpret"))
+def _flash_bwd_call(res, do, delta, mask=None, blocks=None, *,
+                    sm_scale: float, causal: bool, block_q: int,
+                    block_k: int, interpret: bool, size: int = 0):
     q, k, v, out, lse = res
     batch, num_heads, seq_q, head_dim = q.shape
     _, num_kv_heads, seq_k, _ = k.shape
@@ -588,23 +704,31 @@ def _flash_bwd_call(res, do, delta, mask=None, *, sm_scale: float,
         delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                         axis=-1, keepdims=True)  # (b, h, seq_q, 1)
 
-    def q_map(b, h, qi, ki):
+    # every index map takes `*_`: the prefetched operand, where there is one
+    def q_map(b, h, qi, ki, *_):
         return (b, h, qi, 0)
 
-    def kv_map(b, h, qi, ki):
+    def kv_map(b, h, qi, ki, *_):
         if causal:
             # dedupe the DMA of kv blocks above the diagonal (skipped by
             # the kernel): same trick as the forward's kv_map
             ki = jnp.minimum(ki, ((qi + 1) * block_q - 1) // block_k)
         return (b, h // group, ki, 0)
 
-    def row_map(b, h, qi, ki):
+    def row_map(b, h, qi, ki, *_):
         return (b, h, qi, 0)
 
+    def kernel_of(kernel, names, kv_major):
+        return _kernel_of(kernel, 6, mask, blocks, size, names,
+                          kv_major=kv_major, q_shape=q.shape,
+                          k_shape=k.shape, block_q=block_q, block_k=block_k)
+
     # ---- dq: iterate kv blocks innermost -----------------------------
+    dq_kernel, name, prefetch = kernel_of(
+        _bwd_dq_kernel, (KERNEL_DQ, KERNEL_SPARSE_DQ, KERNEL_BLOCK_DQ),
+        False)
     dq_kernel = functools.partial(
-        _bwd_dq_kernel if mask is None else _with_mask(_bwd_dq_kernel, 6),
-        sm_scale=sm_scale, causal=causal,
+        dq_kernel, sm_scale=sm_scale, causal=causal,
         block_q=block_q, block_k=block_k, num_k_blocks=num_k_blocks,
     )
     operands, masked = (q, k, v, do, lse, delta), []
@@ -612,8 +736,13 @@ def _flash_bwd_call(res, do, delta, mask=None, *, sm_scale: float,
         operands += (mask,)
         masked = [pl.BlockSpec(
             (1, block_q, block_k),
-            lambda b, h, qi, ki: (b, qi, kv_map(b, h, qi, ki)[2]))]
-    dq = pl.pallas_call(
+            lambda b, h, qi, ki, *_: (b, qi, kv_map(b, h, qi, ki)[2]))]
+    if blocks is not None:
+        operands += (blocks[0],)
+        masked = [pl.BlockSpec(
+            (1, 1, block_q, blocks[0].shape[-1]),
+            lambda b, h, qi, ki, *_: (b, h // group, qi, 0))]
+    dq = _pallas(
         dq_kernel,
         grid=(batch, num_heads, num_q_blocks, num_k_blocks),
         in_specs=[
@@ -627,15 +756,13 @@ def _flash_bwd_call(res, do, delta, mask=None, *, sm_scale: float,
         out_specs=pl.BlockSpec((1, 1, block_q, head_dim), q_map),
         out_shape=_sds(q.shape, q.dtype, _vma(q, k, v, do)),
         scratch_shapes=[pltpu.VMEM((block_q, head_dim), jnp.float32)],
-        compiler_params=_DIM_SEMANTICS,
-        interpret=interpret,
-        name=KERNEL_DQ if mask is None else KERNEL_SPARSE_DQ,
+        name=name, interpret=interpret, prefetch=prefetch,
     )(*operands)
 
     # ---- dk/dv: per q-head contributions, iterate q blocks innermost --
     # Grid runs over *query* heads so GQA contributions are disjoint per
     # (kv-head, group member); sum over the group afterwards.
-    def kv_out_map(b, h, ki, qi):
+    def kv_out_map(b, h, ki, qi, *_):
         return (b, h, ki, 0)
 
     if causal:
@@ -654,25 +781,31 @@ def _flash_bwd_call(res, do, delta, mask=None, *, sm_scale: float,
         def _qi_eff(ki, qi):
             return qi
 
-    def q_map2(b, h, ki, qi):
+    def q_map2(b, h, ki, qi, *_):
         return (b, h, _qi_eff(ki, qi), 0)
 
-    def kv_map2(b, h, ki, qi):
+    def kv_map2(b, h, ki, qi, *_):
         return (b, h // group, ki, 0)
 
-    def row_map2(b, h, ki, qi):
+    def row_map2(b, h, ki, qi, *_):
         return (b, h, _qi_eff(ki, qi), 0)
 
+    dkv_kernel, name, prefetch = kernel_of(
+        _bwd_dkv_kernel, (KERNEL_DKV, KERNEL_SPARSE_DKV, KERNEL_BLOCK_DKV),
+        True)
     dkv_kernel = functools.partial(
-        _bwd_dkv_kernel if mask is None else _with_mask(_bwd_dkv_kernel, 6),
-        sm_scale=sm_scale, causal=causal,
+        dkv_kernel, sm_scale=sm_scale, causal=causal,
         block_q=block_q, block_k=block_k, num_q_blocks=num_q_blocks,
     )
     if mask is not None:
         masked = [pl.BlockSpec(
             (1, block_q, block_k),
-            lambda b, h, ki, qi: (b, _qi_eff(ki, qi), ki))]
-    dk_per_qh, dv_per_qh = pl.pallas_call(
+            lambda b, h, ki, qi, *_: (b, _qi_eff(ki, qi), ki))]
+    if blocks is not None:
+        masked = [pl.BlockSpec(
+            (1, 1, block_q, blocks[0].shape[-1]),
+            lambda b, h, ki, qi, *_: (b, h // group, _qi_eff(ki, qi), 0))]
+    dk_per_qh, dv_per_qh = _pallas(
         dkv_kernel,
         grid=(batch, num_heads, num_k_blocks, num_q_blocks),
         in_specs=[
@@ -697,9 +830,7 @@ def _flash_bwd_call(res, do, delta, mask=None, *, sm_scale: float,
             pltpu.VMEM((block_k, head_dim), jnp.float32),
             pltpu.VMEM((block_k, head_dim), jnp.float32),
         ],
-        compiler_params=_DIM_SEMANTICS,
-        interpret=interpret,
-        name=KERNEL_DKV if mask is None else KERNEL_SPARSE_DKV,
+        name=name, interpret=interpret, prefetch=prefetch,
     )(*operands)
 
     if group > 1:

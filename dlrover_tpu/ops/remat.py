@@ -14,8 +14,14 @@ class Kept:
     SELECTION = "indexer_selection"     # the int8 mask and its row log-sum-exp
     ATTENTION = "sparse_attn_out"       # the attention's output and log-sum-exp
     KL_GRADS = "indexer_kl_grads"       # what the KL term's backward reads
+    LIGHTNING = "lightning_out"         # the output and the chunks' states
+    BLOCKS = "block_selection"          # the int8 block mask
+    BLOCK_SPARSE = "block_sparse_attn_out"  # its attention's out and lse
 
-    ALL = (SELECTION, ATTENTION, KL_GRADS)
+    # what ops/sparse_attention*.py tags; ops/linear_attention.py and
+    # ops/block_sparse_attention.py tag the rest
+    INDEXED = (SELECTION, ATTENTION, KL_GRADS)
+    ALL = INDEXED + (LIGHTNING, BLOCKS, BLOCK_SPARSE)
 
 
 def resolve_remat_policy(name: str):

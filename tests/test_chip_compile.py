@@ -235,6 +235,75 @@ def test_a_recomputed_block_launches_none_of_its_attentions_kernels_again(
     assert "%indexer_dq" not in text and "%indexer_dk" not in text
 
 
+@pytest.mark.parametrize("kernel", ["lightning", "block_sparse"])
+def test_sala_kernels_at_the_published_widths(topo, one_chip, chip_path,
+                                              kernel):
+    """MiniCPM-SALA's mixers' kernels, forward and backward, at 16,384
+    tokens: lightning attention over 32 heads of 128 in chunks of 256, and
+    InfLLM-v2's selection with the flash kernels given its blocks over 32
+    query heads and 2 kv heads of 128."""
+    from dlrover_tpu.ops import block_sparse_attention, linear_attention
+
+    seq = 16384
+
+    def of(heads):
+        return jax.ShapeDtypeStruct((1, heads, seq, 128), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    if kernel == "lightning":
+        rate = jax.ShapeDtypeStruct((32,), jnp.float32, sharding=one_chip)
+
+        def loss(q, k, v, rate):
+            return jnp.sum(linear_attention.linear_attention(
+                q, k, v, rate, 128 ** -0.5).astype(jnp.float32))
+
+        names = (linear_attention.KERNEL_FWD, linear_attention.KERNEL_BWD)
+        args = (of(32), of(32), of(32), rate)
+    else:
+        def loss(q, k, v):
+            out, share = block_sparse_attention.block_sparse_attention(
+                q, k, v, block_sparse_attention.Sparsity())
+            return jnp.sum(out.astype(jnp.float32)) + share
+
+        names = ("block_sparse_attn_fwd", "block_sparse_attn_dq",
+                 "block_sparse_attn_dkv")
+        args = (of(32), of(2), of(2))
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), *args)
+    for name in names:
+        assert len(re.findall(rf"%{name}[.\d]* = ", text)) == 1, name
+
+
+def test_a_recomputed_sala_stage_launches_no_mixer_kernel_again(topo,
+                                                               chip_path):
+    """A sparse and a lightning layer of `models/minicpm_sala.py` at the
+    published widths and 16,384 tokens, recomputed by block under its
+    default policy (`kernel_outputs`): each mixer kernel stands once in the
+    step, only the norms' forward kernels are launched again, and XLA
+    rematerialises nothing of its own."""
+    from dlrover_tpu.models.minicpm_sala import MiniCPMSala, SalaConfig
+
+    cfg = SalaConfig(
+        vocab_size=18362, hidden_size=4096, intermediate_size=16384,
+        num_layers=2, num_heads=32, num_kv_heads=2, attn_head_dim=128,
+        max_seq_len=16384, rms_norm_eps=1e-6, dtype=jnp.bfloat16,
+        norm_impl="fused", embed_impl="gather", remat=True,
+        embed_scale=12.0, mixer_types=("minicpm4", "lightning-attn"))
+    assert cfg.remat_policy == "kernel_outputs"
+    tx = optax.chain(optax.scale_by_factored_rms(), optax.scale(-3e-4))
+    trainer = build_trainer(
+        MiniCPMSala(cfg), tx, create_mesh(MeshSpec(), topo.devices[:1]),
+        jnp.zeros((1, 16384), jnp.int32), cross_entropy_loss,
+        accum_steps=1, micro_batch=1)
+    trainer.precompile()
+    text = trainer._compiled_step.as_text()
+    counts = schedule_counts(text, (1, 16384))
+    assert set(counts["recomputed_kernels"]) == {norms.KERNEL_FWD}
+    assert counts["remat_instructions"] == 0
+    for kernel in ("lightning_fwd", "lightning_bwd", "block_sparse_attn_fwd",
+                   "block_sparse_attn_dq", "block_sparse_attn_dkv"):
+        assert len(re.findall(rf"%{kernel}[.\d]* = ", text)) == 1, kernel
+
+
 @pytest.mark.parametrize("hidden", [2048, 4096])
 def test_fused_rms_norm_fwd_bwd(topo, one_chip, chip_path, hidden):
     x = jax.ShapeDtypeStruct((2, 2048, hidden), jnp.bfloat16,
