@@ -60,7 +60,8 @@ class LlamaConfig:
     norm_impl: str = "fused"         # "fused" (Pallas) | "reference" (XLA)
     remat: bool = False              # rematerialize each block
     # "full"/"nothing_saveable" | "dots"/"dots_saveable" |
-    # "dots_with_no_batch_dims" | "kernel_outputs" (ops/remat.py)
+    # "dots_with_no_batch_dims" | "kernel_outputs" |
+    # "matmul_and_kernel_outputs" (ops/remat.py)
     remat_policy: str = "nothing_saveable"
     tie_embeddings: bool = False
     # a head's width where the model declares one of its own (q is then

@@ -59,8 +59,9 @@ class SalaConfig(LlamaConfig):
 
     qk_norm: bool = True
     # with `remat`: a block's recomputation keeps what its mixer's kernels
-    # made (`ops/remat.py:Kept`) and recomputes the rest
-    remat_policy: str = "kernel_outputs"
+    # made (`ops/remat.py:Kept`) and its projections' outputs, and
+    # recomputes the rest (the norms, the gates' elementwise work)
+    remat_policy: str = "matmul_and_kernel_outputs"
     mixer_types: tuple = ()          # published, whole
     published_layers: int = 32       # the depth muP's and the decay's are of
     scale_depth: float = 1.4

@@ -29,7 +29,12 @@ def resolve_remat_policy(name: str):
     "full"/"nothing_saveable" recomputes everything; "dots"/"dots_saveable"
     keeps matmul outputs (cheaper backward, more memory); "kernel_outputs"
     keeps what the block tagged with a name of `Kept` and recomputes the
-    rest: `nothing_saveable` for a block that tags nothing."""
+    rest: `nothing_saveable` for a block that tags nothing;
+    "matmul_and_kernel_outputs" keeps those and every matmul without batch
+    dimensions, i.e. the x @ W projections' outputs (not a batched product
+    such as `block_select`'s scores, nor a kernel's own matmuls), so the
+    backward pass launches no projection a second time, at the memory of
+    their outputs."""
     import jax
 
     policies = {
@@ -42,6 +47,10 @@ def resolve_remat_policy(name: str):
             jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
         "kernel_outputs":
             jax.checkpoint_policies.save_only_these_names(*Kept.ALL),
+        "matmul_and_kernel_outputs":
+            jax.checkpoint_policies.save_from_both_policies(
+                jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+                jax.checkpoint_policies.save_only_these_names(*Kept.ALL)),
     }
     if name not in policies:
         raise ValueError(f"unknown remat policy {name!r}; "

@@ -48,7 +48,7 @@ _INSTRUCTION = re.compile(
 def schedule_counts(compiled_text: str, rows_and_seq: Tuple[int, ...]) -> dict:
     """What the compiled step's ENTRY computation, which is printed in
     schedule order, says of two things XLA decides and nobody asks for,
-    and of one the block's recomputation policy decides:
+    and of two the block's recomputation policy decides:
 
     `remat_instructions`: forward matmuls (`jvp(` outside `transpose(`,
     `dot_general`) launched beyond the first of their `op_name` where XLA
@@ -66,11 +66,16 @@ def schedule_counts(compiled_text: str, rows_and_seq: Tuple[int, ...]) -> dict:
     `recomputed_kernels`: kernel name -> launches (`custom-call`s, named
     after their `pl.pallas_call`) under `rematted_computation`, a block's
     forward run again in the backward pass (`remat`). What
-    `ops/remat.py:Kept` names is kept and its kernel is not among them."""
+    `ops/remat.py:Kept` names is kept and its kernel is not among them.
+
+    `recomputed_matmuls`: matmuls (found as for `remat_instructions`) under
+    `rematted_computation`; 0 where the block's policy keeps every
+    projection's output (`matmul_and_kernel_outputs`)."""
     entry = compiled_text[compiled_text.rfind("\nENTRY "):]
     activation = "[" + ",".join(str(d) for d in rows_and_seq) + ","
     forward, weight_grads, last_dx = {}, [], (-1, "")
     recomputed: dict = {}
+    recomputed_matmuls = 0
     for at, found in enumerate(_INSTRUCTION.finditer(entry)):
         opcode, op_name = found["opcode"], found["op_name"]
         if opcode == "custom-call" and "rematted_computation" in op_name:
@@ -80,6 +85,7 @@ def schedule_counts(compiled_text: str, rows_and_seq: Tuple[int, ...]) -> dict:
             opcode == "fusion" and "kind=kOutput" in found[0])
         if not matmul or not op_name.endswith("dot_general"):
             continue
+        recomputed_matmuls += "rematted_computation" in op_name
         if "transpose(jvp(" in op_name:
             if "_proj/" not in op_name:
                 continue
@@ -98,6 +104,7 @@ def schedule_counts(compiled_text: str, rows_and_seq: Tuple[int, ...]) -> dict:
             at > last_dx[0] and op_name != last_dx[1]
             for at, op_name in weight_grads),
         "recomputed_kernels": recomputed,
+        "recomputed_matmuls": recomputed_matmuls,
     }
 
 
@@ -199,10 +206,11 @@ class ShardedTrainer:
                 aot_span.set_attr(name, value)
         default_logger.info(
             "step program: remat_instructions=%d late_weight_grads=%d "
-            "recomputed_kernels=%d %s (read in %.2f s)",
+            "recomputed_kernels=%d %s recomputed_matmuls=%d "
+            "(read in %.2f s)",
             counts["remat_instructions"], counts["late_weight_grads"],
             counts["recomputed_kernels"], dict(sorted(by_kernel.items())),
-            counts["schedule_read_s"])
+            counts["recomputed_matmuls"], counts["schedule_read_s"])
         self._compiled_step = compiled
 
     def step(self, state: TrainState, tokens, targets):

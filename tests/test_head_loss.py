@@ -381,7 +381,8 @@ def test_precompiles_span_carries_the_schedules_counts(one_device, spans):
     trainer.precompile()
     (aot,) = _recompiles(spans, "aot")
     assert (aot["remat_instructions"], aot["late_weight_grads"],
-            aot["recomputed_kernels"]) == (0, 0, 0)
+            aot["recomputed_kernels"], aot["recomputed_matmuls"]) == (
+                0, 0, 0, 0)
     assert 0 <= aot["schedule_read_s"] < 5
 
 
@@ -428,6 +429,7 @@ def test_schedule_counts_on_a_hand_made_entry(order, want):
     assert (counts["remat_instructions"],
             counts["late_weight_grads"]) == want
     assert counts["recomputed_kernels"] == {}
+    assert counts["recomputed_matmuls"] == 0
 
 
 _BLOCK = "transpose(jvp(M))/layer_{}/checkpoint/rematted_computation/{}"
@@ -465,6 +467,20 @@ def test_schedule_counts_names_the_kernels_a_block_recomputes(again, want):
     counts = schedule_counts("\n".join(lines), (2, 64))
     assert counts["recomputed_kernels"] == want
     assert (counts["remat_instructions"], counts["late_weight_grads"]) == (0, 0)
+
+
+def test_schedule_counts_the_matmuls_a_block_recomputes():
+    """A projection's matmul under `rematted_computation` is counted, its
+    first launch in the forward pass is not, and neither is XLA's own
+    recomputation (`.remat`, which `remat_instructions` counts); a
+    computation before ENTRY is never read."""
+    counts = schedule_counts(_entry(
+        ("f.1", _ACT, _FWD.format(0, "up")),
+        ("f.1.remat", _ACT, _FWD.format(0, "up")),
+        ("r.1", _ACT, _BLOCK.format(0, "mlp/up_proj/dot_general")),
+        ("dx.1", _ACT, _BWD.format(0, "up"))), (2, 64))
+    assert counts["recomputed_matmuls"] == 1
+    assert counts["remat_instructions"] == 1
 
 
 @pytest.mark.parametrize("loss_fn, want", [
